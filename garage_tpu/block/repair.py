@@ -15,8 +15,8 @@ Equivalent of reference src/block/repair.rs (SURVEY.md §2.5):
 TPU-first difference (the north-star design, BASELINE.md): the reference
 scrubs strictly one block at a time — read, blake2, next
 (repair.rs:438-490).  Here the iterator feeds *batches* to the BlockCodec:
-one device dispatch hashes `batch_blocks` blocks at once, so a TPU codec
-turns scrub from CPU-bound into IO-bound.
+whole prefix dirs gathered up to `batch_blocks` blocks per dispatch, so a
+TPU codec turns scrub from CPU-bound into IO-bound.
 """
 
 from __future__ import annotations
@@ -327,7 +327,16 @@ class ScrubWorker(Worker):
         it = self.iterator
         if it is None:
             return None
-        batch = await asyncio.to_thread(it.next_prefix)
+        # gather prefix dirs until `codec.batch_blocks` blocks: one prefix
+        # holds ~1 block below ~8M blocks per node, and the device wants
+        # wide batches (the fused kernel starts at 128 lanes)
+        want = max(1, self.manager.codec.params.batch_blocks)
+        batch = None
+        while batch is None or len(batch) < want:
+            more = await asyncio.to_thread(it.next_prefix)
+            if more is None:
+                break
+            batch = (batch or []) + more
         if batch is None:
             return None
         reads = await asyncio.gather(

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -44,23 +43,21 @@ def _load() -> ctypes.CDLL:
         return _lib
     if _lib_err is not None:
         raise DbError(f"native logdb unavailable: {_lib_err}")
-    try:
-        lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
-        # stale or missing binary (e.g. built on another host with
-        # -march=native): one rebuild attempt
+    from ..ops.native import build_lock, make_so
+
+    # under the inter-process build lock: a sibling worker may be
+    # rebuilding this very file
+    with build_lock():
         try:
-            target = ("asan" if _SO_NAME.endswith(".asan.so")
-                      else "tsan" if _SO_NAME.endswith(".tsan.so")
-                      else "liblogdb.so")
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR, "-s", target],
-                check=True, capture_output=True, timeout=120,
-            )
             lib = ctypes.CDLL(_SO_PATH)
-        except Exception as e:  # noqa: BLE001
-            _lib_err = str(e)
-            raise DbError(f"native logdb unavailable: {e}")
+        except OSError:
+            # stale or missing binary (e.g. built on another host with
+            # -march=native): one rebuild attempt
+            try:
+                lib = ctypes.CDLL(make_so("liblogdb.so"))
+            except Exception as e:  # noqa: BLE001
+                _lib_err = str(e)
+                raise DbError(f"native logdb unavailable: {e}")
     c = ctypes
     lib.ldb_open.restype = c.c_void_p
     lib.ldb_open.argtypes = [c.c_char_p, c.c_int]

@@ -13,7 +13,9 @@ to numpy (GF) / hashlib (BLAKE2s) when a kernel is unavailable.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import logging
 import os
 import subprocess
@@ -28,6 +30,59 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _BUILD_LOCK = threading.Lock()
 
 
+@contextlib.contextmanager
+def build_lock():
+    """Serialize build-and-load of native/*.so across threads AND
+    processes (flock on the native directory): test workers and daemons
+    sharing one checkout must not run two `make` over the same file, nor
+    dlopen one another's half-written output."""
+    with _BUILD_LOCK:
+        fd = os.open(_NATIVE_DIR, os.O_RDONLY)
+        try:
+            fcntl.flock(fd, fcntl.LOCK_EX)
+            yield
+        finally:
+            os.close(fd)        # closing drops the flock
+
+
+def _variant(so_name: str):
+    """(file to load, make target, files that target writes) for a
+    plain library name.  GARAGE_NATIVE_SUFFIX=.asan/.tsan selects the
+    sanitizer-instrumented variants (run the tests under the matching
+    LD_PRELOAD — see native/Makefile); those are built all three at once
+    by the PHONY asan/tsan targets, the plain ones by per-.so rules."""
+    suffix = os.environ.get("GARAGE_NATIVE_SUFFIX", "")
+    if not suffix:
+        return so_name, so_name, [so_name]
+    return (so_name.replace(".so", f"{suffix}.so"), suffix.lstrip("."),
+            [f"lib{n}{suffix}.so" for n in ("gf256", "logdb", "blake2smb")])
+
+
+def make_so(so_name: str) -> str:
+    """(Re)build native/<so_name> (its sanitizer variant when selected)
+    under a temporary name, then rename into place: a reader never maps
+    a file the linker is still writing.  Returns the path to load.
+    Call under build_lock()."""
+    load_name, make_target, written = _variant(so_name)
+    tmp = f".tmp.{os.getpid()}"
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "-s", "-B", make_target,
+             f"SO_SUFFIX={tmp}"],
+            check=True, capture_output=True, timeout=300,
+        )
+        for name in written:
+            os.replace(os.path.join(_NATIVE_DIR, name + tmp),
+                       os.path.join(_NATIVE_DIR, name))
+    finally:
+        for name in written:
+            try:
+                os.unlink(os.path.join(_NATIVE_DIR, name + tmp))
+            except OSError:
+                pass
+    return os.path.join(_NATIVE_DIR, load_name)
+
+
 def _load_or_build(so_name: str, src_name: str) -> Optional[ctypes.CDLL]:
     """Load native/<so_name>, building it (make) if missing or stale.
 
@@ -35,22 +90,14 @@ def _load_or_build(so_name: str, src_name: str) -> Optional[ctypes.CDLL]:
     host — the Makefile uses -march=native) triggers one clean rebuild.
     A failed build writes a marker keyed on the source mtime so this exact
     source is never re-attempted."""
-    # GARAGE_NATIVE_SUFFIX=.asan/.tsan selects the sanitizer-
-    # instrumented variants built by `make asan`/`make tsan` (run the
-    # tests under the matching LD_PRELOAD — see native/Makefile)
+    # sanitizer build failures must not poison the plain build's marker
+    # (or vice versa)
     suffix = os.environ.get("GARAGE_NATIVE_SUFFIX", "")
-    # sanitizer variants are built by the PHONY asan/tsan targets (the
-    # per-.so rules only exist for the plain builds), and their build
-    # failures must not poison the plain build's marker (or vice versa)
-    make_target = so_name
-    if suffix:
-        so_name = so_name.replace(".so", f"{suffix}.so")
-        make_target = suffix.lstrip(".")
-    so_path = os.path.join(_NATIVE_DIR, so_name)
+    so_path = os.path.join(_NATIVE_DIR, _variant(so_name)[0])
     src_path = os.path.join(_NATIVE_DIR, src_name)
     fail_marker = os.path.join(_NATIVE_DIR,
                                f".build_failed_{src_name}{suffix}")
-    with _BUILD_LOCK:
+    with build_lock():
         src_mtime = os.path.getmtime(src_path)
         fresh = os.path.exists(so_path) and os.path.getmtime(so_path) >= src_mtime
         if fresh:
@@ -63,11 +110,7 @@ def _load_or_build(so_name: str, src_name: str) -> Optional[ctypes.CDLL]:
         if os.path.exists(fail_marker) and os.path.getmtime(fail_marker) >= src_mtime:
             return None
         try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR, "-s", "-B", make_target],
-                check=True, capture_output=True, timeout=120,
-            )
-            return ctypes.CDLL(so_path)
+            return ctypes.CDLL(make_so(so_name))
         except Exception as e:
             logger.debug("native %s build failed: %s", so_name, e)
             try:

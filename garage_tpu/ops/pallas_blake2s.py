@@ -50,6 +50,15 @@ from .tpu_blake2s import H0, IV, SIGMA, _G_IDX, bytes_to_words
 LANE = 128
 
 
+def lanes_supported(nlanes: int) -> bool:
+    """Batch widths the kernel tiles: whole 128-lane rows, and either at
+    most 8 rows (one tile) or a multiple of 8 rows.  1536 lanes = 12
+    rows has neither, and the chip's compiler refuses its (6, 128)
+    tiles."""
+    rows, rem = divmod(nlanes, LANE)
+    return rem == 0 and rows > 0 and (rows <= 8 or rows % 8 == 0)
+
+
 def _rotr(x, n: int):
     return (x >> jnp.uint32(n)) | (x << jnp.uint32(32 - n))
 
@@ -129,14 +138,12 @@ def blake2s_words_pallas(msg, lengths, interpret: bool = False):
 
     nchunks, _, rows, _ = msg.shape
     # batch tile: up to 8 sublane-rows (1024 lanes) per grid step — one
-    # native (8, 128) vreg per state word; bigger tiles spill
-    rt = rows
-    while rt > 8 or rows % rt:
-        rt -= 1
+    # native (8, 128) vreg per state word; bigger tiles spill.  Mosaic
+    # wants the tile's row count to be the whole dim or a multiple of 8
+    # (lanes_supported is the caller-side guard).
+    assert lanes_supported(rows * LANE), rows
+    rt = min(rows, 8)
     grid = (rows // rt, nchunks)
-    # renamed in jax 0.5: TPUCompilerParams → CompilerParams; support both
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
     return pl.pallas_call(
         functools.partial(_kernel, nchunks),
         grid=grid,
@@ -147,7 +154,7 @@ def blake2s_words_pallas(msg, lengths, interpret: bool = False):
         out_specs=pl.BlockSpec((8, rt, LANE), lambda i, j: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((8, rows, LANE), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((8, rt, LANE), jnp.uint32)],
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -165,7 +172,7 @@ def blake2s_batch_pallas(data_u8: jax.Array, lengths: jax.Array,
     """
     bsz, total = data_u8.shape
     assert total % 64 == 0 and total > 0
-    assert bsz % LANE == 0, bsz
+    assert lanes_supported(bsz), bsz
     nchunks = total // 64
     rows = bsz // LANE
     msg = jnp.transpose(

@@ -773,10 +773,12 @@ class DeviceTransport:
                                 else None))
             tl.event(f"adopt {batch.kind}", track, batch.t_stage1,
                      batch.t_adopt1, cat="transport")
+            variant = getattr(self.device, "last_submit_variant", None)
             tl.event(f"submit {batch.kind}", track, batch.t_adopt1,
                      batch.t_submit1, cat="transport",
-                     compiled=batch.compiled)
-            variant = getattr(self.device, "last_submit_variant", None)
+                     compiled=batch.compiled,
+                     shape=self._staged_shape(batch.kind, staged),
+                     variant=variant if batch.kind == "scrub" else None)
             with self._cond:
                 if not self._inflight and self._busy_since is None:
                     self._busy_since = time.monotonic()
@@ -804,6 +806,16 @@ class DeviceTransport:
             with self._cond:
                 self._slot_free.append(slot)
                 self._cond.notify_all()
+
+    @staticmethod
+    def _staged_shape(kind: str, staged) -> List[int]:
+        """(lanes, cols) a staged hash/scrub batch dispatches at — the
+        compiled-executable shape, for the timeline."""
+        if kind == "hash":
+            return list(staged[0].shape)
+        if kind == "scrub":  # (arr | miss_arr, [miss_rows,] lengths, …)
+            return [int(staged[-3].shape[0]), int(staged[0].shape[1])]
+        return []
 
     def _collect_oldest(self) -> None:
         with self._cond:

@@ -12,6 +12,10 @@ mkdir -p "$BASE"
 for i in 0 1 2; do
   d="$BASE/node$i"
   mkdir -p "$d/meta" "$d/data"
+  # a chip belongs to ONE process: node 0 keeps the default backend
+  # (hybrid, attaches the device), nodes 1-2 run the CPU codec
+  backend_line=""
+  if [ "$i" != 0 ]; then backend_line='backend = "cpu"'; fi
   cat > "$d/garage.toml" <<EOF
 metadata_dir = "$d/meta"
 data_dir = "$d/data"
@@ -28,6 +32,7 @@ api_bind_addr = "127.0.0.1:39${i}0"
 
 [codec]
 store_parity = true
+$backend_line
 
 [admin]
 api_bind_addr = "127.0.0.1:39${i}3"

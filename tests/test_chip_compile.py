@@ -1,0 +1,151 @@
+"""The main path's device programs, compiled at real widths by the TPU
+compiler for a DESCRIBED v5e (no chip attached, nothing runs).  What
+interpret mode cannot show — tiles the chip's compiler refuses, programs
+that do not fit HBM, kernels that cannot be partitioned — fails here,
+at no chip time.  A compile that passes is not a chip run.
+
+The topology is described inside a fixture of this file, never while a
+module is imported: only one process may hold the TPU library, and every
+xdist worker imports every test file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from garage_tpu.ops import gf256, tpu_blake2s
+from garage_tpu.ops.codec import CodecParams
+from garage_tpu.ops.pallas_blake2s import (blake2s_batch_pallas,
+                                           lanes_supported)
+from garage_tpu.ops.pallas_gf import PallasGf
+from garage_tpu.ops.tpu_codec import TpuCodec, scrub_step_kernel
+
+MIB = 1 << 20
+HBM_BYTES = 16 * 10**9          # one v5e chip
+S = jax.ShapeDtypeStruct
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off around them
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _scrub_shapes(lanes, cols, codec, sh, const_sh=None):
+    return (S((lanes, cols), jnp.uint8, sharding=sh),
+            S((lanes,), jnp.int32, sharding=sh),
+            S((lanes, 8), jnp.uint32, sharding=sh),
+            S(codec._K_enc.shape, codec._K_enc.dtype,
+              sharding=const_sh or sh))
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+
+
+def test_pallas_blake2s_256_lanes(one_chip):
+    c = jax.jit(blake2s_batch_pallas).lower(
+        S((256, MIB), jnp.uint8, sharding=one_chip),
+        S((256,), jnp.int32, sharding=one_chip)).compile()
+    assert c.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("k,m", [(8, 4), (4, 2)])
+def test_pallas_gf_one_mib_shards(one_chip, k, m):
+    pg = PallasGf(gf256.rs_parity_matrix(k, m))
+    c = jax.jit(pg.__call__).lower(
+        S((256 // k, k, MIB // 4), jnp.uint32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_fused_scrub_fits_the_chip_beside_the_pools(one_chip):
+    """The transport keeps transport_staging_slots submissions in
+    flight beside the device pool: together they must fit one chip."""
+    params = CodecParams(rs_data=8, rs_parity=4)
+    codec = TpuCodec(params)
+    c = codec._scrub_pallas().lower(
+        *_scrub_shapes(256, MIB, codec, one_chip), 8).compile()
+    assert c.as_text().count("tpu_custom_call") == 2
+    need = (params.transport_staging_slots * _device_bytes(c)
+            + (params.pool_mib << 20))
+    assert need < HBM_BYTES, need
+
+
+def test_unrolled_xla_hash_small_lanes(one_chip):
+    """The <128-lane road.  Under rehearsal default_backend() is "cpu"
+    and would pick the rolled body, so the test steers the unroll."""
+    tpu_blake2s.set_unroll_override(True)
+    try:
+        c = jax.jit(tpu_blake2s.blake2s_batch).lower(
+            S((8, MIB), jnp.uint8, sharding=one_chip),
+            S((8,), jnp.int32, sharding=one_chip)).compile()
+    finally:
+        tpu_blake2s.set_unroll_override(None)
+    assert "tpu_custom_call" not in c.as_text()
+    assert _device_bytes(c) < HBM_BYTES
+
+
+def test_sharded_scrub_on_four_chips(topo):
+    """`[codec] shard_mesh = 4`: the XLA scrub step split over a 2x2
+    host — every device holds a quarter of the batch, not all of it."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    batch, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    tpu_blake2s.set_unroll_override(True)
+    try:
+        c = jax.jit(
+            scrub_step_kernel, static_argnums=(4,),
+            in_shardings=(batch, batch, batch, repl),
+            out_shardings=(batch, batch, repl, batch),
+        ).lower(*_scrub_shapes(256, MIB, codec, batch, repl), 8).compile()
+    finally:
+        tpu_blake2s.set_unroll_override(None)
+    ma = c.memory_analysis()
+    # per device: 64 of the 256 MiB in, 8 codewords x 4 parity MiB out
+    assert 64 * MIB <= ma.argument_size_in_bytes < 65 * MIB
+    assert 32 * MIB <= ma.output_size_in_bytes < 33 * MIB
+    assert _device_bytes(c) < HBM_BYTES
+    assert "all-reduce" in c.as_text()      # the corrupt count
+
+
+def test_twelve_row_batches_are_declined(one_chip):
+    """1536 lanes = 12 rows tiles as (6, 128), which the chip's compiler
+    refuses: the fused road declines such widths and they run the XLA
+    variant; 2048 lanes (16 rows, tiles of 8) compiles."""
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    assert not lanes_supported(1536)
+    assert not codec._use_pallas_scrub(1536)
+    assert codec._use_pallas_scrub(2048) and codec._use_pallas_scrub(256)
+    with pytest.raises(AssertionError):
+        jax.jit(blake2s_batch_pallas).lower(
+            S((1536, 64 << 10), jnp.uint8, sharding=one_chip),
+            S((1536,), jnp.int32, sharding=one_chip))
+    jax.jit(blake2s_batch_pallas).lower(
+        S((2048, 64 << 10), jnp.uint8, sharding=one_chip),
+        S((2048,), jnp.int32, sharding=one_chip)).compile()

@@ -163,7 +163,7 @@ def test_fused_latch_sync_failure_demotes(monkeypatch):
 
     # transient sync failures from the pallas variant accumulate...
     for i in range(PALLAS_MAX_TRANSIENT_FAILS - 1):
-        tpu.note_sync_failure(RuntimeError("UNAVAILABLE: tunnel reset"),
+        tpu.note_sync_failure(RuntimeError("UNAVAILABLE: connection reset"),
                               variant="pallas")
         assert tpu._pallas_fused_fails == i + 1
         assert tpu._pallas_fused_ok

@@ -2,7 +2,7 @@
 
 Runs the kernel in Pallas interpret mode on the CPU platform — no TPU
 needed for correctness (the on-device rate evidence lives in
-scripts/blake2s_tune.py + DEVICE_CAPTURE.json).
+DEVICE_CAPTURE.json, round 5).
 """
 
 import hashlib

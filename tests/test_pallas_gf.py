@@ -83,7 +83,7 @@ def test_pallas_latch_permanent_vs_transient(monkeypatch):
             self.calls += 1
             raise self.exc
 
-    # transient error (tunnel flake): retried, not latched
+    # transient error (device flake): retried, not latched
     boom = Boom(RuntimeError("UNAVAILABLE: connection reset by peer"))
     monkeypatch.setattr(codec, "_pallas_for", lambda mat: boom)
     out1 = codec._gf_apply_np(flat, codec._K_enc, mat=codec._enc_mat)

@@ -1,7 +1,6 @@
 """Multi-chip codec sharding on the virtual 8-device CPU mesh
 (xla_force_host_platform_device_count, see conftest.py) — validates the
-mesh-sharded verify/encode path the driver also exercises via
-__graft_entry__.dryrun_multichip."""
+mesh-sharded verify/encode path (`[codec] shard_mesh`)."""
 
 import hashlib
 
