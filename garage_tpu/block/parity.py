@@ -94,7 +94,7 @@ class ParityStore:
         hashes: Sequence[Hash],
         lengths: Sequence[int],
         parity: np.ndarray,
-    ) -> None:
+    ) -> bool:
         """Persist one codeword's parity: `hashes`/`lengths` are the j ≤ k
         member blocks in codeword order, `parity` is (m, maxlen) uint8
         encoded at the codec's (k, m) geometry.  j < k means a PARTIAL
@@ -104,7 +104,16 @@ class ParityStore:
         whose tail members are zero, and reconstruction counts the zero
         shards as always-available pieces.  Called by the scrub worker
         (full rows whose members all verified) and the write-path
-        accumulator (possibly partial)."""
+        accumulator (possibly partial).  → whether a sidecar was
+        written (False: one with this content was there and got a fresh
+        mtime)."""
+        # one call a codeword: in the profiler's trace, not in the ring,
+        # where the scrub batch's `parity write` event stands
+        with self.codec.obs.timeline.span("put codeword", "scrub-io",
+                                          cat="scrub", record=False):
+            return self._put_codeword(hashes, lengths, parity)
+
+    def _put_codeword(self, hashes, lengths, parity) -> bool:
         k = self.codec.params.rs_data
         assert 0 < len(hashes) <= k, (len(hashes), k)
         gid = self._gid(k, int(parity.shape[0]), hashes)
@@ -143,6 +152,7 @@ class ParityStore:
             os.replace(tmp, path)
         for h in hashes:
             self.index.insert(bytes(h), bytes(gid))
+        return existing is None
 
     # --- repair path -------------------------------------------------------
 

@@ -35,6 +35,7 @@ from ..utils.crdt import now_msec
 from ..utils.data import Hash
 from ..utils.migrate import Migrated
 from ..utils.persister import Persister
+from ..utils.timeline import Timeline
 from ..utils.tranquilizer import Tranquilizer
 
 logger = logging.getLogger("garage_tpu.block.repair")
@@ -44,6 +45,45 @@ SCRUB_INTERVAL_MAX = 35 * 86400
 DEFAULT_SCRUB_TRANQUILITY = 4     # ref repair.rs:27
 CHECKPOINT_INTERVAL = 60.0        # ref repair.rs:460-464
 REPAIR_BATCH = 1000               # ref repair.rs:92-101 (sqlite-safe batches)
+
+# What a scrub pass's wall time is partitioned into, by consecutive
+# stamps of the one coroutine that runs it (`ScrubWorker.work`): the
+# segments of a finished pass sum to its `scrub pass` span exactly.
+# `other` is the residue: between two `work()` calls (the runner, the
+# governor's pause, a paused pass), event-loop lag, what no stamp covers.
+SCRUB_SEGMENTS = (
+    "read_wait", "decompress", "codec_wait", "heal", "coverage_refresh",
+    "parity_write", "purge", "checkpoint", "tranquilize", "other",
+)
+
+# spans of a manager with no codec observer (unit fakes) go nowhere
+_NO_TIMELINE = Timeline(size=1)
+
+
+def _timeline(mgr) -> Timeline:
+    """The ring the scrub road is traced in: the codec observer's, the
+    one the feeder and the transport write and `device_timeline` reads."""
+    obs = getattr(getattr(mgr, "codec", None), "obs", None)
+    return obs.timeline if obs is not None else _NO_TIMELINE
+
+
+class _PassAccount:
+    """The exact-sum account of one scrub pass: every `mark(segment)`
+    puts the time since the last stamp to that segment."""
+
+    def __init__(self, t0_ns: int, resumed: bool = False):
+        self.t0 = self.last = t0_ns
+        self.resumed = resumed
+        self.ns = dict.fromkeys(SCRUB_SEGMENTS, 0)
+        self.flushed = dict.fromkeys(SCRUB_SEGMENTS, 0)
+        self.blocks = self.bytes = self.batches = 0
+
+    def mark(self, segment: str) -> Tuple[int, int]:
+        """→ (the last stamp, now): the interval now owned by `segment`."""
+        now = time.monotonic_ns()
+        prev, self.last = self.last, now
+        self.ns[segment] += now - prev
+        return prev, now
 
 
 class BlockStoreIterator:
@@ -198,6 +238,23 @@ class ScrubWorker(Worker):
         # codeword (k blocks) accumulates for the parity sidecar store
         self._parity_carry: Tuple[list, list] = ([], [])
         self._prev_pass_start = 0.0  # resumed pass: purge nothing extra
+        # the running pass's account (None between passes), and the
+        # counters it is flushed into after every batch
+        self._acct: Optional[_PassAccount] = None
+        metrics = getattr(getattr(manager, "system", None), "metrics", None)
+        self.m_segments = self.m_passes = self.m_bytes = None
+        if metrics is not None:
+            self.m_segments = metrics.counter(
+                "scrub_pass_seconds_total",
+                "Wall seconds of scrub passes by segment, partitioned by "
+                "consecutive stamps: a finished pass's segments sum to "
+                "its `scrub pass` timeline span; `other` is the residue")
+            self.m_passes = metrics.counter(
+                "scrub_passes_total", "Scrub passes run to their end")
+            self.m_bytes = metrics.counter(
+                "scrub_verified_bytes_total",
+                "Block bytes the scrub handed to the codec (parity carry "
+                "lanes, which are hashed again, not counted)")
 
     def _roots(self) -> List[str]:
         return [d.path for d in self.manager.data_layout.data_dirs]
@@ -225,6 +282,7 @@ class ScrubWorker(Worker):
                 self.iterator = BlockStoreIterator(self._roots())
                 st.running, st.paused, st.position, st.corruptions = True, False, 0, 0
                 self._verified_pos = 0
+                self._begin_pass()
                 self._drop_read_ahead()
                 self._drop_parity_carry()
                 # purge grace is ONE pass: remember the previous start
@@ -249,7 +307,11 @@ class ScrubWorker(Worker):
             st.running, st.paused, st.position = False, False, 0
             self._verified_pos = 0
             self._drop_read_ahead()
+            self._flush_account()   # a cancelled pass has no span
+            self._acct = None
         self._checkpoint(force=True)
+        if self._acct is not None:
+            self._acct.mark("checkpoint")
 
     def _drop_parity_carry(self) -> None:
         self._parity_carry = ([], [])
@@ -259,15 +321,63 @@ class ScrubWorker(Worker):
             self._ra_task.cancel()
             self._ra_task = None
 
-    def _checkpoint(self, force: bool = False) -> None:
+    def _checkpoint(self, force: bool = False) -> bool:
+        """→ whether the state was written."""
         if self.persister is None:
-            return
+            return False
         if force or time.monotonic() - self._last_checkpoint > CHECKPOINT_INTERVAL:
             # resume must re-verify anything not actually verified yet, so
             # the persisted position trails the (read-ahead) iterator
             self.state.position = self._verified_pos if self.iterator else 0
             self.persister.save(self.state)
             self._last_checkpoint = time.monotonic()
+            return True
+        return False
+
+    # --- the pass's account and span tree (docs/OBSERVABILITY.md,
+    #     "Device timeline"): track `scrub`, cat `scrub`, one event a
+    #     batch or a pass, never one a block ---
+
+    def _begin_pass(self, resumed: bool = False) -> None:
+        t0 = time.monotonic_ns()
+        # the profiler's clock against the ring's, once a pass: the
+        # annotation starts just after the stamp it carries
+        _timeline(self.manager).mark_clock(t0)
+        self._acct = _PassAccount(t0, resumed)
+
+    def _segment(self, segment: str, name: Optional[str] = None,
+                 **args) -> None:
+        """Close the interval since the last stamp as `segment`, with
+        the timeline event `name` over the same two stamps.  Outside a
+        pass (scrub_batch called on its own) there is no account."""
+        if self._acct is None:
+            return
+        t0, t1 = self._acct.mark(segment)
+        if name is not None:
+            _timeline(self.manager).event(name, "scrub", t0, t1,
+                                          cat="scrub", **args)
+
+    def _flush_account(self) -> None:
+        """What the account gained since the last flush, to the counters."""
+        acct = self._acct
+        if acct is None or self.m_segments is None:
+            return
+        for seg, ns in acct.ns.items():
+            if ns > acct.flushed[seg]:
+                self.m_segments.inc((ns - acct.flushed[seg]) / 1e9,
+                                    segment=seg)
+                acct.flushed[seg] = ns
+
+    def _end_pass(self) -> None:
+        acct = self._acct
+        _timeline(self.manager).event(
+            "scrub pass", "scrub", acct.t0, acct.last, cat="scrub",
+            blocks=acct.blocks, bytes=acct.bytes, batches=acct.batches,
+            corruptions=self.state.corruptions, resumed=acct.resumed,
+            **{f"{seg}_ms": round(ns / 1e6, 3)
+               for seg, ns in acct.ns.items() if ns})
+        self._flush_account()
+        self._acct = None
 
     # --- the batch scrub step ---
 
@@ -285,17 +395,30 @@ class ScrubWorker(Worker):
             return WorkerState.IDLE
         if st.paused:
             return WorkerState.IDLE
+        if self._acct is None:
+            # a pass resumed from the persisted state: its span and its
+            # account cover what this process ran of it
+            self._begin_pass(resumed=True)
+        # since the last stamp: the runner between two work() calls
+        self._acct.mark("other")
         self.tranquilizer.reset()
         task = self._ra_task or asyncio.ensure_future(self._read_ahead())
         # clear BEFORE awaiting: if the read fails, the next work() cycle
         # must retry a fresh read, not re-await the cached exception
         self._ra_task = None
         item = await task
+        self._segment("read_wait", "read wait")
         if item is None:
             # complete
             st.time_last_complete = now_msec()
             st.time_next_run = randomize_next_scrub()
             st.running = False
+            # counted where the pass's end shows to whoever polls the
+            # state, with what the account holds so far; the purge and
+            # the checkpoint below still belong to the pass's span
+            if self.m_passes is not None:
+                self.m_passes.inc()
+            self._flush_account()
             self.iterator = None
             self._drop_parity_carry()  # <k leftover: next pass retries
             if self.manager.parity_store is not None:
@@ -303,11 +426,14 @@ class ScrubWorker(Worker):
                 # refreshed by NEITHER this pass nor the previous one,
                 # else orphans accumulate forever (one-pass grace keeps
                 # coverage for rows that failed verify this pass)
-                await asyncio.to_thread(
+                removed = await asyncio.to_thread(
                     self.manager.parity_store.purge_stale,
                     self._prev_pass_start,
                 )
+                self._segment("purge", "purge stale", removed=removed)
             self._checkpoint(force=True)
+            self._segment("checkpoint", "checkpoint")
+            self._end_pass()
             logger.info("scrub complete, %d corruptions found", st.corruptions)
             return WorkerState.BUSY
         batch, reads, pos_after = item
@@ -318,8 +444,15 @@ class ScrubWorker(Worker):
         if batch:
             await self.scrub_batch(batch, reads)
         self._verified_pos = pos_after
-        self._checkpoint()
-        return await self.tranquilizer.tranquilize_worker(st.tranquility)
+        # the stamps before and after this stretch are scrub_batch's
+        # last and the checkpoint's: what lies between is the checkpoint
+        saved = self._checkpoint()
+        self._segment("checkpoint", "checkpoint" if saved else None)
+        state = await self.tranquilizer.tranquilize_worker(st.tranquility)
+        self._segment("tranquilize",
+                      "tranquilize" if st.tranquility > 0 else None)
+        self._flush_account()
+        return state
 
     async def _read_ahead(self):
         """Next prefix's batch + file contents, read off-thread.  Returns
@@ -327,6 +460,7 @@ class ScrubWorker(Worker):
         it = self.iterator
         if it is None:
             return None
+        t0 = time.monotonic_ns()
         # gather prefix dirs until `codec.batch_blocks` blocks: one prefix
         # holds ~1 block below ~8M blocks per node, and the device wants
         # wide batches (the fused kernel starts at 128 lanes)
@@ -343,6 +477,12 @@ class ScrubWorker(Worker):
             *[asyncio.to_thread(_try_read, self.manager, path)
               for _h, path, _c in batch]
         )
+        # the read-ahead itself, listing included, which overlaps the
+        # worker's codec wait: on a track of its own, in no segment
+        _timeline(self.manager).event(
+            "read files", "scrub-io", t0, time.monotonic_ns(), cat="scrub",
+            blocks=len(batch),
+            bytes=sum(len(r) for r in reads if isinstance(r, bytes)))
         # hint the device pool about the upcoming prefix: the transport
         # stages these blocks as background-class work WHILE the current
         # batch computes (riding the PR 11 double buffer), so the next
@@ -374,6 +514,9 @@ class ScrubWorker(Worker):
                 *[asyncio.to_thread(_try_read, mgr, path)
                   for _h, path, _c in batch]
             )
+            self._segment("read_wait", "read wait")
+        lost = []           # (hash, path) to quarantine and heal
+        decompressed = 0
         for i, ((h, path, compressed), raw) in enumerate(zip(batch, reads)):
             if raw is None:
                 continue
@@ -381,7 +524,7 @@ class ScrubWorker(Worker):
                 # unreadable on media: the copy is as lost as a content
                 # mismatch — quarantine it and let the sidecar/resync
                 # ladder re-materialize a clean one
-                await self._quarantine(h, path)
+                lost.append((h, path))
                 continue
             if compressed:
                 # decompress so the codec verifies the CONTENT hash (a
@@ -390,8 +533,9 @@ class ScrubWorker(Worker):
                 # codeword — compressed blocks must be locally repairable
                 # too, not just the plain ones
                 data = await asyncio.to_thread(_try_decompress, raw)
+                decompressed += 1
                 if data is None:
-                    await self._quarantine(h, path)
+                    lost.append((h, path))
                     continue
                 plain_idx.append(i)
                 plain_blocks.append(data)
@@ -400,6 +544,9 @@ class ScrubWorker(Worker):
                 plain_idx.append(i)
                 plain_blocks.append(raw)
                 plain_hashes.append(h)
+        if decompressed:
+            self._segment("decompress", "decompress", blocks=decompressed)
+        await self._heal(lost)
         if plain_blocks:
             store = mgr.parity_store
             want_parity = (
@@ -416,6 +563,13 @@ class ScrubWorker(Worker):
             nc = len(carry_b)
             all_b = carry_b + plain_blocks
             all_h = carry_h + plain_hashes
+            nbytes = sum(len(b) for b in plain_blocks)
+            if self._acct is not None:
+                self._acct.batches += 1
+                self._acct.blocks += len(plain_blocks)
+                self._acct.bytes += nbytes
+            if self.m_bytes is not None:
+                self.m_bytes.inc(nbytes)
             # span per fused dispatch: a slow batch (gated link, mid-pass
             # XLA compile, CPU steal) shows up in the slow-op log even on
             # nodes with no trace_sink configured
@@ -438,10 +592,10 @@ class ScrubWorker(Worker):
                         mgr.codec.scrub_encode_batch, all_b, all_h,
                         want_parity,
                     )
-            for j, good in enumerate(ok[nc:]):
-                if not good:
-                    h, path, _ = batch[plain_idx[j]]
-                    await self._quarantine(h, path)
+            self._segment("codec_wait", "codec wait", blocks=len(all_b),
+                          bytes=nbytes, carry=nc)
+            await self._heal([batch[plain_idx[j]][:2]
+                              for j, good in enumerate(ok[nc:]) if not good])
             # Coverage refresh: verified blocks with NO live distributed
             # codeword (distribution failed at write time, coverage was
             # wrongly tombstoned, or the data predates EC) re-enter the
@@ -481,9 +635,13 @@ class ScrubWorker(Worker):
                         if d.holds_index_for(h) and not d.locally_covered(h)
                     ]
 
+                refreshed = 0
                 for h, b in await asyncio.to_thread(_uncovered):
                     self.coverage_refreshed += 1
+                    refreshed += 1
                     acc.add(h, DataBlock.plain(b))
+                self._segment("coverage_refresh", "coverage refresh",
+                              candidates=len(cand), refreshed=refreshed)
             if want_parity and parity is not None:
                 # persist RS sidecars for every COMPLETE codeword whose
                 # members all verified — this is what makes a later
@@ -491,6 +649,7 @@ class ScrubWorker(Worker):
                 # (the BlockCodec north star's decode-repair half)
                 k = mgr.codec.params.rs_data
                 nrows = len(all_b) // k
+                written = touched = par_bytes = 0
                 for row in range(nrows):
                     lo = row * k
                     if not all(ok[lo:lo + k]):
@@ -499,12 +658,20 @@ class ScrubWorker(Worker):
                     # longest member are zero parity (GF-linear) and would
                     # bloat the sidecar to the batch-global maxlen
                     row_max = max(len(b) for b in all_b[lo:lo + k])
-                    await asyncio.to_thread(
+                    row_parity = np.asarray(parity[row])[:, :row_max]
+                    if await asyncio.to_thread(
                         store.put_codeword,
                         all_h[lo:lo + k],
                         [len(b) for b in all_b[lo:lo + k]],
-                        np.asarray(parity[row])[:, :row_max],
-                    )
+                        row_parity,
+                    ):
+                        written += 1
+                        par_bytes += row_parity.nbytes
+                    else:
+                        touched += 1
+                self._segment("parity_write", "parity write", rows=nrows,
+                              written=written, touched=touched,
+                              bytes=par_bytes)
                 rest = nrows * k
                 self._parity_carry = (
                     [b for b, good in zip(all_b[rest:], ok[rest:]) if good],
@@ -512,7 +679,19 @@ class ScrubWorker(Worker):
                                           ok[rest:]) if good],
                 )
 
-    async def _quarantine(self, h: Hash, path: str) -> None:
+    async def _heal(self, lost: List[Tuple[Hash, str]]) -> None:
+        """Quarantine and heal a batch's lost copies as one stamped
+        section; `how` says where the heals came from."""
+        if not lost:
+            return
+        hows = [await self._quarantine(h, path) for h, path in lost]
+        how = hows[0] if len(set(hows)) == 1 else "mixed"
+        self._segment("heal", "quarantine+heal", blocks=len(lost), how=how,
+                      local_sidecar=hows.count("local_sidecar"))
+
+    async def _quarantine(self, h: Hash, path: str) -> str:
+        """→ how the copy is healed: `local_sidecar` (rebuilt here, now)
+        or `resync` (queued for a fetch from the replicas)."""
         self.state.corruptions += 1
         self.manager.corruptions += 1
         logger.error("scrub: corrupted block %s at %s", bytes(h).hex()[:16], path)
@@ -533,10 +712,11 @@ class ScrubWorker(Worker):
                 await self.manager.write_block(h, DataBlock.plain(data))
                 self.manager.blocks_reconstructed += 1
                 self.manager.note_heal("local_sidecar")
-                return
+                return "local_sidecar"
         if self.manager.resync is not None:
             self.manager.resync.put_to_resync(h, 0.0,
                                               source="scrub_corrupt")
+        return "resync"
 
     async def wait_for_work(self) -> None:
         self._wake.clear()
@@ -712,21 +892,25 @@ def _try_read(mgr, path: str):
     fundamentally healthy root read-only."""
     from .health import is_media_error
 
-    try:
-        raw = mgr.disk.read_file_direct(path)
-    except FileNotFoundError:
-        return None
-    except OSError as e:
-        if not is_media_error(e):
-            logger.warning("scrub: transient read error on %s "
-                           "(errno %s: %s)", path, e.errno, e)
+    # one hop a block: in the profiler's trace, not in the ring, where
+    # the batch's `read files` event stands
+    with _timeline(mgr).span("read file", "scrub-io", cat="scrub",
+                             record=False):
+        try:
+            raw = mgr.disk.read_file_direct(path)
+        except FileNotFoundError:
             return None
-        logger.error("scrub: read of %s failed (errno %s: %s)",
-                     path, e.errno, e)
-        mgr.health.note_error(mgr._root_of(path), "scrub", e)
-        return _READ_ERROR
-    mgr.health.note_ok(mgr._root_of(path), "scrub")
-    return raw
+        except OSError as e:
+            if not is_media_error(e):
+                logger.warning("scrub: transient read error on %s "
+                               "(errno %s: %s)", path, e.errno, e)
+                return None
+            logger.error("scrub: read of %s failed (errno %s: %s)",
+                         path, e.errno, e)
+            mgr.health.note_error(mgr._root_of(path), "scrub", e)
+            return _READ_ERROR
+        mgr.health.note_ok(mgr._root_of(path), "scrub")
+        return raw
 
 
 def _try_decompress(raw: bytes) -> Optional[bytes]:

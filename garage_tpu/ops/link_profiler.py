@@ -12,9 +12,10 @@ Every profiled round trip is partitioned into the stage taxonomy
 
     stage_copy  flat-buffer fill (the transport's single host copy)
     adopt       dlpack adoption / device_put of the staged buffer
-    compile     dispatch that triggered an XLA compile (first call
-                for a (kind, shape) — split out so cold-start cost
-                never pollutes the steady-state dispatch picture)
+    compile     dispatch inside which JAX built a program or loaded
+                one from its persistent cache (ops/compile_listener.py)
+                — split out so cold-start cost never pollutes the
+                steady-state dispatch picture
     dispatch    XLA call launch (async; returns before the device runs)
     compute     device busy: submit-return → results ready
                 (block_until_ready delta, measured at collect)

@@ -116,8 +116,34 @@ class CodecObserver:
                 "codec_gate_events_total",
                 "Gate-decision/demotion events by kind and reason",
             )
+            self._substage_s = metrics.counter(
+                "transport_substage_seconds_total",
+                "Seconds inside a transport stage that have a stamp of "
+                "their own: compose (pool composition, inside adopt), "
+                "pool_adopt (inside collect); the five stages of "
+                "transport_stage_seconds are not re-cut",
+            )
+            self._substage_n = metrics.counter(
+                "transport_substage_calls_total",
+                "Sections counted in transport_substage_seconds_total "
+                "(compose: one a resident dispatch; pool_adopt: one a "
+                "collect that adopted)",
+            )
+            self._compile_n = metrics.counter(
+                "codec_compiles_total",
+                "Device programs JAX built or loaded from its persistent "
+                "cache, by the innermost open timeline span of the "
+                "compiling thread (`unspanned` where none was open)",
+            )
+            self._compile_s = metrics.counter(
+                "codec_compile_seconds_total",
+                "Backend compile seconds of codec_compiles_total, a "
+                "cache load's included",
+            )
         else:
             self._hist = self._bytes_ctr = self._event_ctr = None
+            self._substage_s = self._substage_n = None
+            self._compile_n = self._compile_s = None
 
     # --- events ---
 
@@ -165,6 +191,20 @@ class CodecObserver:
                 k: {"count": int(c), "seconds": round(s, 6)}
                 for k, (c, s) in sorted(self._stage_acc.items())
             }
+
+    def note_substage(self, stage: str, ns: int) -> None:
+        """One stamped section inside a transport stage (`compose`,
+        `pool_adopt`), counted from the stamps of its timeline span."""
+        if self._substage_s is not None:
+            self._substage_s.inc(ns / 1e9, stage=stage)
+            self._substage_n.inc(stage=stage)
+
+    def note_compile(self, where: str, source: str, seconds: float) -> None:
+        """One program built (`built`) or loaded from the persistent
+        cache (`cache`) under the span `where` (ops/compile_listener.py)."""
+        if self._compile_n is not None:
+            self._compile_n.inc(**{"where": where, "from": source})
+            self._compile_s.inc(seconds, where=where)
 
     # --- bytes ---
 

@@ -217,9 +217,12 @@ def blake2s_batch(
         active = c32 <= last
         return jnp.where(active[None, :], h_new, h), None
 
-    h, _ = jax.lax.scan(
-        step, h0, (jnp.arange(nchunks, dtype=jnp.int32), msg)
-    )
+    # the scope names the scan's `while` in a trace, whichever jitted
+    # program it was lowered into
+    with jax.named_scope("blake2s_scan"):
+        h, _ = jax.lax.scan(
+            step, h0, (jnp.arange(nchunks, dtype=jnp.int32), msg)
+        )
     return h.T
 
 

@@ -22,7 +22,7 @@ Bit-identical to CpuCodec (tests/test_codec_equivalence.py).
 
 from __future__ import annotations
 
-import functools
+import contextlib
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.data import Hash
-from . import gf256
+from . import compile_listener, gf256
 from .codec import BlockCodec, CodecParams
 from .compile_cache import ensure_compile_cache
 from .tpu_blake2s import blake2s_batch, digests_to_bytes
@@ -102,18 +102,20 @@ def gf_apply(shards_u32: jax.Array, K: jax.Array) -> jax.Array:
     r, k, _ = K.shape
     one = jnp.uint32(0x01010101)
     ff = jnp.uint32(0xFF)
-    masks = []
-    for i in range(k):
-        x = shards_u32[:, i]
-        masks.append([(((x >> jnp.uint32(b)) & one) * ff) for b in range(8)])
-    outs = []
-    for p in range(r):
-        acc = jnp.zeros_like(shards_u32[:, 0])
+    with jax.named_scope("gf_apply"):
+        masks = []
         for i in range(k):
-            for b in range(8):
-                acc = acc ^ (masks[i][b] & K[p, i, b])
-        outs.append(acc)
-    return jnp.stack(outs, axis=1)
+            x = shards_u32[:, i]
+            masks.append(
+                [(((x >> jnp.uint32(b)) & one) * ff) for b in range(8)])
+        outs = []
+        for p in range(r):
+            acc = jnp.zeros_like(shards_u32[:, 0])
+            for i in range(k):
+                for b in range(8):
+                    acc = acc ^ (masks[i][b] & K[p, i, b])
+            outs.append(acc)
+        return jnp.stack(outs, axis=1)
 
 
 def bytes_view_u32(x_u8: jax.Array) -> jax.Array:
@@ -157,6 +159,27 @@ def scrub_step_kernel(data_u8, lengths, expected, K_enc, k: int):
     groups = u32.reshape(u32.shape[0] // k, k, u32.shape[-1])
     parity = u32_view_bytes(gf_apply(groups, K_enc))
     return h, ok, bad, parity
+
+
+# The jitted entry points, under the names their programs carry in a
+# profiler trace (`jit_<name>` on the device's "XLA Modules" line): a
+# reader of the trace finds a kernel by these after any refactor.
+
+
+def hash_xla(data_u8, lengths):
+    return blake2s_batch(data_u8, lengths)
+
+
+def verify_xla(data_u8, lengths, expected):
+    return verify_kernel(data_u8, lengths, expected)
+
+
+def gf_apply_xla(shards_u32, K):
+    return gf_apply(shards_u32, K)
+
+
+def scrub_fused_xla(data_u8, lengths, expected, K_enc, k: int):
+    return scrub_step_kernel(data_u8, lengths, expected, K_enc, k)
 
 
 # --- codec ------------------------------------------------------------------
@@ -243,26 +266,32 @@ class TpuCodec(BlockCodec):
         # transport clears these before each submit/collect and reads
         # them after, so adopt (dlpack/device_put) time and the compile
         # vs steady-state dispatch split are attributable without the
-        # transport reaching into JAX.  last_submit_compiled is a
-        # first-call-per-(kind, shape) proxy for "this dispatch paid an
-        # XLA compile" — jit caches by shape+dtype, so a fresh shape on
-        # a warm function is the compile case worth splitting out.
+        # transport reaching into JAX.  last_submit_compiled says that
+        # JAX built a program, or loaded one from its persistent cache,
+        # inside this submission's dispatch (ops/compile_listener.py).
         self.last_adopt_ns = 0
         self.last_ready_ns = 0
         self.last_submit_compiled = False
-        self._dispatched_shapes = set()
+        # the timeline track of this codec's spans (`compose`, `submit
+        # <kind>`): the transport names the slot it submits from
+        self.span_track = "device"
+        compile_listener.attach(self.obs)
+        if self.obs.timeline.annotate is None:
+            # every timeline span is in the profiler's trace too, as
+            # `gt:<name>` (one atomic read while no profile runs)
+            self.obs.timeline.annotate = jax.profiler.TraceAnnotation
         if self.mesh is not None:
             batch, repl = self._batch_sh, self._repl_sh
             self._hash_jit = jax.jit(
-                blake2s_batch, in_shardings=(batch, batch), out_shardings=batch
+                hash_xla, in_shardings=(batch, batch), out_shardings=batch
             )
             self._verify_jit = jax.jit(
-                verify_kernel,
+                verify_xla,
                 in_shardings=(batch, batch, batch),
                 out_shardings=(batch, batch, repl),
             )
             self._gf_jit = jax.jit(
-                gf_apply, in_shardings=(batch, repl), out_shardings=batch
+                gf_apply_xla, in_shardings=(batch, repl), out_shardings=batch
             )
             # static k passed POSITIONALLY: pjit rejects kwargs when
             # in_shardings is given, so static_argnums — not
@@ -271,16 +300,16 @@ class TpuCodec(BlockCodec):
             # level sharded scrub test; the kwarg form compiled fine
             # single-device and exploded only on a real mesh)
             self._scrub_jit = jax.jit(
-                scrub_step_kernel,
+                scrub_fused_xla,
                 static_argnums=(4,),
                 in_shardings=(batch, batch, batch, repl),
                 out_shardings=(batch, batch, repl, batch),
             )
         else:
-            self._hash_jit = jax.jit(blake2s_batch)
-            self._verify_jit = jax.jit(verify_kernel)
-            self._gf_jit = jax.jit(gf_apply)
-            self._scrub_jit = jax.jit(scrub_step_kernel, static_argnums=(4,))
+            self._hash_jit = jax.jit(hash_xla)
+            self._verify_jit = jax.jit(verify_xla)
+            self._gf_jit = jax.jit(gf_apply_xla)
+            self._scrub_jit = jax.jit(scrub_fused_xla, static_argnums=(4,))
 
     def ragged_side(self) -> str:
         """Feeder attribution: a bare TpuCodec runs every ragged batch
@@ -349,13 +378,27 @@ class TpuCodec(BlockCodec):
             u32 = jnp.pad(u32, ((0, pad), (0, 0), (0, 0)))
         return self._gf_jit(jax.device_put(u32, self._batch_sh), K)[:n]
 
-    def _mark_adopt(self, kind: str, shape) -> None:
-        """Stamp the adoption boundary + the compile-vs-dispatch verdict
-        for the submission being built (LinkProfiler contract)."""
+    def _mark_adopt(self) -> None:
+        """Stamp the adoption boundary of the submission being built
+        (LinkProfiler contract); its dispatch has not compiled yet."""
         self.last_adopt_ns = time.monotonic_ns()
-        key = (kind, tuple(shape))
-        self.last_submit_compiled = key not in self._dispatched_shapes
-        self._dispatched_shapes.add(key)
+        self.last_submit_compiled = False
+
+    @contextlib.contextmanager
+    def _dispatching(self, kind: str):
+        """The dispatch of one submission, as the span `submit <kind>`:
+        in the profiler's trace and on the thread's stack for the
+        compile listener, not in the ring, where the transport's event
+        of the same name stands.  Leaves `last_submit_compiled` saying
+        whether JAX built or loaded a program inside it."""
+        n0 = compile_listener.thread_compiles()
+        try:
+            with self.obs.timeline.span(f"submit {kind}", self.span_track,
+                                        record=False):
+                yield
+        finally:
+            if compile_listener.thread_compiles() > n0:
+                self.last_submit_compiled = True
 
     def _mark_ready(self, handle) -> None:
         """Block until the device results exist, then stamp the ready
@@ -379,8 +422,9 @@ class TpuCodec(BlockCodec):
             self._probe_sum_jit = jax.jit(
                 lambda x: jnp.sum(x, dtype=jnp.uint32))
         da = self._to_device(arr)
-        self._mark_adopt("probe", arr.shape)
-        return self._probe_sum_jit(da)
+        self._mark_adopt()
+        with self._dispatching("probe"):
+            return self._probe_sum_jit(da)
 
     def probe_collect(self, handle) -> int:
         self._mark_ready(handle)
@@ -392,8 +436,9 @@ class TpuCodec(BlockCodec):
         with self.obs.stage("h2d_transfer", "tpu"):
             da = self._to_device(arr, shard=True)
             dl = self._put(lengths)
-        self._mark_adopt("hash", arr.shape)
-        with self.obs.stage("kernel_dispatch", "tpu"):
+        self._mark_adopt()
+        with self._dispatching("hash"), \
+                self.obs.stage("kernel_dispatch", "tpu"):
             return self._hash_jit(da, dl)
 
     def hash_collect(self, handle, n: int) -> List[Hash]:
@@ -451,8 +496,9 @@ class TpuCodec(BlockCodec):
         with self.obs.stage("h2d_transfer", "tpu"):
             u32 = bytes_view_u32(self._to_device(
                 groups.reshape(-1, groups.shape[-2], groups.shape[-1])))
-        self._mark_adopt("encode", groups.shape)
-        with self.obs.stage("kernel_dispatch", "tpu"):
+        self._mark_adopt()
+        with self._dispatching("encode"), \
+                self.obs.stage("kernel_dispatch", "tpu"):
             return u32_view_bytes(self._gf_submit(u32, self._K_enc,
                                                   self._enc_mat))
 
@@ -483,8 +529,9 @@ class TpuCodec(BlockCodec):
         with self.obs.stage("h2d_transfer", "tpu"):
             u32 = bytes_view_u32(self._to_device(
                 np.ascontiguousarray(sub)))
-        self._mark_adopt("decode", (*sub.shape, *key[0]))
-        with self.obs.stage("kernel_dispatch", "tpu"):
+        self._mark_adopt()
+        with self._dispatching("decode"), \
+                self.obs.stage("kernel_dispatch", "tpu"):
             return u32_view_bytes(self._gf_submit(u32, K, dec_mat))[..., :s]
 
     def decode_collect(self, handle) -> np.ndarray:
@@ -721,7 +768,7 @@ class TpuCodec(BlockCodec):
 
             pg = self._pallas_for(self._enc_mat)
 
-            def fused(data_u8, lengths, expected, K_enc, k):
+            def scrub_fused_pallas(data_u8, lengths, expected, K_enc, k):
                 h = blake2s_batch_pallas(data_u8, lengths)
                 ok = jnp.all(h == expected, axis=-1)
                 bad = jnp.sum(~ok, dtype=jnp.int32)
@@ -733,7 +780,8 @@ class TpuCodec(BlockCodec):
                     parity = u32_view_bytes(gf_apply(groups, K_enc))
                 return h, ok, bad, parity
 
-            self._scrub_pallas_jit = jax.jit(fused, static_argnums=(4,))
+            self._scrub_pallas_jit = jax.jit(scrub_fused_pallas,
+                                             static_argnums=(4,))
         return self._scrub_pallas_jit
 
     def _use_pallas_scrub(self, nlanes: int) -> bool:
@@ -854,23 +902,34 @@ class TpuCodec(BlockCodec):
             da = self._to_device(arr, shard=True)
             dl = self._put(lengths)
             de = self._put(expected)
-        self._mark_adopt("scrub", arr.shape)
-        if self._use_pallas_scrub(arr.shape[0]):
-            try:
-                with self.obs.stage("kernel_dispatch", "tpu"):
-                    out = self._scrub_pallas()(
-                        da, dl, de, self._K_enc, self.params.rs_data,
-                    )
-                self.last_submit_variant = "pallas"
-                return out
-            except Exception as e:
-                self._note_fused_failure(e)
-        with self.obs.stage("kernel_dispatch", "tpu"):
-            out = self._scrub_jit(
-                da, dl, de, self._K_enc, self.params.rs_data,
-            )
-        self.last_submit_variant = "xla"
-        return out
+        return self._scrub_dispatch(da, dl, de)
+
+    def _scrub_dispatch(self, data, dl, de):
+        """Dispatch the fused kernel on a batch that is on the device:
+        the Pallas variant where its latch and the lane count allow,
+        else (or after its failure) the XLA one."""
+        self._mark_adopt()
+        with self._dispatching("scrub"):
+            if self._use_pallas_scrub(data.shape[0]):
+                try:
+                    with self.obs.stage("kernel_dispatch", "tpu"):
+                        out = self._scrub_pallas()(
+                            data, dl, de, self._K_enc, self.params.rs_data,
+                        )
+                    self.last_submit_variant = "pallas"
+                    return out
+                except Exception as e:
+                    self._note_fused_failure(e)
+            if self.mesh is not None:
+                # a batch composed on the pool's device (the first)
+                # moves onto the mesh; one staged there already stays
+                data = jax.device_put(data, self._batch_sh)
+            with self.obs.stage("kernel_dispatch", "tpu"):
+                out = self._scrub_jit(
+                    data, dl, de, self._K_enc, self.params.rs_data,
+                )
+            self.last_submit_variant = "xla"
+            return out
 
     # --- the DevicePool API (ops/device_pool.py) ---
     #
@@ -890,45 +949,33 @@ class TpuCodec(BlockCodec):
         cols = int(miss_arr.shape[1])
         assert lanes % self.params.rs_data == 0
         assert cols % 4 == 0
-        with self.obs.stage("h2d_transfer", "tpu"):
-            full = jnp.zeros((lanes, cols), dtype=jnp.uint8,
-                             device=self.device)
-            if len(miss_rows):
-                dm = self._to_device(
-                    np.ascontiguousarray(miss_arr[:len(miss_rows)]))
-                idx = jax.device_put(
-                    np.asarray(miss_rows, dtype=np.int32), self.device)
-                full = full.at[idx].set(dm)
-            dl = self._put(lengths)
-            de = self._put(expected)
-        # device-side composition of pool-resident lanes: no host
-        # bytes move here — pages are already device arrays
-        for r, pages, length in resident:
-            row = jnp.concatenate(list(pages))
-            if int(row.shape[0]) < cols:
-                row = jnp.pad(row, (0, cols - int(row.shape[0])))
-            full = full.at[int(r)].set(row[:cols])
-        self._mark_adopt("scrub", (lanes, cols))
-        if self._use_pallas_scrub(lanes):
-            try:
-                with self.obs.stage("kernel_dispatch", "tpu"):
-                    out = self._scrub_pallas()(
-                        full, dl, de, self._K_enc, self.params.rs_data,
-                    )
-                self.last_submit_variant = "pallas"
-                return out, full
-            except Exception as e:
-                self._note_fused_failure(e)
-        # sharded: the batch was composed on the pool's device (the
-        # first); the kernel's copy moves onto the mesh
-        dfull = (full if self.mesh is None
-                 else jax.device_put(full, self._batch_sh))
-        with self.obs.stage("kernel_dispatch", "tpu"):
-            out = self._scrub_jit(
-                dfull, dl, de, self._K_enc, self.params.rs_data,
-            )
-        self.last_submit_variant = "xla"
-        return out, full
+        # `compose`: the miss scatter and the resident loop, eager
+        # programs whose shapes follow the batch's miss count — the
+        # part of `adopt` with a stamp of its own
+        with self.obs.timeline.span(
+                "compose", self.span_track, miss_rows=len(miss_rows),
+                resident_rows=len(resident)) as sp, \
+                jax.named_scope("pool_compose"):
+            with self.obs.stage("h2d_transfer", "tpu"):
+                full = jnp.zeros((lanes, cols), dtype=jnp.uint8,
+                                 device=self.device)
+                if len(miss_rows):
+                    dm = self._to_device(
+                        np.ascontiguousarray(miss_arr[:len(miss_rows)]))
+                    idx = jax.device_put(
+                        np.asarray(miss_rows, dtype=np.int32), self.device)
+                    full = full.at[idx].set(dm)
+                dl = self._put(lengths)
+                de = self._put(expected)
+            # device-side composition of pool-resident lanes: no host
+            # bytes move here — pages are already device arrays
+            for r, pages, length in resident:
+                row = jnp.concatenate(list(pages))
+                if int(row.shape[0]) < cols:
+                    row = jnp.pad(row, (0, cols - int(row.shape[0])))
+                full = full.at[int(r)].set(row[:cols])
+        self.obs.note_substage("compose", sp.t1 - sp.t0)
+        return self._scrub_dispatch(full, dl, de), full
 
     def pool_adopt(self, input_ref, lane: int, length: int,
                    page_bytes: int):
@@ -938,11 +985,12 @@ class TpuCodec(BlockCodec):
         ZERO link bytes."""
         npages = max(1, -(-int(length) // int(page_bytes)))
         total = npages * int(page_bytes)
-        row = input_ref[int(lane)]
-        if int(row.shape[0]) < total:
-            row = jnp.pad(row, (0, total - int(row.shape[0])))
-        pages = row[:total].reshape(npages, int(page_bytes))
-        return [pages[i] for i in range(npages)]
+        with jax.named_scope("pool_adopt"):
+            row = input_ref[int(lane)]
+            if int(row.shape[0]) < total:
+                row = jnp.pad(row, (0, total - int(row.shape[0])))
+            pages = row[:total].reshape(npages, int(page_bytes))
+            return [pages[i] for i in range(npages)]
 
     def pool_read(self, pages, length: int) -> bytes:
         """D2H readback of a pooled block (tests/debug only), trimmed

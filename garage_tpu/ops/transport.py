@@ -75,6 +75,7 @@ import numpy as np
 
 from ..utils.cpuprof import register_thread, unregister_thread
 from ..utils.data import Hash
+from ..utils.timeline import clock_pair
 
 logger = logging.getLogger("garage_tpu.ops.transport")
 
@@ -227,7 +228,7 @@ class _Batch:
                  "t_enq", "t_pop", "t_stage0", "t_stage1", "t_adopt1",
                  "t_submit1", "t_ready", "compiled",
                  "pool_resident", "pool_adopt", "staged_payload",
-                 "prefetch")
+                 "prefetch", "track", "lanes")
 
     def __init__(self, kind: str, cls: str):
         self.kind = kind
@@ -263,6 +264,10 @@ class _Batch:
         self.pool_adopt: Optional[list] = None
         self.staged_payload: Optional[int] = None
         self.prefetch = False
+        # timeline track of the slot it is staged in, and the lanes it
+        # dispatches at (None where the staged shape has none)
+        self.track = "transport"
+        self.lanes: Optional[int] = None
 
 
 class DeviceTransport:
@@ -353,9 +358,6 @@ class DeviceTransport:
         self.link_busy_seconds = 0.0
         self._busy_since: Optional[float] = None
         self._queued_est = 0  # staged_est bytes still in the EDF heap
-        # wall↔monotonic offset for converting timeline stamps into the
-        # wall-clock span records the waterfall stores
-        self._mono_off = time.time_ns() - time.monotonic_ns()
 
         # stage-level link attribution (ISSUE 16): every batch and every
         # probe round trip decomposed into stage_copy/adopt/compile/
@@ -751,6 +753,9 @@ class DeviceTransport:
                 staged = self._stage(batch, slot)
             batch.t_stage1 = time.monotonic_ns()
             self._clear_device_stamps()
+            track = batch.track = f"slot{slot}"
+            if hasattr(self.device, "span_track"):
+                self.device.span_track = track
             with self.obs.stage("device_submit", "tpu"):
                 handle = self._submit(batch, staged)
             batch.t_submit1 = time.monotonic_ns()
@@ -763,7 +768,6 @@ class DeviceTransport:
             self.link_busy_seconds += (batch.t_submit1
                                        - batch.t_stage0) / 1e9
             tl = self.obs.timeline
-            track = f"slot{slot}"
             tl.event(f"stage {batch.kind}", track, batch.t_stage0,
                      batch.t_stage1, cat="transport", cls=batch.cls,
                      blocks=batch.blocks, staged_est=batch.staged_est,
@@ -773,12 +777,13 @@ class DeviceTransport:
                                 else None))
             tl.event(f"adopt {batch.kind}", track, batch.t_stage1,
                      batch.t_adopt1, cat="transport")
-            variant = getattr(self.device, "last_submit_variant", None)
+            variant = (getattr(self.device, "last_submit_variant", None)
+                       if batch.kind == "scrub" else None)
+            shape = self._staged_shape(batch.kind, staged)
+            batch.lanes = shape[0] if shape else None
             tl.event(f"submit {batch.kind}", track, batch.t_adopt1,
                      batch.t_submit1, cat="transport",
-                     compiled=batch.compiled,
-                     shape=self._staged_shape(batch.kind, staged),
-                     variant=variant if batch.kind == "scrub" else None)
+                     compiled=batch.compiled, shape=shape, variant=variant)
             with self._cond:
                 if not self._inflight and self._busy_since is None:
                     self._busy_since = time.monotonic()
@@ -854,13 +859,14 @@ class DeviceTransport:
                                exc_info=True)
         self.obs.add_bytes("tpu", batch.nbytes)
         tl = self.obs.timeline
-        track = f"slot{slot}"
+        track = batch.track
         if batch.t_submit1 and batch.t_ready > batch.t_submit1:
             # device-busy window: dispatch return → results ready (the
             # block_until_ready delta, observed inside _collect)
             tl.event(f"compute {batch.kind}", track, batch.t_submit1,
                      batch.t_ready, cat="transport",
-                     prefetch=batch.prefetch)
+                     prefetch=batch.prefetch, variant=variant,
+                     lanes=batch.lanes)
         tl.event(f"collect {batch.kind}", track, batch.t_ready or t_c0,
                  t_c1, cat="transport", blocks=batch.blocks)
         if batch.t_stage0:
@@ -885,7 +891,10 @@ class DeviceTransport:
         tracer = self.obs.tracer
         if tracer is None:
             return
-        off = self._mono_off
+        # the waterfall stores wall-clock span records: the ring's stamps
+        # move over by the two clocks read back to back
+        pair = clock_pair()
+        off = pair["time_ns"] - pair["monotonic_ns"]
         seen = set()
         for part in batch.parts:
             it = part.item
@@ -1206,16 +1215,23 @@ class DeviceTransport:
                 # adopt VERIFIED miss lanes only: a lane that failed
                 # its hash check must never become a servable page
                 page = pool.page_bytes
-                for r, key, n in batch.pool_adopt:
-                    if not bool(ok[r]):
-                        continue
-                    try:
-                        pages = dev.pool_adopt(input_ref, r, n, page)
-                    except Exception:  # noqa: BLE001 — adoption is best-effort
-                        logger.warning("pool adoption failed",
-                                       exc_info=True)
-                        break
-                    pool.adopt(key, pages, n)
+                # `pool adopt`: every adoption of this collect, eager
+                # slices on the device — the part of `collect` with a
+                # stamp of its own
+                with self.obs.timeline.span("pool adopt", batch.track,
+                                            lanes=0) as sp:
+                    for r, key, n in batch.pool_adopt:
+                        if not bool(ok[r]):
+                            continue
+                        try:
+                            pages = dev.pool_adopt(input_ref, r, n, page)
+                        except Exception:  # noqa: BLE001 — best-effort
+                            logger.warning("pool adoption failed",
+                                           exc_info=True)
+                            break
+                        pool.adopt(key, pages, n)
+                        sp.args["lanes"] += 1
+                self.obs.note_substage("pool_adopt", sp.t1 - sp.t0)
             k = max(1, self.params.rs_data)
             results = []
             for part, (o, n) in zip(batch.parts, spans):
