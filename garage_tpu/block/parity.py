@@ -59,6 +59,12 @@ class ParityStore:
         )
         self.dir = os.path.join(root, "parity")
         self.all_dirs = [os.path.join(d.path, "parity") for d in dirs]
+        metrics = getattr(getattr(manager, "system", None), "metrics", None)
+        self.m_bytes = None if metrics is None else metrics.counter(
+            "parity_sidecar_bytes_total",
+            "Bytes of the codewords put to the parity store, written or "
+            "found and refreshed: part=parity is m x the longest member, "
+            "part=covered the members' own lengths")
 
     # --- write path (scrub) ------------------------------------------------
 
@@ -116,6 +122,9 @@ class ParityStore:
     def _put_codeword(self, hashes, lengths, parity) -> bool:
         k = self.codec.params.rs_data
         assert 0 < len(hashes) <= k, (len(hashes), k)
+        if self.m_bytes is not None:
+            self.m_bytes.inc(int(parity.nbytes), part="parity")
+            self.m_bytes.inc(int(sum(lengths)), part="covered")
         gid = self._gid(k, int(parity.shape[0]), hashes)
         existing = self._find_group_path(bytes(gid))
         if existing is not None:

@@ -242,7 +242,8 @@ class ScrubWorker(Worker):
         # counters it is flushed into after every batch
         self._acct: Optional[_PassAccount] = None
         metrics = getattr(getattr(manager, "system", None), "metrics", None)
-        self.m_segments = self.m_passes = self.m_bytes = None
+        self.m_segments = self.m_passes = None
+        self.m_bytes = self.m_blocks = None
         if metrics is not None:
             self.m_segments = metrics.counter(
                 "scrub_pass_seconds_total",
@@ -255,6 +256,10 @@ class ScrubWorker(Worker):
                 "scrub_verified_bytes_total",
                 "Block bytes the scrub handed to the codec (parity carry "
                 "lanes, which are hashed again, not counted)")
+            self.m_blocks = metrics.counter(
+                "scrub_verified_blocks_total",
+                "Blocks the scrub handed to the codec: the lanes behind "
+                "scrub_verified_bytes_total")
 
     def _roots(self) -> List[str]:
         return [d.path for d in self.manager.data_layout.data_dirs]
@@ -570,6 +575,7 @@ class ScrubWorker(Worker):
                 self._acct.bytes += nbytes
             if self.m_bytes is not None:
                 self.m_bytes.inc(nbytes)
+                self.m_blocks.inc(len(plain_blocks))
             # span per fused dispatch: a slow batch (gated link, mid-pass
             # XLA compile, CPU steal) shows up in the slow-op log even on
             # nodes with no trace_sink configured

@@ -141,6 +141,22 @@ def u32_view_bytes(x_u32: jax.Array) -> jax.Array:
     return parts.reshape(x_u32.shape[:-1] + (-1,))
 
 
+def host_words(x_u8: np.ndarray) -> np.ndarray:
+    """Host uint8 (..., 4n) as uint32 (..., n) in bytes_view_u32's byte
+    order, before it goes to the device: a numpy view.  Outside a jit
+    the device-side views are two eager programs a width each, and the
+    chip's compiler takes minutes over u32_view_bytes's reshape (163 s
+    for one heal of a short block inside a scrub pass: PERF.md, PR 29);
+    every width a ragged store brings would pay that again."""
+    return np.ascontiguousarray(x_u8).view("<u4")
+
+
+def host_bytes(x_u32) -> np.ndarray:
+    """A device uint32 (..., n) array on the host as uint8 (..., 4n), in
+    u32_view_bytes's byte order: the D2H copy and a numpy view."""
+    return np.asarray(x_u32).astype("<u4", copy=False).view(np.uint8)
+
+
 def verify_kernel(data_u8: jax.Array, lengths: jax.Array, expected: jax.Array):
     """Batched hash + compare: returns ((B,8) digests, (B,) ok, scalar
     corrupt-count) — the scrub hot op."""
@@ -490,27 +506,28 @@ class TpuCodec(BlockCodec):
     def encode_submit(self, groups: np.ndarray):
         """Enqueue RS parity for staged (B, k, S) codeword groups
         without synchronizing (S must be a multiple of 4 — guaranteed
-        by staging_geometry's bucketing).  Returns a lazily-viewed
-        device array; np.asarray (encode_collect) is the sync."""
+        by staging_geometry's bucketing).  Returns the parity as the
+        device's uint32 words; encode_collect is the sync and views
+        them as bytes on the host."""
         assert groups.shape[-1] % 4 == 0, groups.shape
         with self.obs.stage("h2d_transfer", "tpu"):
-            u32 = bytes_view_u32(self._to_device(
+            u32 = self._to_device(host_words(
                 groups.reshape(-1, groups.shape[-2], groups.shape[-1])))
         self._mark_adopt()
         with self._dispatching("encode"), \
                 self.obs.stage("kernel_dispatch", "tpu"):
-            return u32_view_bytes(self._gf_submit(u32, self._K_enc,
-                                                  self._enc_mat))
+            return self._gf_submit(u32, self._K_enc, self._enc_mat)
 
     def encode_collect(self, handle) -> np.ndarray:
         self._mark_ready(handle)
-        return np.asarray(handle)
+        return host_bytes(handle)
 
     def decode_submit(self, shards: np.ndarray, present: Sequence[int],
                       rows: Optional[Sequence[int]] = None):
         """Enqueue one survivor-pattern decode over staged (B, p, S)
         shards without synchronizing; shares rs_reconstruct's
-        mask-constant schedule cache."""
+        mask-constant schedule cache.  → (uint32 words on the device, S)
+        for decode_collect."""
         k, m = self.params.rs_data, self.params.rs_parity
         key = (tuple(present[:k]), tuple(rows) if rows is not None else None)
         cached = self._decode_w_cache.get(key)
@@ -527,19 +544,19 @@ class TpuCodec(BlockCodec):
         if pad:
             sub = np.pad(sub, [(0, 0)] * (sub.ndim - 1) + [(0, pad)])
         with self.obs.stage("h2d_transfer", "tpu"):
-            u32 = bytes_view_u32(self._to_device(
-                np.ascontiguousarray(sub)))
+            u32 = self._to_device(host_words(sub))
         self._mark_adopt()
         with self._dispatching("decode"), \
                 self.obs.stage("kernel_dispatch", "tpu"):
-            return u32_view_bytes(self._gf_submit(u32, K, dec_mat))[..., :s]
+            return self._gf_submit(u32, K, dec_mat), s
 
     def decode_collect(self, handle) -> np.ndarray:
         """Ready-stamped decode materialization (the transport prefers
         this over a bare np.asarray so `compute` vs `collect` split
         holds for decode batches too)."""
-        self._mark_ready(handle)
-        return np.asarray(handle)
+        words, s = handle
+        self._mark_ready(words)
+        return host_bytes(words)[..., :s]
 
     # --- hashing ---
     @staticmethod
@@ -656,12 +673,12 @@ class TpuCodec(BlockCodec):
         pad = (-s) % 4
         if pad:
             flat = np.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, pad)])
-        u32 = bytes_view_u32(jax.device_put(flat, self.device))
+        u32 = jax.device_put(host_words(flat), self.device)
         if mat is not None:
             pg = self._pallas_for(mat)
             if pg is not None:
                 try:
-                    out = np.asarray(u32_view_bytes(pg(u32)))[..., :s]
+                    out = host_bytes(pg(u32))[..., :s]
                     # reset only after the host-side materialization
                     # proved the kernel ran (same rule as the fused
                     # latch, round-5 ADVICE #1)
@@ -703,8 +720,7 @@ class TpuCodec(BlockCodec):
                                 "(%d/%d); will retry",
                                 self._pallas_transient_fails,
                                 PALLAS_MAX_TRANSIENT_FAILS, exc_info=True)
-        out = u32_view_bytes(self._gf_xla(u32, K))
-        return np.asarray(out)[..., :s]
+        return host_bytes(self._gf_xla(u32, K))[..., :s]
 
     def rs_encode(self, data: np.ndarray) -> np.ndarray:
         assert data.shape[-2] == self.params.rs_data, data.shape

@@ -376,6 +376,12 @@ class DeviceTransport:
                 "Block bytes staged for the device by the transport, "
                 "labelled with the host copies each byte paid "
                 "(the zero-copy path stages exactly one)")
+            self.m_lane_bytes = metrics.counter(
+                "transport_lane_bytes_total",
+                "Bytes of the staging arrays handed to the device, by "
+                "batch kind: part=payload is block data, part=pad the "
+                "zeros that fill rows to the bucketed width and the "
+                "geometry's empty lanes")
             self.m_depth = metrics.gauge(
                 "transport_queue_depth",
                 "Batches waiting in the device transport queue, by class",
@@ -423,6 +429,7 @@ class DeviceTransport:
                             / self.budget_bytes))
         else:
             self.m_staged = self.m_depth = self.m_inflight = None
+            self.m_lane_bytes = None
 
     def device_busy_now(self) -> float:
         """Cumulative device-busy seconds including the open interval."""
@@ -749,9 +756,17 @@ class DeviceTransport:
         staged = None
         try:
             batch.t_stage0 = time.monotonic_ns()
+            payload0 = self.staged_bytes
             with self.obs.stage("host_staging", "tpu"):
                 staged = self._stage(batch, slot)
             batch.t_stage1 = time.monotonic_ns()
+            payload_bytes = self.staged_bytes - payload0
+            staged_bytes = self._slot_bytes(batch.kind, staged)
+            if self.m_lane_bytes is not None:
+                self.m_lane_bytes.inc(payload_bytes, kind=batch.kind,
+                                      part="payload")
+                self.m_lane_bytes.inc(staged_bytes - payload_bytes,
+                                      kind=batch.kind, part="pad")
             self._clear_device_stamps()
             track = batch.track = f"slot{slot}"
             if hasattr(self.device, "span_track"):
@@ -783,7 +798,9 @@ class DeviceTransport:
             batch.lanes = shape[0] if shape else None
             tl.event(f"submit {batch.kind}", track, batch.t_adopt1,
                      batch.t_submit1, cat="transport",
-                     compiled=batch.compiled, shape=shape, variant=variant)
+                     compiled=batch.compiled, shape=shape, variant=variant,
+                     lanes=batch.lanes, payload_bytes=payload_bytes,
+                     staged_bytes=staged_bytes)
             with self._cond:
                 if not self._inflight and self._busy_since is None:
                     self._busy_since = time.monotonic()
@@ -821,6 +838,15 @@ class DeviceTransport:
         if kind == "scrub":  # (arr | miss_arr, [miss_rows,] lengths, …)
             return [int(staged[-3].shape[0]), int(staged[0].shape[1])]
         return []
+
+    @staticmethod
+    def _slot_bytes(kind: str, staged) -> int:
+        """Bytes of the staging arrays a batch hands the device: what
+        `_stage` copied in (`staged_bytes` grows by it) and the pad
+        around it.  A pooled scrub stages its miss lanes only."""
+        if kind == "decode":
+            return sum(int(plan[0].nbytes) for plan in staged)
+        return int(staged[0].nbytes)
 
     def _collect_oldest(self) -> None:
         with self._cond:
