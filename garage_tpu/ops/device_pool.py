@@ -12,14 +12,35 @@ occupancy, in-place reuse) keyed by block hash, budgeted by
 (``max_device_staging_mib`` bounds bytes in flight; the pool bounds
 bytes at rest).
 
-Layout.  A block of ``length`` bytes spans ``ceil(length / page)``
-pages; the tail page is partially filled and zero-padded (ragged
-occupancy — the budget charges whole pages, so ``bytes_for(length)``
-is the page-rounded claim).  Pages are opaque device handles produced
-by the device codec's pool API (``pool_adopt`` slices them out of an
-already-submitted device batch — a device-side copy, ZERO link bytes)
-and composed back into batch lanes by
-``scrub_encode_submit_resident`` (again device-side).
+Layout.  The pool's bytes live in ONE device array of
+``pool_mib ÷ pool_page_kib`` pages (1,024 × 256 KiB as shipped), made
+by the device codec's ``pool_alloc`` when the first pooled batch needs
+it (the codec chooses how it lies there: ops/tpu_codec.py keeps words,
+a page whole tiles).  What lives on the host, under the pool's one
+lock, is the page table (block hash → the slots of its pages, in
+order) and the free list.  A block of ``length`` bytes spans ``ceil(length / page)`` slots;
+the tail page is partially filled and zero-padded (ragged occupancy —
+the budget charges whole pages, so ``bytes_for(length)`` is the
+page-rounded claim).
+
+Two programs move a batch's pages, each of a fixed shape, so that a
+batch costs one dispatch each way whatever its lanes hold:
+
+  - **compose** (at dispatch; ``scrub_encode_submit_resident``): the
+    ``lanes × cols`` batch is a gather from the array over the vector
+    ``row_index`` builds — ``lanes × pages_for(cols)`` slots, the
+    sentinel (one past the last slot) wherever a row has no page, which
+    reads as zeros — with the staged miss rows laid over it.
+  - **adopt** (at collect; ``adopt_lanes``): the verified miss lanes of
+    the composed batch, seen as the same ``lanes × pages_for(cols)``
+    pages, are scattered into the array over a vector of destination
+    slots, the sentinel (dropped) for every page that is not adopted.
+
+The array is never updated in place: ``adopt`` returns a new array and
+the pool swaps its reference under the lock (a 256 MiB copy is ~0.7 ms
+of a v5e's HBM, five times a pass).  So a batch composed earlier, a
+``read`` from another thread and an ``invalidate`` in between all see
+a whole array, and no donated buffer can be used after its donation.
 
 Integration (ops/transport.py).  The transport consults the pool
 while STAGING a scrub batch: a resident block's lane skips the host
@@ -32,7 +53,9 @@ never return clean — but strict invalidation keeps hits USEFUL:
 block delete, quarantine, rebalance-drop and overwrite all call
 ``invalidate`` synchronously before the operation acks
 (block/manager.py), so the pool never serves a page for a block the
-store no longer holds.
+store no longer holds.  ``invalidate`` is a page-table operation: the
+slots go back to the free list and only a later ``adopt`` writes them,
+after every batch composed before it on the device's one stream.
 
 Eviction clock.  LRU in SCRUB-CYCLE time, not wall time: ``tick()``
 advances once per scrub pass (block/repair.py), and entries untouched
@@ -41,11 +64,11 @@ whole working set during any long idle period even though the next
 pass needs exactly the same blocks; cycle LRU keeps "the blocks the
 last pass touched" resident however long the pass interval is.
 
-Thread-safety: one lock.  ``lookup``/``adopt`` run on the transport
-worker thread, ``invalidate`` on event-loop and disk worker threads,
-``tick`` on the scrub worker — all synchronous, all cheap (dict ops;
-page frees are reference drops, the device runtime reclaims
-asynchronously).
+Thread-safety: one lock.  ``lookup``/``adopt_lanes`` run on the
+transport worker thread (the only one that dispatches a pool program),
+``invalidate`` on event-loop and disk worker threads, ``tick`` on the
+scrub worker — all synchronous, all cheap (dict and list ops; adopt
+holds the lock over one asynchronous dispatch).
 """
 
 from __future__ import annotations
@@ -53,28 +76,41 @@ from __future__ import annotations
 import logging
 import threading
 from collections import OrderedDict
-from typing import List, Optional
+from typing import Optional, Sequence, Tuple
+
+import numpy as np
 
 logger = logging.getLogger("garage_tpu.ops.device_pool")
 
 
+def miss_bucket(n: int, lanes: int) -> int:
+    """Rows the `n` staged miss rows of a `lanes`-lane batch cross the
+    link as: the next multiple of an eighth of the batch (of 32 rows at
+    least), so that compose meets a closed set of shapes and the pad is
+    under an eighth of a whole batch's rows."""
+    if n <= 0:
+        return 0
+    quantum = max(32, lanes // 8)
+    return min(lanes, -(-n // quantum) * quantum)
+
+
+def miss_buckets(lanes: int) -> list:
+    """Every value `miss_bucket` takes for a `lanes`-lane batch."""
+    return sorted({miss_bucket(n, lanes) for n in range(lanes + 1)})
+
+
 class _PoolEntry:
-    """One resident block: its device pages plus the bookkeeping the
-    eviction clock needs."""
+    """One resident block: the slots of its pages in the device array,
+    in order, plus the bookkeeping the eviction clock needs."""
 
-    __slots__ = ("key", "length", "pages", "tick", "page_bytes")
+    __slots__ = ("key", "length", "slots", "tick")
 
-    def __init__(self, key: bytes, length: int, pages: List,
-                 tick: int, page_bytes: int):
+    def __init__(self, key: bytes, length: int, slots: Tuple[int, ...],
+                 tick: int):
         self.key = key
         self.length = length
-        self.pages = pages
+        self.slots = slots
         self.tick = tick  # scrub cycle of the last touch
-        self.page_bytes = page_bytes
-
-    @property
-    def charged_bytes(self) -> int:
-        return len(self.pages) * self.page_bytes
 
 
 class DevicePool:
@@ -85,6 +121,10 @@ class DevicePool:
         self.device = device
         self.pool_bytes = max(0, int(pool_bytes))
         self.page_bytes = max(1, int(page_bytes))
+        # the budget in whole pages: the slots of the device array, and
+        # the sentinel index (one past the last) that a gather reads as
+        # zeros and a scatter drops
+        self.npages = self.pool_bytes // self.page_bytes
         self.prefetch_enabled = bool(prefetch)
         self.obs = observer
         self._lock = threading.Lock()
@@ -93,6 +133,8 @@ class DevicePool:
         # used entry of the oldest cycle (cycle order within = touch
         # order — exactly what the scrub walk produces)
         self._entries: "OrderedDict[bytes, _PoolEntry]" = OrderedDict()
+        self._free = list(range(self.npages - 1, -1, -1))  # pop(): lowest
+        self._array = None  # the device's pages, from the first batch on
         self._resident_bytes = 0  # page-rounded (the budget currency)
         self._tick = 0
         # always-on accounting (admin `codec info` pool block + bench)
@@ -143,11 +185,12 @@ class DevicePool:
 
     @classmethod
     def supports_device(cls, device) -> bool:
-        """The device implements the pool API: resident-lane scrub
-        submission plus device-side page extraction/readback."""
-        return (hasattr(device, "scrub_encode_submit_resident")
-                and hasattr(device, "pool_adopt")
-                and hasattr(device, "pool_read"))
+        """The device implements the pool API: the page array, the
+        resident-lane scrub submission that composes from it, the
+        adoption into it and the readback."""
+        return all(hasattr(device, name) for name in (
+            "pool_alloc", "scrub_encode_submit_resident", "pool_adopt",
+            "pool_read"))
 
     # --- geometry -----------------------------------------------------------
 
@@ -159,6 +202,31 @@ class DevicePool:
     def bytes_for(self, length: int) -> int:
         """Page-rounded budget charge for a block of `length` bytes."""
         return self.pages_for(length) * self.page_bytes
+
+    def row_index(self, lanes: int, cols: int, rows) -> np.ndarray:
+        """The index vector of the two pool programs for a `lanes ×
+        cols` batch, whose rows they see as `pages_for(cols)` pages each
+        (a row narrower than a page is one, zero-extended): a slot for
+        every page of every row, in row order, the sentinel where there
+        is none (for compose a miss lane, a pad lane, the pages past a
+        short block; for adopt whatever is not adopted).  `rows`:
+        (lane, slots) of the lanes that have slots."""
+        per = self.pages_for(cols)
+        index = np.full((lanes * per,), self.npages, dtype=np.int32)
+        for lane, slots in rows:
+            index[lane * per:lane * per + len(slots)] = slots
+        return index
+
+    def array(self):
+        """The device's page array (made on first use)."""
+        with self._lock:
+            return self._array_locked()
+
+    def _array_locked(self):
+        if self._array is None:
+            self._array = self.device.pool_alloc(self.npages,
+                                                 self.page_bytes)
+        return self._array
 
     # --- the scrub-cycle clock ----------------------------------------------
 
@@ -212,40 +280,71 @@ class DevicePool:
                     return False
             return got_any
 
-    def adopt(self, key: bytes, pages: List, length: int) -> bool:
-        """Admit one block's device pages, evicting LRU entries until
-        the budget fits.  A block bigger than the whole budget is
-        refused (dropping the page refs frees them).  Re-adopting a
-        resident hash replaces the old pages — the overwrite shape of
-        strict invalidation."""
-        key = bytes(key)
-        need = len(pages) * self.page_bytes
-        if need > self.pool_bytes:
+    def adopt_lanes(self, batch, lanes: int, cols: int,
+                    verified: Sequence[Tuple[int, bytes, int]]
+                    ) -> Tuple[int, int]:
+        """Admit the VERIFIED miss lanes of one composed `lanes × cols`
+        device batch — (lane, key, length) each — with one device
+        program: every lane gets its slots from the free list, evicting
+        LRU entries until the budget fits, and the batch's pages are
+        scattered into them.  A block bigger than the whole budget is
+        refused.  Re-adopting a resident hash replaces the old pages —
+        the overwrite shape of strict invalidation.  → (lanes, pages)
+        adopted."""
+        with self._lock:
+            made = []
+            for lane, key, n in verified:
+                e = self._reserve_locked(bytes(key), int(n))
+                if e is not None:
+                    made.append((lane, e))
+            # a batch larger than the budget evicts its own first lanes
+            # for its last: what is still in the table holds its slots
+            # alone, so no slot is written twice
+            made = [(lane, e) for lane, e in made
+                    if self._entries.get(e.key) is e]
+            if not made:
+                return 0, 0
+            dst = self.row_index(lanes, cols,
+                                 [(lane, e.slots) for lane, e in made])
+            try:
+                self._array = self.device.pool_adopt(
+                    self._array_locked(), batch, dst)
+            except BaseException:
+                # a slot that was not written must not be served
+                for _lane, e in made:
+                    self._drop_locked(e.key, "invalidate")
+                raise
+            self.adopted += len(made)
+            return len(made), sum(len(e.slots) for _lane, e in made)
+
+    def _reserve_locked(self, key: bytes, length: int
+                        ) -> Optional[_PoolEntry]:
+        """The page-table half of an adoption: `key`'s entry with slots
+        of its own, or None (refused)."""
+        need = self.pages_for(length)
+        if need > self.npages:
             if self.obs is not None:
                 self.obs.event("pool_refuse", reason="over_budget",
-                               nbytes=need)
-            return False
-        with self._lock:
-            if key in self._entries:
-                self._drop_locked(key, "replace")
-            while (self._resident_bytes + need > self.pool_bytes
-                   and self._entries):
-                old_key = next(iter(self._entries))
-                self._drop_locked(old_key, "lru")
-                self.evicted_lru += 1
-            e = _PoolEntry(key, int(length), list(pages), self._tick,
-                           self.page_bytes)
-            self._entries[key] = e
-            self._resident_bytes += need
-            self.adopted += 1
-        return True
+                               nbytes=need * self.page_bytes)
+            return None
+        if key in self._entries:
+            self._drop_locked(key, "replace")
+        while len(self._free) < need:
+            self._drop_locked(next(iter(self._entries)), "lru")
+            self.evicted_lru += 1
+        slots = tuple(self._free.pop() for _ in range(need))
+        e = self._entries[key] = _PoolEntry(key, length, slots, self._tick)
+        self._resident_bytes += need * self.page_bytes
+        return e
 
     def _drop_locked(self, key: bytes, reason: str) -> None:
         e = self._entries.pop(key, None)
         if e is None:
             return
-        self._resident_bytes -= e.charged_bytes
-        e.pages = []  # the reference drop IS the device-side free
+        self._resident_bytes -= len(e.slots) * self.page_bytes
+        # the slots are free for a later adopt; their bytes stay until
+        # it overwrites them, and nothing reads them meanwhile
+        self._free.extend(reversed(e.slots))
         if self.m_evict is not None:
             self.m_evict.inc(reason=reason)
 
@@ -297,14 +396,14 @@ class DevicePool:
 
     def read(self, key: bytes) -> Optional[bytes]:
         """The resident block's bytes fetched back from its device
-        pages (D2H — test/debug surface, not a data path), trimmed to
-        the ragged tail.  None when not resident."""
+        pages (one gather + D2H — test/debug surface, not a data path),
+        trimmed to the ragged tail.  None when not resident."""
         with self._lock:
             e = self._entries.get(bytes(key))
             if e is None:
                 return None
-            pages, length = list(e.pages), e.length
-        return self.device.pool_read(pages, length)
+            array, slots, length = self._array, e.slots, e.length
+        return self.device.pool_read(array, slots, length)
 
     # --- introspection ------------------------------------------------------
 

@@ -129,6 +129,14 @@ class CodecObserver:
                 "(compose: one a resident dispatch; pool_adopt: one a "
                 "collect that adopted)",
             )
+            self._pool_programs = metrics.counter(
+                "pool_programs_total",
+                "Device programs the block pool dispatched, by op "
+                "(compose: one a resident dispatch; adopt: one a collect "
+                "that adopted; alloc: the page array, once); beside "
+                "transport_substage_calls_total this says how many "
+                "programs a batch costs",
+            )
             self._compile_n = metrics.counter(
                 "codec_compiles_total",
                 "Device programs JAX built or loaded from its persistent "
@@ -143,6 +151,7 @@ class CodecObserver:
         else:
             self._hist = self._bytes_ctr = self._event_ctr = None
             self._substage_s = self._substage_n = None
+            self._pool_programs = None
             self._compile_n = self._compile_s = None
 
     # --- events ---
@@ -198,6 +207,11 @@ class CodecObserver:
         if self._substage_s is not None:
             self._substage_s.inc(ns / 1e9, stage=stage)
             self._substage_n.inc(stage=stage)
+
+    def note_pool_program(self, op: str) -> None:
+        """One device program dispatched by the block pool."""
+        if self._pool_programs is not None:
+            self._pool_programs.inc(op=op)
 
     def note_compile(self, where: str, source: str, seconds: float) -> None:
         """One program built (`built`) or loaded from the persistent

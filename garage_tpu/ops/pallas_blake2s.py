@@ -164,19 +164,21 @@ def blake2s_words_pallas(msg, lengths, interpret: bool = False):
 def blake2s_batch_pallas(data_u8: jax.Array, lengths: jax.Array,
                          interpret: bool = False) -> jax.Array:
     """Drop-in for tpu_blake2s.blake2s_batch on lane counts divisible by
-    128: data_u8 (B, C*64) uint8 zero-padded messages, lengths (B,) true
-    byte counts → (B, 8) uint32 digests (little-endian word order).
+    128: data_u8 (B, C*64) uint8 zero-padded messages (or their (B, C*16)
+    uint32 words), lengths (B,) true byte counts → (B, 8) uint32 digests
+    (little-endian word order).
 
     Jittable; the (B, C, 16) → (C, 16, B/128, 128) word transpose runs
     as one XLA HBM pass feeding the kernel's streaming layout.
     """
-    bsz, total = data_u8.shape
-    assert total % 64 == 0 and total > 0
+    words = bytes_to_words(data_u8)
+    bsz, total = words.shape
+    assert total % 16 == 0 and total > 0
     assert lanes_supported(bsz), bsz
-    nchunks = total // 64
+    nchunks = total // 16
     rows = bsz // LANE
     msg = jnp.transpose(
-        bytes_to_words(data_u8).reshape(bsz, nchunks, 16), (1, 2, 0)
+        words.reshape(bsz, nchunks, 16), (1, 2, 0)
     ).reshape(nchunks, 16, rows, LANE)
     lanes = lengths.astype(jnp.uint32).reshape(rows, LANE)
     h = blake2s_words_pallas(msg, lanes, interpret=interpret)

@@ -34,6 +34,7 @@ from ..utils.data import Hash
 from . import compile_listener, gf256
 from .codec import BlockCodec, CodecParams
 from .compile_cache import ensure_compile_cache
+from .device_pool import miss_buckets
 from .tpu_blake2s import blake2s_batch, digests_to_bytes
 
 # --- pure jittable kernels --------------------------------------------------
@@ -198,6 +199,60 @@ def scrub_fused_xla(data_u8, lengths, expected, K_enc, k: int):
     return scrub_step_kernel(data_u8, lengths, expected, K_enc, k)
 
 
+# The device pool's programs (ops/device_pool.py).  Everything is
+# uint32 words: uint8 on the device costs the chip's compiler 10 s a
+# program and minutes for a view (PERF.md, PRs 29 and 30), and both
+# kernels hash and encode words.  `pool` is the one array of pages,
+# (npages, page words ÷ 128, 128): a page is whole (8, 128) tiles, so a
+# gather or a scatter of pages moves contiguous memory (2.9 and 4.1 ms
+# for 256 MiB on a v5e against 5.6 and 19.3 with pages as rows of a 2-D
+# array; PERF.md, PR 30).  A batch is (lanes, cols ÷ 4) words.  An index
+# equal to npages is the sentinel: a gather reads zeros there and a
+# scatter drops the page.
+
+POOL_TILE = 128     # words: a page is a whole number of these
+
+
+def pool_alloc(npages: int, words: int):
+    return jnp.zeros((npages, words // POOL_TILE, POOL_TILE),
+                     dtype=jnp.uint32)
+
+
+def _as_pages(rows, per: int, pool):
+    """(n, cols) rows as (n · per, …) pages shaped like the pool's,
+    each row zero-extended to `per` whole pages."""
+    words = pool.shape[1] * pool.shape[2]
+    if per * words != rows.shape[1]:
+        rows = jnp.pad(rows, ((0, 0), (0, per * words - rows.shape[1])))
+    return rows.reshape((rows.shape[0] * per,) + pool.shape[1:])
+
+
+def pool_compose(pool, row_pages, miss, miss_rows, lanes: int, cols: int):
+    """The (lanes, cols) batch: row r is the pages row_pages[r·per :
+    (r+1)·per] of the pool, and row miss_rows[i] is miss[i] (staged
+    rows; an index of `lanes` pads the bucket and is dropped)."""
+    with jax.named_scope("pool_compose"):
+        per = row_pages.shape[0] // lanes
+        pages = jnp.take(pool, row_pages, axis=0, mode="fill", fill_value=0)
+        if miss is not None:
+            at = miss_rows[:, None] * per + jnp.arange(per, dtype=jnp.int32)
+            pages = pages.at[at.reshape(-1)].set(
+                _as_pages(miss, per, pool), mode="drop")
+        return pages.reshape(lanes, -1)[:, :cols]
+
+
+def pool_adopt(pool, batch, dst):
+    """The pool with page j of the batch (its rows seen as `per` pages
+    each, zero-extended) written to slot dst[j]."""
+    with jax.named_scope("pool_adopt"):
+        per = dst.shape[0] // batch.shape[0]
+        return pool.at[dst].set(_as_pages(batch, per, pool), mode="drop")
+
+
+def pool_gather(pool, slots):
+    return jnp.take(pool, slots, axis=0, mode="fill", fill_value=0)
+
+
 # --- codec ------------------------------------------------------------------
 
 
@@ -291,6 +346,12 @@ class TpuCodec(BlockCodec):
         # the timeline track of this codec's spans (`compose`, `submit
         # <kind>`): the transport names the slot it submits from
         self.span_track = "device"
+        # the device pool's closed set of programs, compiled ahead of
+        # their first dispatch and called as executables: nothing is
+        # traced on the transport thread (`_pool_program`)
+        self._pool_execs: dict = {}
+        self._pool_geom: Optional[Tuple[int, int]] = None
+        self._pool_read_jit = jax.jit(pool_gather)
         compile_listener.attach(self.obs)
         if self.obs.timeline.annotate is None:
             # every timeline span is in the profiler's trace too, as
@@ -898,6 +959,8 @@ class TpuCodec(BlockCodec):
         # means a multi-second mid-pass compile on a remote backend —
         # exactly what warm() exists to prevent
         self._scrub_jit.lower(*shapes, k).compile()
+        if self._pool_geom is not None:
+            self.pool_warm(bsz, padded)
 
     def scrub_encode_submit(self, arr: np.ndarray, lengths: np.ndarray,
                             expected: np.ndarray):
@@ -950,69 +1013,126 @@ class TpuCodec(BlockCodec):
     # --- the DevicePool API (ops/device_pool.py) ---
     #
     # Pool-aware scrub: only MISS lanes cross the link (one compact
-    # H2D upload + a device-side scatter); resident lanes are composed
-    # from pool pages — device-resident jnp arrays — entirely on
-    # device.  The composed batch runs the SAME fused kernel as the
-    # plain path, so pool-served lanes are re-verified against their
-    # expected digests on every read.
+    # H2D upload, its row count bucketed); resident lanes are gathered
+    # from the pool's page array on the device.  One program composes a
+    # batch and one adopts its verified misses, each of a shape fixed by
+    # the batch's geometry and its miss bucket: a closed set, compiled
+    # ahead and dispatched as executables.  The composed batch runs the
+    # SAME fused kernel as the plain path, so pool-served lanes are
+    # re-verified against their expected digests on every read.
+
+    def pool_alloc(self, npages: int, page_bytes: int):
+        """The pool's pages, zeroed, on the codec's (first) device."""
+        if page_bytes % (4 * POOL_TILE):
+            raise ValueError(f"a pool page is whole tiles of {4 * POOL_TILE} "
+                             f"bytes, not {page_bytes}")
+        self._pool_geom = (int(npages), int(page_bytes))
+        return self._pool_dispatch(("alloc",) + self._pool_geom)
+
+    def pool_program_keys(self, lanes: int, cols: int) -> List[tuple]:
+        """The pool programs a (lanes, cols) batch can dispatch: one
+        adopt, and one compose for every miss bucket."""
+        geom = self._pool_geom + (int(lanes), int(cols))
+        return [("adopt",) + geom] + [
+            ("compose",) + geom + (mb,) for mb in miss_buckets(lanes)]
+
+    def pool_warm(self, lanes: int, cols: int) -> None:
+        """Compile every pool program of a (lanes, cols) batch."""
+        for key in self.pool_program_keys(lanes, cols):
+            self._pool_program(key)
+
+    def _pool_program(self, key: tuple):
+        """The compiled executable of one member of the closed set."""
+        exe = self._pool_execs.get(key)
+        if exe is None:
+            from jax.sharding import SingleDeviceSharding
+
+            exe = self._pool_execs[key] = self.pool_lowered(
+                key, SingleDeviceSharding(self.device)).compile()
+        return exe
+
+    @staticmethod
+    def pool_lowered(key: tuple, sharding):
+        """One pool program, lowered for arrays placed by `sharding`."""
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        op, npages, page = key[:3]
+        pool = spec((npages, page // 4 // POOL_TILE, POOL_TILE), jnp.uint32)
+        if op == "alloc":
+            return jax.jit(pool_alloc, static_argnums=(0, 1),
+                           out_shardings=sharding).lower(npages, page // 4)
+        lanes, cols = key[3:5]
+        index = spec((lanes * max(1, -(-cols // page)),), jnp.int32)
+        if op == "adopt":
+            return jax.jit(pool_adopt).lower(
+                pool, spec((lanes, cols // 4), jnp.uint32), index)
+        mb = key[5]
+        miss = (spec((mb, cols // 4), jnp.uint32),
+                spec((mb,), jnp.int32)) if mb else (None, None)
+        return jax.jit(pool_compose, static_argnums=(4, 5)).lower(
+            pool, index, *miss, lanes, cols // 4)
+
+    def _pool_dispatch(self, key: tuple, *args):
+        self.obs.note_pool_program(key[0])
+        return self._pool_program(key)(*args)
 
     def scrub_encode_submit_resident(self, miss_arr: np.ndarray,
                                      miss_rows, lengths: np.ndarray,
-                                     expected: np.ndarray, resident):
-        """Returns (scrub handle, composed device input) — the input
-        ref is what pool_adopt slices verified miss lanes out of."""
+                                     expected: np.ndarray, pool,
+                                     row_pages: np.ndarray):
+        """`miss_arr`: the staged miss rows, (miss bucket, cols), of
+        which the first len(miss_rows) go to the lanes `miss_rows`;
+        `pool`: the page array; `row_pages`: the slot of every page of
+        every row (DevicePool.row_index).  Returns (scrub handle,
+        composed device input) — the input ref, (lanes, cols ÷ 4)
+        words, is what pool_adopt takes verified miss lanes from."""
         lanes = int(lengths.shape[0])
-        cols = int(miss_arr.shape[1])
+        mb, cols = (int(d) for d in miss_arr.shape)
         assert lanes % self.params.rs_data == 0
         assert cols % 4 == 0
-        # `compose`: the miss scatter and the resident loop, eager
-        # programs whose shapes follow the batch's miss count — the
-        # part of `adopt` with a stamp of its own
+        # a row the pool serves has a slot for its first page
+        resident_rows = int(np.count_nonzero(
+            row_pages.reshape(lanes, -1)[:, 0] < pool.shape[0]))
+        compose = ("compose",) + self._pool_geom + (lanes, cols, mb)
+        # `compose`: the part of `adopt` with a stamp of its own; the
+        # first batch of a geometry builds that geometry's programs here
         with self.obs.timeline.span(
                 "compose", self.span_track, miss_rows=len(miss_rows),
-                resident_rows=len(resident)) as sp, \
-                jax.named_scope("pool_compose"):
+                resident_rows=resident_rows) as sp:
+            if compose not in self._pool_execs:
+                self.pool_warm(lanes, cols)
             with self.obs.stage("h2d_transfer", "tpu"):
-                full = jnp.zeros((lanes, cols), dtype=jnp.uint8,
-                                 device=self.device)
-                if len(miss_rows):
-                    dm = self._to_device(
-                        np.ascontiguousarray(miss_arr[:len(miss_rows)]))
-                    idx = jax.device_put(
-                        np.asarray(miss_rows, dtype=np.int32), self.device)
-                    full = full.at[idx].set(dm)
+                miss = (None, None)
+                if mb:
+                    rows = np.full((mb,), lanes, dtype=np.int32)
+                    rows[:len(miss_rows)] = miss_rows
+                    miss = (self._to_device(host_words(miss_arr)),
+                            jax.device_put(rows, self.device))
+                index = jax.device_put(row_pages, self.device)
                 dl = self._put(lengths)
                 de = self._put(expected)
-            # device-side composition of pool-resident lanes: no host
-            # bytes move here — pages are already device arrays
-            for r, pages, length in resident:
-                row = jnp.concatenate(list(pages))
-                if int(row.shape[0]) < cols:
-                    row = jnp.pad(row, (0, cols - int(row.shape[0])))
-                full = full.at[int(r)].set(row[:cols])
+            full = self._pool_dispatch(compose, pool, index, *miss)
         self.obs.note_substage("compose", sp.t1 - sp.t0)
         return self._scrub_dispatch(full, dl, de), full
 
-    def pool_adopt(self, input_ref, lane: int, length: int,
-                   page_bytes: int):
-        """Slice one verified lane of a resident-submitted batch into
-        fixed-size device pages (tail zero-padded past the ragged
-        length) — device-side slicing of an already-resident array,
-        ZERO link bytes."""
-        npages = max(1, -(-int(length) // int(page_bytes)))
-        total = npages * int(page_bytes)
-        with jax.named_scope("pool_adopt"):
-            row = input_ref[int(lane)]
-            if int(row.shape[0]) < total:
-                row = jnp.pad(row, (0, total - int(row.shape[0])))
-            pages = row[:total].reshape(npages, int(page_bytes))
-            return [pages[i] for i in range(npages)]
+    def pool_adopt(self, pool, batch, dst: np.ndarray):
+        """The pool's array with the pages of the composed `batch`
+        written to the slots `dst` names (DevicePool.adopt_lanes) —
+        one device program, ZERO link bytes but the index vector."""
+        lanes, words = (int(d) for d in batch.shape)
+        return self._pool_dispatch(
+            ("adopt",) + self._pool_geom + (lanes, 4 * words),
+            pool, batch, jax.device_put(dst, self.device))
 
-    def pool_read(self, pages, length: int) -> bytes:
-        """D2H readback of a pooled block (tests/debug only), trimmed
-        to the ragged tail."""
-        return np.concatenate(
-            [np.asarray(p) for p in pages])[:int(length)].tobytes()
+    def pool_read(self, pool, slots, length: int) -> bytes:
+        """D2H readback of a pooled block (tests/debug only): one
+        gather of its pages, trimmed to the ragged tail."""
+        index = np.full((self._bucket(len(slots), 1),), pool.shape[0],
+                        dtype=np.int32)
+        index[:len(slots)] = slots
+        pages = self._pool_read_jit(pool, jax.device_put(index, self.device))
+        return host_bytes(pages).reshape(-1)[:int(length)].tobytes()
 
     def scrub_encode_batch(self, blocks: Sequence[bytes], hashes: Sequence[Hash],
                            fetch_parity: bool = True):

@@ -44,10 +44,11 @@ One DeviceTransport per device codec owns ALL host↔device movement:
     the retired serialize+copy path's.
   - **Device-resident pool.**  When a DevicePool is attached
     (ops/device_pool.py), scrub staging consults it first: resident
-    blocks ship as device page references and move ZERO link bytes
-    (``pool_hit_bytes_total``), misses stage through the slot path and
-    their verified lanes are adopted into the pool at collect
-    (``pool_miss_bytes_total``); ``prefetch`` stages the scrub
+    blocks ship as the slots of their pages in the pool's device array
+    and move ZERO link bytes (``pool_hit_bytes_total``), misses stage
+    through the slot path and their verified lanes are adopted into the
+    pool at collect (``pool_miss_bytes_total``), one device program a
+    batch each way; ``prefetch`` stages the scrub
     worker's next range ahead of need as background-class work.
 
 Failure containment: a device failure never fails the caller — the
@@ -76,6 +77,7 @@ import numpy as np
 from ..utils.cpuprof import register_thread, unregister_thread
 from ..utils.data import Hash
 from ..utils.timeline import clock_pair
+from .device_pool import miss_bucket
 
 logger = logging.getLogger("garage_tpu.ops.transport")
 
@@ -227,7 +229,8 @@ class _Batch:
                  "cls", "want_parity", "ts", "staged_est",
                  "t_enq", "t_pop", "t_stage0", "t_stage1", "t_adopt1",
                  "t_submit1", "t_ready", "compiled",
-                 "pool_resident", "pool_adopt", "staged_payload",
+                 "pool_rows", "pool_hits", "pool_adopt", "pool_shape",
+                 "staged_payload",
                  "prefetch", "track", "lanes")
 
     def __init__(self, kind: str, cls: str):
@@ -256,12 +259,16 @@ class _Batch:
         self.t_ready = 0
         self.compiled = False  # did this dispatch trigger an XLA compile
         # DevicePool bookkeeping (None = staged the legacy, pool-less
-        # way): resident lanes composed device-side, miss lanes to
-        # adopt at collect, and the bytes that actually crossed the
+        # way): the pool slots of every row's pages (resident lanes are
+        # composed device-side from them), how many lanes that serves,
+        # the miss lanes to adopt at collect out of the composed
+        # (lanes, cols) batch, and the bytes that actually crossed the
         # link (what transport_staged_bytes_total must count — pool
         # hits move zero)
-        self.pool_resident: Optional[list] = None
+        self.pool_rows: Optional[np.ndarray] = None
+        self.pool_hits: Optional[int] = None
         self.pool_adopt: Optional[list] = None
+        self.pool_shape: Optional[tuple] = None
         self.staged_payload: Optional[int] = None
         self.prefetch = False
         # timeline track of the slot it is staged in, and the lanes it
@@ -787,9 +794,7 @@ class DeviceTransport:
                      batch.t_stage1, cat="transport", cls=batch.cls,
                      blocks=batch.blocks, staged_est=batch.staged_est,
                      prefetch=batch.prefetch,
-                     pool_hits=(len(batch.pool_resident)
-                                if batch.pool_resident is not None
-                                else None))
+                     pool_hits=batch.pool_hits)
             tl.event(f"adopt {batch.kind}", track, batch.t_stage1,
                      batch.t_adopt1, cat="transport")
             variant = (getattr(self.device, "last_submit_variant", None)
@@ -1133,8 +1138,11 @@ class DeviceTransport:
         geometry (k-aligned parts, lane-indexed spans/lengths/expected
         — so parity grouping and collect-side slicing are unchanged),
         but only MISS lanes pay the host copy, written compactly into
-        the slot's first rows; resident lanes ship as device page
-        references (zero link bytes).  Returns
+        the slot's first rows; resident lanes ship as the slots of
+        their pool pages (zero link bytes).  The rows handed over are
+        the miss count's bucket (device_pool.miss_bucket), so that the
+        device meets a closed set of shapes: the rows past the misses
+        are pad, sent and dropped.  Returns
         (miss_arr, miss_rows, lengths, expected, spans) for
         scrub_encode_submit_resident."""
         pool = self.pool
@@ -1155,7 +1163,7 @@ class DeviceTransport:
         lengths = np.zeros((lanes,), dtype=np.int32)
         expected = np.broadcast_to(
             _empty_digest_words(), (lanes, 8)).astype(np.uint32)
-        resident: list = []   # (lane, pages, length) composed on device
+        resident: list = []   # (lane, slots) composed on device
         adopt: list = []      # (lane, key, length) adopted at collect
         miss_rows: List[int] = []
         hit_bytes = miss_bytes = 0
@@ -1166,7 +1174,7 @@ class DeviceTransport:
             expected[r] = np.frombuffer(bytes(hh), dtype="<u4")
             entry = pool.lookup(bytes(hh), n)
             if entry is not None:
-                resident.append((r, entry.pages, n))
+                resident.append((r, entry.slots))
                 hit_bytes += n
                 continue
             # THE host copy, miss lanes only (tail zeroed: the slot
@@ -1192,10 +1200,13 @@ class DeviceTransport:
                 pool.note_miss(miss_bytes)
         elif miss_bytes:
             pool.note_miss(miss_bytes, prefetch=True)
-        batch.pool_resident = resident
+        batch.pool_rows = pool.row_index(lanes, cols, resident)
+        batch.pool_hits = len(resident)
         batch.pool_adopt = adopt
+        batch.pool_shape = (lanes, cols)
         batch.staged_payload = miss_bytes
-        return arr[:ci], miss_rows, lengths, expected, spans
+        return (arr[:miss_bucket(ci, lanes)], miss_rows, lengths, expected,
+                spans)
 
     # --- device dispatch / collect ------------------------------------------
 
@@ -1210,7 +1221,7 @@ class DeviceTransport:
                 miss_arr, miss_rows, lengths, expected, spans = staged
                 return dev.scrub_encode_submit_resident(
                     miss_arr, miss_rows, lengths, expected,
-                    batch.pool_resident), spans
+                    self.pool.array(), batch.pool_rows), spans
             arr, lengths, expected, spans = staged
             return dev.scrub_encode_submit(arr, lengths, expected), spans
         if kind == "encode":
@@ -1239,24 +1250,20 @@ class DeviceTransport:
             if (pool is not None and batch.pool_adopt
                     and input_ref is not None):
                 # adopt VERIFIED miss lanes only: a lane that failed
-                # its hash check must never become a servable page
-                page = pool.page_bytes
-                # `pool adopt`: every adoption of this collect, eager
-                # slices on the device — the part of `collect` with a
-                # stamp of its own
+                # its hash check must never become a servable page.
+                # `pool adopt`: one device program for all of them —
+                # the part of `collect` with a stamp of its own
                 with self.obs.timeline.span("pool adopt", batch.track,
-                                            lanes=0) as sp:
-                    for r, key, n in batch.pool_adopt:
-                        if not bool(ok[r]):
-                            continue
-                        try:
-                            pages = dev.pool_adopt(input_ref, r, n, page)
-                        except Exception:  # noqa: BLE001 — best-effort
-                            logger.warning("pool adoption failed",
-                                           exc_info=True)
-                            break
-                        pool.adopt(key, pages, n)
-                        sp.args["lanes"] += 1
+                                            lanes=0, pages=0) as sp:
+                    verified = [(r, key, n) for r, key, n
+                                in batch.pool_adopt if ok[r]]
+                    try:
+                        sp.args["lanes"], sp.args["pages"] = \
+                            pool.adopt_lanes(input_ref, *batch.pool_shape,
+                                             verified)
+                    except Exception:  # noqa: BLE001 — best-effort
+                        logger.warning("pool adoption failed",
+                                       exc_info=True)
                 self.obs.note_substage("pool_adopt", sp.t1 - sp.t0)
             k = max(1, self.params.rs_data)
             results = []

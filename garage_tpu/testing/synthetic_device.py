@@ -308,9 +308,13 @@ class SyntheticLinkCodec:
     # kernel as the plain path, so pool-served lanes are re-verified
     # against their expected digests on every read.
 
+    def pool_alloc(self, npages: int, page_bytes: int) -> np.ndarray:
+        return np.zeros((npages, page_bytes), dtype=np.uint8)
+
     def scrub_encode_submit_resident(self, miss_arr: np.ndarray,
                                      miss_rows, lengths: np.ndarray,
-                                     expected: np.ndarray, resident):
+                                     expected: np.ndarray, pool,
+                                     row_pages: np.ndarray):
         lanes = int(lengths.shape[0])
         cols = int(miss_arr.shape[1])
         miss_bytes = int(sum(int(lengths[r]) for r in miss_rows))
@@ -318,36 +322,34 @@ class SyntheticLinkCodec:
         self.bytes_submitted += miss_bytes
         self._mark_adopt("scrub", (lanes, cols))
         ready = self._link_ready_at(miss_bytes)
-        # device-side composition: zeros (gap/pad lanes verify against
-        # the empty digest), scattered miss uploads, pool-page lanes
-        full = np.zeros((lanes, cols), dtype=np.uint8)
-        for ci, r in enumerate(miss_rows):
-            full[r] = miss_arr[ci]
-        for r, pages, length in resident:
-            row = np.concatenate([np.asarray(p) for p in pages])[:length]
-            full[int(r), :int(length)] = row
+        # device-side composition: every row from its pool pages (the
+        # sentinel slot reads as zeros: gap, pad and miss lanes), then
+        # the miss uploads over their lanes
+        pages = pool[np.minimum(row_pages, len(pool) - 1)]
+        pages[row_pages >= len(pool)] = 0
+        full = pages.reshape(lanes, -1)[:, :cols]
+        full[list(miss_rows)] = miss_arr[:len(miss_rows)]
         return self._scrub_math(full, lengths, expected, ready), full
 
-    def pool_adopt(self, input_ref, lane: int, length: int,
-                   page_bytes: int):
-        """Slice one verified lane of a resident-submitted batch into
-        fixed-size device pages (tail zero-padded) — a device-side
-        copy, ZERO link bytes, so adoption never shows up on the
-        transport's staging meter."""
-        full = input_ref
-        assert full is not None, "adoption needs a resident-path input"
-        npages = max(1, -(-int(length) // int(page_bytes)))
-        buf = np.zeros((npages * int(page_bytes),), dtype=np.uint8)
-        buf[:int(length)] = full[int(lane), :int(length)]
-        return [buf[i * int(page_bytes):(i + 1) * int(page_bytes)].copy()
-                for i in range(npages)]
+    def pool_adopt(self, pool, batch, dst: np.ndarray) -> np.ndarray:
+        """The pool with the batch's pages written to the slots `dst`
+        names (the sentinel drops a page) — a device-side copy, ZERO
+        link bytes, so adoption never shows up on the transport's
+        staging meter."""
+        page = pool.shape[1]
+        lanes = batch.shape[0]
+        rows = np.zeros((lanes, len(dst) // lanes * page), dtype=np.uint8)
+        rows[:, :batch.shape[1]] = batch
+        keep = dst < pool.shape[0]
+        out = pool.copy()
+        out[dst[keep]] = rows.reshape(-1, page)[keep]
+        return out
 
-    def pool_read(self, pages, length: int) -> bytes:
+    def pool_read(self, pool, slots, length: int) -> bytes:
         """D2H readback of a pooled block (tests/smoke only — the data
         path never reads pages back to the host), trimmed to the
         ragged tail."""
-        return np.concatenate(
-            [np.asarray(p) for p in pages])[:int(length)].tobytes()
+        return pool[list(slots)].reshape(-1)[:int(length)].tobytes()
 
     def encode_submit(self, groups: np.ndarray):
         self.array_submissions += 1

@@ -97,6 +97,34 @@ def test_fused_scrub_fits_the_chip_beside_the_pools(one_chip):
     assert need < HBM_BYTES, need
 
 
+@pytest.mark.parametrize("lanes,cols", [(256, MIB), (64, MIB),
+                                        (64, 64 << 10)])
+def test_pool_programs_hold_words(one_chip, lanes, cols):
+    """The device pool's closed set for a geometry (a row of four pages,
+    and one narrower than a page), at the shipped 1,024 x 256 KiB pool.
+    uint8 on the device costs this compiler 10 s a program and minutes
+    for a view of words as bytes (PERF.md, PRs 29-30): the pool, the
+    batch it composes and the fused kernel's input are words."""
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    codec._pool_geom = (1024, 256 << 10)
+    keys = codec.pool_program_keys(lanes, cols)
+    assert len(keys) == {256: 10, 64: 4}[lanes]
+    for key in keys:
+        c = TpuCodec.pool_lowered(key, one_chip).compile()
+        assert "u8[" not in c.as_text(), key
+        # the array, a new one, the batch, a gather's scratch
+        assert _device_bytes(c) < 5 * 256 * MIB, key
+
+
+def test_fused_scrub_takes_words(one_chip):
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    bytes_in = _scrub_shapes(256, MIB, codec, one_chip)
+    words_in = (S((256, MIB // 4), jnp.uint32, sharding=one_chip),
+                ) + bytes_in[1:]
+    c = codec._scrub_pallas().lower(*words_in, 8).compile()
+    assert c.as_text().count("tpu_custom_call") == 2
+
+
 def test_unrolled_xla_hash_small_lanes(one_chip):
     """The <128-lane road.  Under rehearsal default_backend() is "cpu"
     and would pick the rolled body, so the test steers the unroll."""

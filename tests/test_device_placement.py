@@ -15,6 +15,7 @@ from garage_tpu.ops.codec import CodecParams
 from garage_tpu.ops.tpu_codec import TpuCodec
 
 K, M, COLS = 4, 2, 256
+PAGE = 512     # a pool page: two of the staged rows
 
 
 @pytest.fixture(scope="module")
@@ -74,20 +75,58 @@ def test_scrub_submit_lands_on_codec_device(pinned):
 
 
 def test_resident_scrub_lands_on_codec_device(pinned):
+    from garage_tpu.ops.device_pool import DevicePool
+
     codec, dev = pinned
     arr, lengths, expected = _staged()
-    # lanes 0..5 cross the link as misses; 6 and 7 come from the pool
+    pool = DevicePool(codec, pool_bytes=64 * PAGE, page_bytes=PAGE)
+    assert pool.array().devices() == {dev}
+    # all eight lanes cross the link as misses; 6 and 7 are adopted
+    none = pool.row_index(8, COLS, [])
     _, seed = codec.scrub_encode_submit_resident(
-        arr, list(range(8)), lengths, expected, [])
-    resident = [(r, codec.pool_adopt(seed, r, COLS, 64), COLS)
-                for r in (6, 7)]
-    assert all(p.devices() == {dev} for _, pages, _ in resident
-               for p in pages)
+        arr, list(range(8)), lengths, expected, pool.array(), none)
+    keys = {r: bytes([r]) * 32 for r in (6, 7)}
+    assert pool.adopt_lanes(seed, 8, COLS,
+                            [(r, keys[r], COLS) for r in (6, 7)]) == (2, 2)
+    assert pool.array().devices() == {dev}
+    # then 0..5 are misses again and 6, 7 come from the pool
+    resident = [(r, pool.lookup(keys[r], COLS).slots) for r in (6, 7)]
     out, full = codec.scrub_encode_submit_resident(
-        arr[:6], list(range(6)), lengths, expected, resident)
+        arr[:6], list(range(6)), lengths, expected, pool.array(),
+        pool.row_index(8, COLS, resident))
     assert all(d == {dev} for d in _devices((out, full)))
     assert bool(np.all(np.asarray(out[1])))
-    assert np.array_equal(np.asarray(full), arr)
+    assert np.array_equal(np.asarray(full).view(np.uint8), arr)
+    assert pool.read(keys[7]) == arr[7].tobytes()
+
+
+def test_resident_scrub_on_a_mesh_composes_on_its_first_device():
+    """`shard_mesh = 4`: the pool and the composition live on the first
+    device, and the composed batch moves onto the mesh for the kernel."""
+    from garage_tpu.ops.device_pool import DevicePool
+
+    devs = jax.devices()
+    if len(devs) < 4:
+        pytest.skip("needs the virtual multi-device platform")
+    codec = TpuCodec(CodecParams(rs_data=K, rs_parity=M, shard_mesh=4),
+                     devices=devs[:4])
+    n = 16      # whole codewords on every device
+    arr, lengths, expected = _staged(n)
+    pool = DevicePool(codec, pool_bytes=64 * PAGE, page_bytes=PAGE)
+    _, seed = codec.scrub_encode_submit_resident(
+        arr, list(range(n)), lengths, expected, pool.array(),
+        pool.row_index(n, COLS, []))
+    key = b"k" * 32
+    assert pool.adopt_lanes(seed, n, COLS, [(5, key, COLS)]) == (1, 1)
+    assert pool.array().devices() == seed.devices() == {devs[0]}
+    rows = [r for r in range(n) if r != 5]
+    out, full = codec.scrub_encode_submit_resident(
+        arr[rows], rows, lengths, expected, pool.array(),
+        pool.row_index(n, COLS, [(5, pool.lookup(key, COLS).slots)]))
+    assert full.devices() == {devs[0]}
+    assert out[1].devices() == set(devs[:4])
+    assert bool(np.all(np.asarray(out[1])))
+    assert np.array_equal(np.asarray(full).view(np.uint8), arr)
 
 
 def test_probe_submit_lands_on_codec_device(pinned):

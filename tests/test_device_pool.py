@@ -52,14 +52,66 @@ def _blocks(n=8, seed=0, sizes=RAGGED_SIZES):
 
 
 def _pooled_transport(link=100.0, pool_bytes=64 << 20, page_bytes=1024,
-                      metrics=None, params=None):
+                      metrics=None, params=None, kind="synthetic"):
+    """A transport over a pool on the synthetic device, or (`kind`
+    "tpu") on the real device codec with the CPU's devices: the pool's
+    two programs as the chip runs them."""
     p = params or _params()
-    dev = SyntheticLinkCodec(p, link_gibs=link, compute_real=True)
     cpu = CpuCodec(p)
+    if kind == "tpu":
+        from garage_tpu.ops.tpu_codec import TpuCodec
+
+        dev = TpuCodec(p, metrics=metrics)
+        obs = dev.obs
+    else:
+        dev = SyntheticLinkCodec(p, link_gibs=link, compute_real=True)
+        obs = None
     pool = DevicePool(dev, pool_bytes=pool_bytes, page_bytes=page_bytes,
                       metrics=metrics)
-    tr = DeviceTransport(dev, p, fallback=cpu, metrics=metrics, pool=pool)
+    tr = DeviceTransport(dev, p, fallback=cpu, observer=obs,
+                         metrics=metrics, pool=pool)
     return tr, pool, dev, cpu
+
+
+DEVICES = ("synthetic", "tpu")
+
+
+class _TableOnly:
+    """A device that holds no bytes: the page table alone is under test."""
+
+    def pool_alloc(self, npages, page_bytes):
+        return "pages"
+
+    def pool_adopt(self, pool, batch, dst):
+        return pool
+
+
+def _table(pool_bytes, page_bytes=1024):
+    return DevicePool(_TableOnly(), pool_bytes=pool_bytes,
+                      page_bytes=page_bytes)
+
+
+def _adopt(pool, key, length):
+    """One block through `adopt_lanes`, as lane 0 of a batch one row
+    wide enough for it: → whether it is resident afterwards."""
+    pool.adopt_lanes(None, 1, pool.bytes_for(length), [(0, key, length)])
+    return pool.contains(key)
+
+
+def _bytes(device_array) -> np.ndarray:
+    """A batch or the page array as the bytes it holds (the device
+    codec keeps both as little-endian words)."""
+    return np.asarray(device_array).view(np.uint8)
+
+
+def _by_hand(tr, blocks, hashes, slot=0):
+    """Dispatch one scrub batch on the caller's thread, through the
+    transport's own staging and submission: → (batch, handle), for
+    `tr._collect` whenever the test wants the collect to happen."""
+    it = TransportItem("scrub", (blocks, hashes), len(blocks),
+                       sum(map(len, blocks)))
+    (batch,) = tr._plan("scrub", [it], True)
+    return batch, tr._submit(batch, tr._stage(batch, slot))
 
 
 def _scrub(tr, blocks, hashes, want_parity=True, timeout=30):
@@ -127,9 +179,13 @@ def test_partial_residency_splits_bytes_exactly():
 # --- ragged occupancy: tail pages bit-identical -------------------------
 
 
-def test_ragged_tail_readback_bit_identical():
-    tr, pool, dev, _cpu = _pooled_transport(page_bytes=1024)
-    blocks, hashes = _blocks(n=8)  # RAGGED_SIZES: 77 B .. 4096 B
+@pytest.mark.parametrize("kind", DEVICES)
+def test_ragged_tail_readback_bit_identical(kind):
+    tr, pool, dev, _cpu = _pooled_transport(page_bytes=1024, kind=kind)
+    # RAGGED_SIZES, 77 B .. 4096 B: lanes of 1, 2, 3 and 4 pages, and
+    # lengths (77, 1025) that are no multiple of 4
+    blocks, hashes = _blocks(n=8)
+    assert {pool.pages_for(len(b)) for b in blocks} == {1, 2, 3, 4}
     _scrub(tr, blocks, hashes)
     for b, h in zip(blocks, hashes):
         got = pool.read(bytes(h))
@@ -147,27 +203,30 @@ def test_ragged_tail_readback_bit_identical():
 
 
 def test_lru_evicts_in_cycle_order():
-    # unit-level: adopt() with opaque page tokens, no device needed
-    pool = DevicePool(device=None, pool_bytes=4096, page_bytes=1024)
-    assert pool.adopt(b"a" * 32, ["p"], 1000)       # cycle 0
+    # unit-level: the page table alone, no device bytes needed
+    pool = _table(4096)
+    assert _adopt(pool, b"a" * 32, 1000)    # cycle 0
     pool.tick()
-    assert pool.adopt(b"b" * 32, ["p", "p"], 2000)  # cycle 1
+    assert _adopt(pool, b"b" * 32, 2000)    # cycle 1
     pool.tick()
     # needs 2 pages; only 1 free → the oldest-cycle entry goes first
-    assert pool.adopt(b"c" * 32, ["p", "p"], 2000)  # cycle 2
+    assert _adopt(pool, b"c" * 32, 2000)    # cycle 2
     assert not pool.contains(b"a" * 32)
     assert pool.contains(b"b" * 32) and pool.contains(b"c" * 32)
     assert pool.stats()["evicted_lru"] == 1
+    # every page is some entry's or free, and none is both
+    held = [s for e in pool._entries.values() for s in e.slots]
+    assert sorted(held + pool._free) == list(range(pool.npages))
 
 
 def test_lookup_bumps_recency_within_budget():
-    pool = DevicePool(device=None, pool_bytes=3072, page_bytes=1024)
-    pool.adopt(b"a" * 32, ["p"], 1000)
-    pool.adopt(b"b" * 32, ["p"], 1000)
+    pool = _table(3072)
+    _adopt(pool, b"a" * 32, 1000)
+    _adopt(pool, b"b" * 32, 1000)
     pool.tick()
     # touching `a` in the new cycle makes `b` the LRU victim
     assert pool.lookup(b"a" * 32, 1000) is not None
-    pool.adopt(b"c" * 32, ["p", "p"], 2000)
+    _adopt(pool, b"c" * 32, 2000)
     assert pool.contains(b"a" * 32) and pool.contains(b"c" * 32)
     assert not pool.contains(b"b" * 32)
     # contains() must NOT bump (the prefetch filter would otherwise
@@ -176,9 +235,41 @@ def test_lookup_bumps_recency_within_budget():
 
 
 def test_oversized_block_refused():
-    pool = DevicePool(device=None, pool_bytes=2048, page_bytes=1024)
-    assert not pool.adopt(b"x" * 32, ["p", "p", "p"], 3000)
-    assert pool.resident_bytes == 0
+    pool = _table(2048)
+    assert not _adopt(pool, b"x" * 32, 3000)
+    assert pool.resident_bytes == 0 and len(pool._free) == 2
+
+
+def test_a_batch_larger_than_the_pool_keeps_its_last_lanes():
+    """One adoption of more pages than the budget: the batch's first
+    lanes are evicted for its last, as lane-by-lane adoption did, and
+    no slot is handed to two lanes of the one scatter."""
+    seen = []
+
+    class Recording(_TableOnly):
+        def pool_adopt(self, pool, batch, dst):
+            seen.append(dst.copy())
+            return pool
+
+    pool = DevicePool(Recording(), pool_bytes=4096, page_bytes=1024)
+    keys = [bytes([i]) * 32 for i in range(3)]
+    lanes, pages = pool.adopt_lanes(
+        None, 4, 2048, [(i, k, 2000) for i, k in enumerate(keys)])
+    assert (lanes, pages) == (2, 4)
+    assert [pool.contains(k) for k in keys] == [False, True, True]
+    (dst,) = seen
+    kept = dst[dst < pool.npages]
+    assert len(kept) == len(set(kept)) == 4 and (dst[:2] == 4).all()
+    # a device that fails the scatter leaves nothing servable behind
+
+    class Failing(_TableOnly):
+        def pool_adopt(self, pool, batch, dst):
+            raise RuntimeError("device lost")
+
+    pool = DevicePool(Failing(), pool_bytes=4096, page_bytes=1024)
+    with pytest.raises(RuntimeError):
+        pool.adopt_lanes(None, 1, 1024, [(0, keys[0], 1000)])
+    assert not pool.contains(keys[0]) and len(pool._free) == 4
 
 
 # --- strict synchronous invalidation ------------------------------------
@@ -204,10 +295,11 @@ def test_post_invalidate_read_is_a_miss():
     tr.shutdown()
 
 
-def test_corrupt_lane_never_adopted():
+@pytest.mark.parametrize("kind", DEVICES)
+def test_corrupt_lane_never_adopted(kind):
     """A lane that fails the device hash verify must not become a
     servable page — adoption is gated on the per-lane ok bit."""
-    tr, pool, dev, _cpu = _pooled_transport()
+    tr, pool, dev, _cpu = _pooled_transport(kind=kind)
     blocks, hashes = _blocks(n=4)
     bad = list(blocks)
     bad[2] = b"\x00" + bad[2][1:]
@@ -215,6 +307,139 @@ def test_corrupt_lane_never_adopted():
     assert not ok[2] and ok[0] and ok[1] and ok[3]
     assert pool.read(bytes(hashes[2])) is None
     assert pool.stats()["resident_blocks"] == 3
+    tr.shutdown()
+
+
+@pytest.mark.parametrize("kind", DEVICES)
+def test_invalidate_between_dispatch_and_collect(kind):
+    """`invalidate` is a page-table operation: the key is gone when it
+    returns, the slots it frees are written by a later adopt only, and
+    a batch composed before that adopt is what it was."""
+    tr, pool, dev, _cpu = _pooled_transport(kind=kind, pool_bytes=8192)
+    a, ha = _blocks(n=4, sizes=(2048,))             # fills the 8 pages
+    batch, handle = _by_hand(tr, a, ha)
+    assert tr._collect(batch, handle)[0][0].all()
+    slots_a = {s for h in ha for s in pool.lookup(bytes(h), 2048).slots}
+    assert slots_a == set(range(8))
+    # dispatched with all four lanes served from the pool ...
+    batch_a, handle_a = _by_hand(tr, a, ha, slot=0)
+    assert batch_a.pool_hits == 4
+    # ... and invalidated before its collect: synchronously gone
+    for h in ha[:2]:
+        assert pool.invalidate(bytes(h), reason="delete")
+        assert not pool.contains(bytes(h)) and pool.read(bytes(h)) is None
+    # a later adopt takes the freed slots
+    b, hb = _blocks(n=2, seed=9, sizes=(2048,))
+    batch_b, handle_b = _by_hand(tr, b, hb, slot=1)
+    assert tr._collect(batch_b, handle_b)[0][0].all()
+    slots_b = {s for h in hb for s in pool.lookup(bytes(h), 2048).slots}
+    assert slots_b < slots_a and len(slots_b) == 4
+    assert [pool.read(bytes(h)) for h in hb] == b
+    # the batch composed before it still holds a's bytes and verifies
+    (out_a, full_a), _spans = handle_a
+    assert [bytes(r) for r in _bytes(full_a)[:4, :2048]] == a
+    ok, _par = tr._collect(batch_a, handle_a)[0]
+    assert ok.all()
+    assert [pool.contains(bytes(h)) for h in ha] == [False, False, True, True]
+    tr.shutdown()
+
+
+# --- the two programs: one each a batch, from a closed set --------------
+
+
+def _compose_lane_by_lane(miss_arr, miss_rows, lanes, cols, resident):
+    """The composition as it was before the page array: zeros, the miss
+    rows scattered, then each resident lane's pages concatenated, padded
+    and set — the plain reference of the one-program composition."""
+    full = np.zeros((lanes, cols), dtype=np.uint8)
+    for i, r in enumerate(miss_rows):
+        full[r] = miss_arr[i]
+    for r, pages, _length in resident:
+        row = np.concatenate(list(pages))
+        if row.shape[0] < cols:
+            row = np.pad(row, (0, cols - row.shape[0]))
+        full[int(r)] = row[:cols]
+    return full
+
+
+def test_composed_batch_equals_lane_by_lane_composition():
+    tr, pool, dev, _cpu = _pooled_transport(kind="tpu")
+    blocks, hashes = _blocks(n=16)                  # ragged, 77 B .. 4 KiB
+    batch, handle = _by_hand(tr, blocks, hashes)
+    assert tr._collect(batch, handle)[0][0].all()
+    # next: every third block is a miss again, and there are new ones
+    for h in hashes[::3]:
+        pool.invalidate(bytes(h))
+    more, more_h = _blocks(n=5, seed=4)
+    blocks, hashes = blocks + more, hashes + more_h
+    pages = _bytes(pool.array()).reshape(pool.npages, -1)
+    resident = [(r, [pages[s] for s in e.slots], len(b))
+                for r, (b, e) in enumerate(
+                    (b, pool._entries.get(bytes(h)))
+                    for b, h in zip(blocks, hashes)) if e is not None]
+    assert 0 < len(resident) < len(blocks)
+    it = TransportItem("scrub", (blocks, hashes), len(blocks),
+                       sum(map(len, blocks)))
+    (batch,) = tr._plan("scrub", [it], True)
+    miss_arr, miss_rows, lengths, _expected, _spans = staged = \
+        tr._stage(batch, 0)
+    (_out, full), _spans = tr._submit(batch, staged)
+    lanes, cols = int(lengths.shape[0]), int(miss_arr.shape[1])
+    assert miss_arr.shape[0] == lanes > len(miss_rows)      # a bucket
+    want = _compose_lane_by_lane(miss_arr, miss_rows, lanes, cols, resident)
+    assert np.array_equal(_bytes(full), want)
+    assert [bytes(want[r, :len(b)]) for r, b in enumerate(blocks)] == blocks
+    tr.shutdown()
+
+
+def test_one_program_a_batch_each_way_from_a_closed_set():
+    """A scripted pass of batches with 256, 0, 1, 17, 255 and 256
+    misses: one `compose` a dispatch, one `adopt` a collect with
+    misses, all of them members of the set the first batch of the
+    geometry compiled — nothing is built or loaded after it."""
+    from garage_tpu.ops.device_pool import miss_bucket, miss_buckets
+
+    assert miss_buckets(256) == [0, 32, 64, 96, 128, 160, 192, 224, 256]
+    assert miss_buckets(64) == [0, 32, 64] and miss_buckets(8) == [0, 8]
+    assert [miss_bucket(n, 256) for n in (0, 1, 17, 255, 256)] == \
+        [0, 32, 32, 256, 256]
+    assert all(miss_bucket(n, lanes) in miss_buckets(lanes)
+               and n <= miss_bucket(n, lanes) < n + max(32, lanes // 8)
+               for lanes in (8, 24, 64, 256, 1024)
+               for n in range(lanes + 1))
+    reg = MetricsRegistry()
+    tr, pool, dev, _cpu = _pooled_transport(
+        kind="tpu", metrics=reg, page_bytes=512, pool_bytes=1 << 20,
+        params=_params(block_size=1024))
+    blocks, hashes = _blocks(n=256, sizes=(1024, 512, 900, 37))
+    programs = reg.counter("pool_programs_total")
+    compiles = reg.counter("codec_compiles_total")
+
+    def compiled():
+        return sum(compiles._vals.values())
+
+    def scrub_with_misses(n):
+        for h in hashes[:n]:
+            pool.invalidate(bytes(h))
+        before = {op: programs.get(op=op) for op in ("compose", "adopt")}
+        ok, _par = _scrub(tr, blocks, hashes, timeout=300)
+        assert ok.all()
+        return {op: programs.get(op=op) - before[op] for op in before}
+
+    assert scrub_with_misses(0) == {"compose": 1, "adopt": 1}   # cold: 256
+    closed = {("alloc", pool.npages, 512)} | set(
+        dev.pool_program_keys(256, 1024))
+    assert set(dev._pool_execs) == closed and len(closed) == 11
+    warm = compiled()
+    assert warm > 0
+    for n in (0, 1, 17, 255, 256):
+        assert scrub_with_misses(n) == {"compose": 1, "adopt": int(n > 0)}
+    assert compiled() == warm and set(dev._pool_execs) == closed
+    assert programs.get(op="alloc") == 1
+    # the bucket's pad rows are counted as pad, not as payload
+    lane_bytes = reg.counter("transport_lane_bytes_total")
+    assert lane_bytes.get(kind="scrub", part="payload") == tr.staged_bytes
+    assert lane_bytes.get(kind="scrub", part="pad") > 0
     tr.shutdown()
 
 

@@ -5,6 +5,7 @@ needed for correctness (the on-device rate evidence lives in
 DEVICE_CAPTURE.json, round 5).
 """
 
+import functools
 import hashlib
 
 import numpy as np
@@ -45,6 +46,25 @@ def test_pallas_blake2s_matches_xla_scan_multi_tile():
         jnp.asarray(arr), jnp.asarray(lengths), interpret=True))
     want = np.asarray(blake2s_batch(jnp.asarray(arr), jnp.asarray(lengths)))
     assert (got == want).all()
+
+
+@pytest.mark.parametrize("kernel", ["pallas", "xla"])
+def test_words_hash_as_their_bytes(kernel):
+    """A batch the device pool composed is uint32 words already (the
+    host's view of the staged bytes): both hash kernels take it as it
+    is, and the digests are those of the bytes."""
+    from garage_tpu.ops.tpu_codec import host_words
+
+    rng = np.random.default_rng(11)
+    arr, lengths = _random_batch(rng, 128, 3 * 64)
+    fn = (functools.partial(blake2s_batch_pallas, interpret=True)
+          if kernel == "pallas" else blake2s_batch)
+    of_bytes = np.asarray(fn(jnp.asarray(arr), jnp.asarray(lengths)))
+    of_words = np.asarray(fn(jnp.asarray(host_words(arr)),
+                             jnp.asarray(lengths)))
+    assert (of_words == of_bytes).all()
+    assert of_words[5].astype("<u4").tobytes() == hashlib.blake2s(
+        arr[5, :lengths[5]].tobytes(), digest_size=32).digest()
 
 
 def test_pallas_blake2s_empty_and_full_lanes():

@@ -266,9 +266,11 @@ def test_compose_and_pool_adopt_lie_inside_adopt_and_collect():
         composes = [e["args"] for e in evs if e["name"] == "compose"]
         assert composes == [{"miss_rows": 8, "resident_rows": 0},
                             {"miss_rows": 4, "resident_rows": 4}]
-        adopts = [e["args"]["lanes"] for e in evs
-                  if e["name"] == "pool adopt"]
-        assert adopts == [8, 4]
+        adopts = [e["args"] for e in evs if e["name"] == "pool adopt"]
+        pages = [sum(-(-len(b) // 1024) for b in bs)
+                 for bs in (blocks, more)]
+        assert adopts == [{"lanes": 8, "pages": pages[0]},
+                          {"lanes": 4, "pages": pages[1]}]
         computes = [e["args"] for e in evs if e["name"] == "compute scrub"]
         assert all(c["variant"] in ("xla", "pallas") and c["lanes"] == 8
                    for c in computes) and len(computes) == 2
@@ -280,6 +282,10 @@ def test_compose_and_pool_adopt_lie_inside_adopt_and_collect():
             assert sub_n.get(stage=stage) == 2
             us = sum(e["dur"] for e in evs if e["name"] == name)
             assert abs(sub_s.get(stage=stage) * 1e6 - us) < 2.5
+        # one device program a section: the ratio anybody can read
+        programs = reg.counter("pool_programs_total")
+        assert programs.get(op="compose") == sub_n.get(stage="compose")
+        assert programs.get(op="adopt") == sub_n.get(stage="pool_adopt")
         # and the five stages still sum to the round trips exactly
         staged = sum(sec for _n, sec, _b in tr.profiler.snapshot().values())
         assert staged == pytest.approx(tr.profiler._wall_ns / 1e9, abs=1e-9)
