@@ -157,56 +157,6 @@ def build_native(smoke: Smoke) -> None:
 # --- cluster ----------------------------------------------------------------
 
 
-async def make_cluster(tmp: pathlib.Path, n: int, repl: str, codec_cfg: dict,
-                       block_size: int):
-    """n in-process Garage nodes with an applied layout and one S3
-    server on node 0 — the assembly server.py performs, minus the
-    sockets nobody dials here."""
-    from garage_tpu.api.s3.api_server import S3ApiServer
-    from garage_tpu.model import Garage
-    from garage_tpu.rpc.layout import ClusterLayout, NodeRole
-    from garage_tpu.utils.config import config_from_dict
-
-    garages = []
-    for i in range(n):
-        garages.append(Garage(config_from_dict({
-            "metadata_dir": str(tmp / f"n{i}" / "meta"),
-            "data_dir": str(tmp / f"n{i}" / "data"),
-            "replication_mode": repl,
-            "block_size": block_size,
-            "rpc_bind_addr": "127.0.0.1:0",
-            "rpc_secret": "chip-smoke",
-            "bootstrap_peers": [],
-            "codec": dict(codec_cfg),
-        })))
-    for g in garages:
-        await g.system.netapp.listen("127.0.0.1:0")
-    ports = [g.system.netapp._server.sockets[0].getsockname()[1]
-             for g in garages]
-    for i, a in enumerate(garages):
-        for j, b in enumerate(garages):
-            if i < j:
-                await a.system.netapp.connect(
-                    f"127.0.0.1:{ports[j]}", expected_id=b.system.id)
-        a.system.config.rpc_public_addr = f"127.0.0.1:{ports[i]}"
-    lay = garages[0].system.layout
-    for g in garages:
-        lay.stage_role(bytes(g.system.id), NodeRole("dc1", 1000))
-    lay.apply_staged_changes()
-    enc = lay.encode()
-    for g in garages:
-        g.system.layout = ClusterLayout.decode(enc)
-        g.system._rebuild_ring()
-        g.system.save_layout()
-        g.spawn_workers()
-    key = await garages[0].helper().create_key("chip-smoke")
-    key.params().allow_create_bucket.update(True)
-    await garages[0].key_table.insert(key)
-    server = S3ApiServer(garages[0])
-    await server.start("127.0.0.1:0")
-    return garages, server, key.key_id, key.params().secret_key
-
-
 class Admin:
     """The operator's commands, through the handler the CLI reaches."""
 
@@ -247,28 +197,6 @@ def metric_sum(metrics: dict, family: str, **labels) -> float:
     return total
 
 
-class S3:
-    """Minimal SigV4 client (garage_tpu.api.signature signs)."""
-
-    def __init__(self, session, port, kid, secret):
-        self.session, self.port, self.kid, self.secret = (
-            session, port, kid, secret)
-
-    async def req(self, method, path, body=b""):
-        import yarl
-
-        from garage_tpu.api.signature import sign_request
-
-        headers = {"host": f"127.0.0.1:{self.port}"}
-        headers.update(sign_request(self.kid, self.secret, "garage", method,
-                                    path, [], headers, body,
-                                    path_is_raw=True))
-        url = yarl.URL(f"http://127.0.0.1:{self.port}{path}", encoded=True)
-        async with self.session.request(method, url, data=body,
-                                        headers=headers) as r:
-            return r.status, await r.read()
-
-
 async def gather_bounded(n: int, coros):
     sem = asyncio.Semaphore(n)
 
@@ -279,19 +207,19 @@ async def gather_bounded(n: int, coros):
     return await asyncio.gather(*[one(c) for c in coros])
 
 
-async def put_all(s3: S3, bucket: str, plan, seed: int, conc: int):
+async def put_all(s3, bucket: str, plan, seed: int, conc: int):
     async def put(key, idx, n):
-        st, body = await s3.req("PUT", f"/{bucket}/{key}",
-                                object_bytes(seed, idx, n))
+        st, _body, _h = await s3.req("PUT", f"/{bucket}/{key}",
+                                     object_bytes(seed, idx, n))
         return key, st
 
     return [k for k, st in await gather_bounded(
         conc, [put(*p) for p in plan]) if st != 200]
 
 
-async def get_all(s3: S3, bucket: str, plan, seed: int, conc: int):
+async def get_all(s3, bucket: str, plan, seed: int, conc: int):
     async def get(key, idx, n):
-        st, body = await s3.req("GET", f"/{bucket}/{key}")
+        st, body, _h = await s3.req("GET", f"/{bucket}/{key}")
         return key, st == 200 and body == object_bytes(seed, idx, n)
 
     return [k for k, ok in await gather_bounded(
@@ -496,10 +424,12 @@ async def run_one_chip(smoke: Smoke, sz: Sizes, seed: int, tmp: pathlib.Path,
     import jax
     import numpy as np
 
+    from garage_tpu.testing.local_cluster import S3, mk_cluster
+
     codec_cfg = {"store_parity": True}      # everything else: the default
     with smoke.phase("cluster"):
-        garages, server, kid, secret = await make_cluster(
-            tmp, 3, "3", codec_cfg, sz.block)
+        garages, server, port, kid, secret = await mk_cluster(
+            tmp, 3, "3", codec_cfg, db="sqlite", block_size=sz.block)
         admins = [Admin(g) for g in garages]
         await wait_attached(admins, smoke)
         info0 = await admins[0].cmd("codec_info")
@@ -514,9 +444,9 @@ async def run_one_chip(smoke: Smoke, sz: Sizes, seed: int, tmp: pathlib.Path,
     plan = object_plan(sz)
     total = sum(n for _k, _i, n in plan)
     async with aiohttp.ClientSession() as session:
-        s3 = S3(session, server.port, kid, secret)
+        s3 = S3(session, port, kid, secret)
         with smoke.phase("load"):
-            st, _ = await s3.req("PUT", "/smoke")
+            st, _body, _h = await s3.req("PUT", "/smoke")
             smoke.check("bucket created", st == 200, f"status {st}")
             failed = await put_all(s3, "smoke", plan, seed, sz.concurrency)
             smoke.check(f"PUT {len(plan)} objects ({total >> 20} MiB) "
@@ -662,15 +592,18 @@ async def run_four_chips(smoke: Smoke, sz: Sizes, seed: int,
     import jax
     import numpy as np
 
+    from garage_tpu.testing.local_cluster import S3, mk_cluster
+
     plan = [(f"mesh/{i:04d}", i, sz.block) for i in range(sz.mesh_blocks)]
     results = {}
     for mesh_n in (4, 1):
         label = f"shard_mesh={mesh_n}"
         root = tmp / f"mesh{mesh_n}"
         with smoke.phase(f"{label}: cluster + load"):
-            garages, server, kid, secret = await make_cluster(
+            garages, server, port, kid, secret = await mk_cluster(
                 root, 1, "none",
-                {"store_parity": True, "shard_mesh": mesh_n}, sz.block)
+                {"store_parity": True, "shard_mesh": mesh_n},
+                db="sqlite", block_size=sz.block)
             admin = Admin(garages[0])
             await wait_attached([admin], smoke)
             codec = garages[0].block_manager.codec
@@ -678,8 +611,8 @@ async def run_four_chips(smoke: Smoke, sz: Sizes, seed: int,
             smoke.check(f"{label}: mesh size",
                         (mesh.size if mesh is not None else 1) == mesh_n)
             async with aiohttp.ClientSession() as session:
-                s3 = S3(session, server.port, kid, secret)
-                st, _ = await s3.req("PUT", "/smoke")
+                s3 = S3(session, port, kid, secret)
+                st, _body, _h = await s3.req("PUT", "/smoke")
                 failed = await put_all(s3, "smoke", plan, seed,
                                        sz.concurrency)
                 smoke.check(f"{label}: PUT {len(plan)} objects",

@@ -699,7 +699,7 @@ class AdminRpcHandler:
 
     async def _cmd_codec_events(self, msg) -> List[Dict]:
         """The bounded gate-decision event ring: every probe result,
-        gate open/hold, ramp step, fused-kernel demotion and sync
+        gate open/hold, fused-kernel demotion and sync
         failure with a reason label, most recent last."""
         limit = msg.get("limit")
         return self.garage.block_manager.codec.obs.events_list(

@@ -577,8 +577,8 @@ class ScrubWorker(Worker):
                 self.m_bytes.inc(nbytes)
                 self.m_blocks.inc(len(plain_blocks))
             # span per fused dispatch: a slow batch (gated link, mid-pass
-            # XLA compile, CPU steal) shows up in the slow-op log even on
-            # nodes with no trace_sink configured
+            # XLA compile, a CPU-side batch) shows up in the slow-op log
+            # even on nodes with no trace_sink configured
             with mgr.system.tracer.span(
                 "Scrub batch", blocks=len(all_b),
                 bytes=sum(len(b) for b in all_b),

@@ -265,8 +265,8 @@ class MemoryDb(IDb):
             # acknowledged).  A CRC mismatch FOLLOWED by parseable
             # records is a different animal: mid-file corruption eating
             # commits that were acknowledged — scan ahead to tell the
-            # two apart and log accordingly (round-5 ADVICE #2; the old
-            # silent truncate hid both cases).
+            # two apart and log accordingly (the old silent truncate hid
+            # both cases).
             later_records = 0
             if bad_reason == "crc_mismatch":
                 scan = off + 8 + struct.unpack_from("<II", raw, off)[0]
@@ -335,7 +335,7 @@ class MemoryDb(IDb):
         The copied snapshot, the stub WAL and the destination directory
         are all fsynced before returning, mirroring _write_snapshot — a
         snapshot whose caller archives/deletes the source right after
-        must not evaporate in a crash (round-5 ADVICE #3)."""
+        must not evaporate in a crash."""
         if self._path is None:
             raise DbError("snapshot requires a durable (path) memory db")
         with self._lock:

@@ -521,16 +521,13 @@ async def try_codeword(garage, h: Hash, ent) -> Optional[bytes]:
     # old gather fetched all m unconditionally, moving (and discarding)
     # up to (m-1) extra shards per degraded read.  Anything fetched
     # beyond k still lands in repair_overfetch_bytes_total so residual
-    # waste is measured, not assumed away.  (`repair_gather_everything`
-    # restores the fetch-everything behavior — the bench's baseline
-    # emulation knob, never set in production.)
+    # waste is measured, not assumed away.
     if len(present) < k:
         from ..block.parity import unpack_parity_shard
 
         pqueue = list(enumerate(ent.parity_hashes))
-        everything = bool(getattr(mgr, "repair_gather_everything", False))
         while len(present) < k and pqueue:
-            need = len(pqueue) if everything else k - len(present)
+            need = k - len(present)
             batch, pqueue = pqueue[:need], pqueue[need:]
             plocal = [mgr.is_block_present(Hash(ph)) for _j, ph in batch]
             pfetched = await asyncio.gather(

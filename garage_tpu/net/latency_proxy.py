@@ -12,7 +12,7 @@ bandwidth, optional jitter), so a 3-node loopback cluster behaves like
 three datacenters.
 
 Used by tests/test_wan_latency.py (1-RTT assertions + latency-ordered
-candidate selection), bench.py's WAN phase, and — via the subclass hooks
+candidate selection) and — via the subclass hooks
 `_on_accept` / `_filter` — by testing/faults.py's FaultyLink, which
 composes partitions, resets and blackholes on top of the delay line.
 Pure harness: the product stack (net/netapp.py, rpc/rpc_helper.py) is

@@ -2,8 +2,8 @@
 
 Why this exists.  Every device touch through PR 17 staged host→device
 and DISCARDED: scrub, resync verify, and degraded decode of the same
-hot blocks re-paid the link on every pass, which is why BENCH_r05
-scrubbed at 0.91 GiB/s while the device kernel does 24 GiB/s.  The
+hot blocks re-paid the link on every pass, which is why round 5
+scrubbed at 0.91 GiB/s while the device kernel did 24 GiB/s.  The
 link, not the ALU, is the warm-path bound — so this module treats the
 device as a MEMORY: a bounded set of fixed-size device pages (the
 Ragged Paged Attention layout, PAPERS.md — fixed page size, ragged

@@ -530,10 +530,9 @@ class CodecFeeder:
                 and all(it.cls == "bg" for it in all_items)
                 and any(it.kind != "mhash" for it in all_items)):
             # a PURELY background batch against a closed/unprobed gate
-            # pays the (TTL-cached) link probe — the old stealing feeder
-            # probed every scrub pass; with scrub riding this queue the
-            # probe rides along, and a healthy link re-opens the device
-            # route for THIS batch.  A batch carrying any foreground
+            # pays the (TTL-cached) link probe: scrub rides this queue
+            # and the probe rides along, so a healthy link re-opens the
+            # device route for THIS batch.  A batch carrying any foreground
             # item never pays it: the probe can cost a full link
             # round-trip and this is the lone dispatcher thread.
             # ...unless the device pool would serve the whole batch:
@@ -623,6 +622,8 @@ class CodecFeeder:
                     else:
                         results = self.codec.rs_reconstruct_ragged(
                             [it.payload for it in items])
+                if kind != "mhash":
+                    kside = self._answered(kside)
                 self.obs.add_bytes(kside, sum(it.nbytes for it in items))
             except BaseException as e:  # noqa: BLE001 — fan the error out
                 for it in items:
@@ -658,6 +659,13 @@ class CodecFeeder:
                     if not it.future.done():
                         it.future.set_exception(err)
 
+    def _answered(self, side: str) -> str:
+        """The side that ran the inline call this thread just made:
+        a routing codec (HybridCodec._routed) runs a batch whose device
+        call raised on its CPU floor, and bytes count where they ran."""
+        ran = getattr(self.codec, "answered_side", None)
+        return ran() if ran is not None else side
+
     def _scrub_worker(self) -> None:
         register_thread("feeder-scrub")
         try:
@@ -682,7 +690,8 @@ class CodecFeeder:
                 results = self.codec.scrub_ragged(
                     [(it.payload[0], it.payload[1], it.want_parity)
                      for it in batch])
-            self.obs.add_bytes(side, sum(it.nbytes for it in batch))
+            self.obs.add_bytes(self._answered(side),
+                               sum(it.nbytes for it in batch))
         except BaseException as e:  # noqa: BLE001 — fan the error out
             for it in batch:
                 if not it.future.done():

@@ -2,7 +2,7 @@
 trip (ISSUE 16).
 
 The hybrid gate's one number (`hybrid_link_gibs`: 0.031 GiB/s measured
-against a 24 GiB/s device, BENCH_r05) says the link is slow but not
+against a 24 GiB/s device in round 5) says the link is slow but not
 WHERE: serialization?  dlpack adoption?  XLA dispatch?  the transfer
 itself?  This module is the instrument — the same exact-sum attribution
 discipline PR 13 applied to requests, one level down, inside the
@@ -33,8 +33,7 @@ stage instead of vanishing.
 Producers: DeviceTransport (every batch + every link probe).
 Consumers: `transport_stage_seconds{stage,kind}` histograms, windowed
 `transport_stage_gibs{stage}` gauges, admin `codec info` / `codec
-profile`, gate probe events, the BENCH JSON `link_stages` block and
-its per-stage regression guard, and `scripts/link_profile.py`.
+profile`, gate probe events and `scripts/link_profile.py`.
 """
 
 from __future__ import annotations

@@ -2,21 +2,21 @@
 
 The codec is the system's reason for existing, yet through round 5 it
 recorded nothing into the node's MetricsRegistry or Tracer: `tpu_frac`
-was a `pop_stats()` tuple only the bench could read, and a 0.0 value was
-undiagnosable (VERDICT r5).  This module gives every codec instance one
+was a tuple only a bench could read, and a 0.0 value was undiagnosable
+(VERDICT r5).  This module gives every codec instance one
 observer holding:
 
   - per-stage duration histograms for the device pipeline
-    (`codec_stage_duration_seconds{stage=,side=}`): probe, feeder_wait,
-    host_staging, h2d_transfer, kernel_dispatch, sync_collect, cpu_span,
-    hedge, tail_wait — the stage-by-stage attribution model of the
-    degraded-read / erasure-coding literature (arXiv:2306.10528,
-    arXiv:2108.02692);
+    (`codec_stage_duration_seconds{stage=,side=}`): probe,
+    host_staging, device_submit, h2d_transfer, kernel_dispatch,
+    sync_collect, feeder_dispatch, transport_wait — the stage-by-stage
+    attribution model of the degraded-read / erasure-coding literature
+    (arXiv:2306.10528, arXiv:2108.02692);
   - bytes-by-side counters (`codec_bytes_total{side=}`) so tpu_frac is a
     scrapeable ratio, not a bench-polled tuple;
   - a bounded, timestamped **gate-decision event ring**: every link
-    probe, gate open/hold, ramp step, fused-kernel demotion, feeder cede
-    and sync failure lands here with a reason label, served by the admin
+    probe, gate open/hold, fused-kernel demotion, transport error and
+    sync failure lands here with a reason label, served by the admin
     `codec events` command — "why is tpu_frac 0.0" is one command.
 
 The ring and the per-stage accumulators are ALWAYS ON (bounded memory,
@@ -32,18 +32,15 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-# pipeline stages recorded by the hybrid engine + the device codec
+# pipeline stages recorded by the hybrid gate, the transport and the
+# device codec
 STAGES = (
-    "probe",           # link-health probe round-trip (feeder, pre-claim)
-    "feeder_wait",     # feeder claiming work from the stealing deque
-    "host_staging",    # group merge + pad to the compiled lane/byte shape
-    "device_submit",   # whole scrub_submit envelope (staging+h2d+dispatch)
+    "probe",           # one fresh link-health probe round-trip (the gate)
+    "host_staging",    # batch pad to the compiled lane/byte shape
+    "device_submit",   # whole submit envelope (staging+h2d+dispatch)
     "h2d_transfer",    # host→device array transfer (enqueue side)
     "kernel_dispatch", # fused verify+encode dispatch (submit, no sync)
     "sync_collect",    # device→host materialization of a submission
-    "cpu_span",        # one wide fused CPU call (verify + RS encode)
-    "hedge",           # CPU redo of groups the device still held in flight
-    "tail_wait",       # grace wait on the device before hedging the tail
     "feeder_dispatch", # one ragged foreground batch (CodecFeeder) through
                        # hash_ragged / rs_encode_ragged / rs_reconstruct_ragged
     "transport_wait",  # queue wait in the DeviceTransport's EDF heap

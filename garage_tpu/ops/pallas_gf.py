@@ -18,12 +18,10 @@ bit was set, with no cross-byte carries (d·1 < 256) — one shift+and per
 (input row, bit) and one mul+xor per output row.
 
 The kernel is validated bit-identically against the numpy/XLA versions
-in tests (interpret mode — no TPU needed for correctness), and the
-device-resident rate comparison against the XLA kernel is printed by
-bench.py when the chip is reachable (pallas_gibs vs device_gibs).
+in tests (interpret mode — no TPU needed for correctness).
 
-Tuned on a v5e in round 5 (2026-07-31; records in DEVICE_CAPTURE.json,
-taken under jax 0.4.37 — not re-measured on the current stack).
+Tuned on a v5e in round 5 (2026-07-31, under jax 0.4.37 — not
+re-measured on the current stack).
 Lessons that produced the current form:
   - loop order: materializing all k*8 bit-plane masks before the output
     loop (the original kernel) is a 64-vector live range that spills —
@@ -33,8 +31,7 @@ Lessons that produced the current form:
   - mask algebra: m1 * d (scalar constant) beats mask-expand-then-AND
     (((x>>b)&one)*0xFF) & K — one fewer vector op per term.
 Result then: the hand kernel beat the XLA mask-XOR formulation on the
-same resident data in every paired run (DEVICE_CAPTURE.json: 109.5 vs
-79.2 GiB/s), timed with in-dispatch fori_loop reps differenced at two
+same resident data in every paired run (109.5 vs 79.2 GiB/s), timed with in-dispatch fori_loop reps differenced at two
 rep counts and a device→host fetch of a scalar checksum as the sync
 point.
 """

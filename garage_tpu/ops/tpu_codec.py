@@ -275,6 +275,12 @@ def _pallas_error_is_permanent(e: BaseException) -> bool:
 
 
 class TpuCodec(BlockCodec):
+    # a real accelerator sits behind a link whose rate is measured, not
+    # assumed: HybridCodec's gate probes a device so marked before it
+    # routes work there (a scripted fake without the mark, and without a
+    # probe_link hook, is taken as healthy)
+    metered_link = True
+
     def __init__(self, params: CodecParams, devices: Optional[list] = None,
                  metrics=None, tracer=None, observer=None):
         super().__init__(params, metrics=metrics, tracer=tracer,
@@ -320,10 +326,9 @@ class TpuCodec(BlockCodec):
         self._pallas_transient_fails = 0
         # Pallas fused scrub (blake2s hash state resident in VMEM across
         # chunks + Pallas GF parity): 117 GiB/s at 1024 lanes on v5e vs
-        # the XLA scan's 4.3 in round 5 (DEVICE_CAPTURE.json; not
-        # re-measured on the current stack) — the scan was bound by
-        # per-chunk state round-trips through HBM.  Separate latch from
-        # the GF kernel;
+        # the XLA scan's 4.3 in round 5 (jax 0.4.37; not re-measured on
+        # the current stack) — the scan was bound by per-chunk state
+        # round-trips through HBM.  Separate latch from the GF kernel;
         # same permanent/transient demotion policy.
         self._pallas_fused_ok = True
         self._pallas_fused_fails = 0
@@ -632,11 +637,9 @@ class TpuCodec(BlockCodec):
         return b
 
     def _lane_align(self) -> int:
-        """Lane-count divisor shared by _pad_group and warm_scrub: whole
-        codewords (k), and — when sharded — whole codewords per device
-        (k × mesh, so the fused kernel's parity output dim B//k divides
-        over the mesh).  One helper so the AOT-warmed shape can never
-        drift from the dispatched one."""
+        """Lane-count divisor of _pad_group: whole codewords (k), and —
+        when sharded — whole codewords per device (k × mesh, so the
+        fused kernel's parity output dim B//k divides over the mesh)."""
         k = max(1, self.params.rs_data)
         return k * (self.mesh.size if self.mesh is not None else 1)
 
@@ -742,7 +745,7 @@ class TpuCodec(BlockCodec):
                     out = host_bytes(pg(u32))[..., :s]
                     # reset only after the host-side materialization
                     # proved the kernel ran (same rule as the fused
-                    # latch, round-5 ADVICE #1)
+                    # latch)
                     self._pallas_transient_fails = 0
                     return out
                 except Exception as e:
@@ -864,7 +867,7 @@ class TpuCodec(BlockCodec):
     def _use_pallas_scrub(self, nlanes: int) -> bool:
         """The Pallas fused scrub wants whole (…,128)-lane tiles in a
         row count its hash kernel can tile; smaller padded batches
-        (deque tails) run the XLA variant instead of paying a 2-16x
+        (a pass's tail) run the XLA variant instead of paying a 2-16x
         lane pad."""
         from .pallas_blake2s import lanes_supported
 
@@ -905,12 +908,12 @@ class TpuCodec(BlockCodec):
     def note_sync_failure(self, e: BaseException,
                           variant: Optional[str] = None) -> None:
         """Sync-time kernel failure — surfacing at the caller's
-        np.asarray (HybridCodec._tpu_collect), long after scrub_submit
-        returned.  Routes the failure into the fused-scrub demotion
-        latch when the failing submission came from the Pallas variant:
-        a consistently sync-failing kernel must demote to the XLA
-        fallback instead of silently losing the device side every pass
-        (round-5 ADVICE #1)."""
+        np.asarray (the transport's collect, scrub_encode_batch), long
+        after the submit returned.  Routes the failure into the
+        fused-scrub demotion latch when the failing submission came
+        from the Pallas variant: a consistently sync-failing kernel must
+        demote to the XLA fallback instead of silently losing the device
+        side every pass."""
         if (variant or self.last_submit_variant) == "pallas":
             self._note_fused_failure(e)
 
@@ -918,7 +921,7 @@ class TpuCodec(BlockCodec):
         """Successful host-side materialization of a submission — the
         ONLY point the fused-kernel transient-failure counter resets
         (resetting at submit time, before the kernel provably ran,
-        defeated the latch: round-5 ADVICE #1)."""
+        defeated the latch)."""
         if (variant or self.last_submit_variant) == "pallas":
             self._pallas_fused_fails = 0
 
@@ -934,34 +937,6 @@ class TpuCodec(BlockCodec):
         _h, ok, _bad, parity = self.scrub_encode_submit(arr, lengths, expected)
         return ok, parity, len(blocks)
 
-    def warm_scrub(self, nblocks: int, nbytes: int) -> None:
-        """AOT-compile the fused scrub executable for the padded shape of an
-        (nblocks × nbytes) group and populate the persistent XLA compilation
-        cache — without transferring any data (device links may be
-        bandwidth-metered, so warmup must not spend bytes)."""
-        k = self.params.rs_data
-        bsz = self._batch_size(max(nblocks, 1))
-        bsz += (-bsz) % self._lane_align()
-        padded = self._bucket(max(nbytes, 1))
-        shapes = (
-            jax.ShapeDtypeStruct((bsz, padded), jnp.uint8),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, 8), jnp.uint32),
-            jax.ShapeDtypeStruct(self._K_enc.shape, self._K_enc.dtype),
-        )
-        if self._use_pallas_scrub(bsz):
-            try:
-                self._scrub_pallas().lower(*shapes, k).compile()
-            except Exception as e:
-                self._note_fused_failure(e)
-        # ALWAYS warm the XLA variant too: it is the runtime fallback
-        # when a Pallas dispatch fails transiently, and a cold fallback
-        # means a multi-second mid-pass compile on a remote backend —
-        # exactly what warm() exists to prevent
-        self._scrub_jit.lower(*shapes, k).compile()
-        if self._pool_geom is not None:
-            self.pool_warm(bsz, padded)
-
     def scrub_encode_submit(self, arr: np.ndarray, lengths: np.ndarray,
                             expected: np.ndarray):
         """Enqueue ONE device dispatch doing verify + RS(k,m) parity for a
@@ -973,7 +948,7 @@ class TpuCodec(BlockCodec):
         only at sync time, and the demotion latch must attribute them to
         the variant that actually produced the arrays.  The transient-
         failure counter is NOT reset here — a submit returning is proof
-        of nothing on an async backend (round-5 ADVICE #1); the reset
+        of nothing on an async backend; the reset
         happens in note_sync_success."""
         assert arr.shape[0] % self.params.rs_data == 0
         assert arr.shape[1] % 4 == 0

@@ -5,7 +5,7 @@ The reference validates durability claims with cluster benchmarks under
 no reusable rig; here the rig is in-tree: one object that can crash and
 revive nodes of an in-process cluster and drop/corrupt chosen blocks on
 disk, used by tests (generalizing the ad-hoc node kills in
-tests/test_integration.py) and by bench.py's degraded-mode phase.
+tests/test_integration.py) and by scripts/chaos.py's drills.
 
 Crash semantics: `crash()` is abrupt — transport closed and workers
 cancelled with NO graceful drains (a dying node doesn't flush its
@@ -86,7 +86,7 @@ FAST_CHAOS_HEALTH = {
 # matrix — z1↔z2 a metro pair, z1↔z3 cross-country, z2↔z3 the long
 # diagonal.  Values are full round trips in SECONDS; the injector
 # applies rtt/2 one-way per boundary link.  SHARED by the wan chaos
-# phase, bench --replay-phase, and the WAN-matrix unit tests.
+# phase and the WAN-matrix unit tests.
 WAN_3ZONE_RTT = {
     ("z1", "z2"): 0.020,
     ("z1", "z3"): 0.080,
@@ -714,8 +714,7 @@ class FaultInjector:
 
 async def crash_heaviest_and_drop(inj: FaultInjector, skip=(0,),
                                   resync_workers: int = 4):
-    """Shared repair-storm opener (bench --repair-storm-phase and
-    scripts/chaos.py repair_storm): crash the heaviest data holder not
+    """The repair-storm opener (scripts/chaos.py repair_storm): crash the heaviest data holder not
     in `skip` (typically the gateway), drop it from the committed
     layout, hand every survivor the new ring and a raised resync worker
     count.  Returns (victim_index, lost_bytes, survivors) — the heal

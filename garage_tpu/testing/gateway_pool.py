@@ -3,9 +3,8 @@
 Production object stores put N stateless gateways behind a client (or
 LB) that health-checks them, backs off the ones that shed, and fails a
 request over to a sibling when one dies mid-flight.  This module is
-that client for the in-process harness: the gateway_failover drill,
-bench --replay-phase, and the workload replayer all drive their
-traffic through it, so "a gateway died mid-PUT" exercises the same
+that client for the in-process harness: the gateway_failover drill
+and the workload replayer drive their traffic through it, so "a gateway died mid-PUT" exercises the same
 retry/resume ladder everywhere.
 
 Failover policy, by request class:

@@ -5,8 +5,8 @@ mixtures, and load breathes on a diurnal curve.  This module generates
 a DETERMINISTIC operation trace from a seed — (kind, key, size, at_s)
 tuples — and replays it against a GatewayPool, verifying every GET
 bit-identical against the last acked body for its key.  Same seed ⇒
-byte-identical trace ⇒ a chaos run (bench --replay-phase kills a
-gateway mid-window) is exactly reproducible.
+byte-identical trace ⇒ a chaos run (a gateway killed mid-window) is
+exactly reproducible.
 
 Shape knobs and their defaults:
 
@@ -244,8 +244,8 @@ class Replayer:
     async def run(self, on_op=None) -> ReplayStats:
         """Replay the trace at its generated timestamps (sleeping into
         each op's at_s; pacing debt is recorded, never skipped).
-        ``on_op(i, at_s)`` fires before each op — bench uses it to
-        trigger the mid-window gateway kill at a deterministic index."""
+        ``on_op(i, at_s)`` fires before each op — the hook for a
+        mid-window gateway kill at a deterministic index."""
         t_start = time.monotonic()
         for i, (kind, key, size, at) in enumerate(self.ops):
             now = time.monotonic() - t_start

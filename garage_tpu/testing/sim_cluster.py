@@ -1,7 +1,7 @@
 """SimCluster — a 20–30 node, 3–5 zone in-process cluster harness.
 
-Scales the 3-node chaos scaffolding (bench._mk_cluster + FaultInjector)
-to cluster-sized drills: per-node config generation (memory db, CPU
+Scales the 3-node chaos scaffolding (local_cluster.mk_cluster +
+FaultInjector) to cluster-sized drills: per-node config generation (memory db, CPU
 codec, fast-twitch [rpc] tunables), bounded concurrent startup, a
 zone-aware applied layout, one S3 gateway, and optional FaultyLink
 interposition on every directed dial path so whole zones can be
@@ -40,6 +40,7 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from .faults import FAST_CHAOS_RPC, FaultInjector
+from .local_cluster import S3
 
 logger = logging.getLogger("garage_tpu.testing.sim_cluster")
 
@@ -48,7 +49,7 @@ DEFAULT_ZONES = ("z1", "z2", "z3", "z4")
 
 def p99(lats: List[float]) -> float:
     """Nearest-rank p99 over raw latency samples (0.0 when empty) —
-    shared by the drills and bench phases so every quantile claim uses
+    shared by the drills so every quantile claim uses
     the same arithmetic."""
     ls = sorted(lats)
     return ls[min(len(ls) - 1, int(len(ls) * 0.99))] if ls else 0.0
@@ -57,15 +58,12 @@ def p99(lats: List[float]) -> float:
 async def make_tenant_client(garage, session, port: int, name: str,
                              bucket: str):
     """One QoS tenant: a fresh access key plus its own bucket, returned
-    as a signing S3 client — shared by the noisy-neighbor drill and the
-    Zipf bench phase so both harnesses mint tenants identically."""
-    import bench
-
+    as a signing S3 client (the noisy-neighbor drill's tenants)."""
     helper = garage.helper()
     key = await helper.create_key(name)
     key.params().allow_create_bucket.update(True)
     await garage.key_table.insert(key)
-    s3 = bench._S3(session, port, key.key_id, key.params().secret_key)
+    s3 = S3(session, port, key.key_id, key.params().secret_key)
     st, _b, _h = await s3.req("PUT", f"/{bucket}")
     assert st == 200, f"bucket {bucket}: {st}"
     return s3
@@ -421,14 +419,12 @@ class TrafficDriver:
 
     def __init__(self, cluster: SimCluster, session, bucket: str = "drill",
                  seed: int = 4242):
-        import bench
-
         self.cluster = cluster
         # honor (clamped) Retry-After on 503s: the drills' sustained
         # traffic is production-shaped, not a shed-hammering loop
-        self.s3 = bench._S3(session, cluster.port, cluster.key_id,
-                            cluster.secret, honor_retry_after=True,
-                            retry_after_cap=0.5)
+        self.s3 = S3(session, cluster.port, cluster.key_id,
+                     cluster.secret, honor_retry_after=True,
+                     retry_after_cap=0.5)
         self.bucket = bucket
         self.rng = random.Random(seed)
         self.acked: Dict[str, bytes] = {}
@@ -771,12 +767,10 @@ async def overload_drill(cluster: SimCluster, session, secs: float,
     process."""
     import xml.etree.ElementTree as ET
 
-    import bench
-
     g0 = cluster.garages[0]
     gate = g0.admission
     cap = max(gate.tun.max_inflight, 1)
-    s3 = bench._S3(session, cluster.port, cluster.key_id, cluster.secret)
+    s3 = S3(session, cluster.port, cluster.key_id, cluster.secret)
     st, _b, _h = await s3.req("PUT", f"/{bucket}")
     assert st == 200, f"bucket create: {st}"
     out: dict = {"capacity": cap, "errors": 0, "error_notes": []}
@@ -1032,7 +1026,7 @@ async def noisy_neighbor_drill(cluster: SimCluster, session, secs: float,
     out["abuser_shed_typed"] = len(abuser_shed) > 0
     # informational here: everything (clients + 4 server nodes) shares
     # one core, so admitted-abuser CPU inflates this ratio with noise
-    # fairness can't remove; the Zipf BENCH phase owns the hard 2x bound
+    # fairness can't remove
     out["well_p99_ratio"] = round(
         out["well_p99_abuse_ms"] / max(out["well_p99_base_ms"], 1.0), 2)
     out["tenant_stats"] = gate.tenant_stats()
@@ -1228,8 +1222,6 @@ async def wan_drill(cluster: SimCluster, session, secs: float,
 
     Bodies are 2 KiB (< INLINE_THRESHOLD) so a GET is a pure metadata
     quorum read — latency IS the RPC geography, no streaming noise."""
-    import bench
-
     inj = cluster.injector
     g0 = cluster.garages[0]
     out: dict = {"errors": 0, "error_notes": [],
@@ -1243,7 +1235,7 @@ async def wan_drill(cluster: SimCluster, session, secs: float,
     # learn the new geography before anything is measured against it)
     await cluster.tick(rounds=3)
 
-    s3 = bench._S3(session, cluster.port, cluster.key_id, cluster.secret)
+    s3 = S3(session, cluster.port, cluster.key_id, cluster.secret)
     st, _b, _h = await s3.req("PUT", f"/{bucket}")
     assert st == 200, f"bucket create: {st}"
 

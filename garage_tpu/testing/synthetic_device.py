@@ -1,27 +1,21 @@
-"""Synthetic-link device codec — the hybrid crossover test backend.
+"""Synthetic-link device codec — the hybrid gate's test backend.
 
-A real link has one rate; the hybrid gate and the stealing engine must
-behave at every rate, above and below the gate threshold.  To prove
-the hybrid's claimed steady-state model
+A real link has one rate; the hybrid gate, the transport and the pool
+must behave at every rate, above and below the gate threshold.  This
+backend stands in for TpuCodec with a CONFIGURABLE link: transfers are
+modeled as sleeps (which release the GIL exactly like a real DMA leaves
+the CPU free for the verify thread), and the probe hook reports the
+configured rate so the gate decision is deterministic.
 
-    total ≈ cpu_rate + min(link_rate, device_rate)
-
-and the gate behavior on BOTH sides of the threshold, this backend
-stands in for TpuCodec with a CONFIGURABLE link: transfers are modeled
-as sleeps (which release the GIL exactly like a real DMA leaves the CPU
-free for the verify thread), and the probe hook reports the configured
-rate so the gate decision is deterministic.
-
-Two modes:
+Two modes of the bytes-level calls (the array-level transport API
+always computes real results):
   - compute_real=False (timing mode): verification results are
     synthesized (the caller's hashes are trusted), so the backend
-    consumes NO host CPU — the sleep is the entire cost, making the
-    throughput model measurable on a 1-core host.  Only valid for
-    fetch_parity=False flows.
+    consumes NO host CPU — the sleep is the entire cost.  Only valid
+    for fetch_parity=False flows.
   - compute_real=True (identity mode): results come from a real
-    CpuCodec, so bit-identity of the hybrid merge/split machinery can
-    be asserted through the probe/gate path.  Costs host CPU; timing
-    is not meaningful on a 1-core host.
+    CpuCodec, so bit-identity can be asserted through the probe/gate
+    path.  Costs host CPU; timing is not meaningful on a 1-core host.
 """
 
 from __future__ import annotations
@@ -89,8 +83,8 @@ class SyntheticLinkCodec:
             CpuCodec(params) if compute_real else None)
         self.submissions = 0
         self.bytes_submitted = 0
-        # transport A/B attribution: the bytes-level path (scrub_submit)
-        # models the retired serialize+copy link — each block pays a
+        # transport A/B attribution: the bytes-level path
+        # (scrub_encode_batch, *_ragged) models the serialize+copy link — each block pays a
         # pack copy plus a transfer-serialize copy, exactly what the
         # real bytes-level TpuCodec path did; array-level submissions
         # arrive pre-staged (the transport's single copy is counted on
@@ -115,7 +109,7 @@ class SyntheticLinkCodec:
         # this, any caller-side threading would fake link bandwidth
         _wait_until(self._link_ready_at(nbytes))
 
-    # --- hooks the hybrid engine looks for ---
+    # --- the hook the hybrid gate looks for ---
 
     def probe_link(self, nbytes: int) -> float:
         """The hybrid probe hook: the measured link rate, with the
@@ -132,36 +126,11 @@ class SyntheticLinkCodec:
             "compute": round(dt, 9), "collect": 0.0}
         return self.link_gibs
 
-    def warm_scrub(self, nblocks: int, nbytes: int) -> None:
-        pass  # nothing to compile
-
-    def _batch_size(self, n: int) -> int:
-        return max(n, 1)
-
-    # --- submission ---
-
-    def scrub_submit(self, blocks: Sequence[bytes],
-                     hashes: Sequence[Hash]):
-        nbytes = sum(len(b) for b in blocks)
-        self.submissions += 1
-        self.bytes_submitted += nbytes
-        self.blocks_submitted += len(blocks)
-        self.host_copies += 2 * len(blocks)  # pack + transfer-serialize
-        self._link_sleep(nbytes)
-        if self.compute_real:
-            ok = self.cpu.batch_verify(blocks, hashes)
-            parity = self.cpu.rs_encode_blocks(blocks)
-            return ok, parity, len(blocks)
-        # timing mode: the caller's hashes are trusted correct-by-
-        # construction; parity is None (fetch_parity=False flows only)
-        return np.ones((len(blocks),), dtype=bool), None, len(blocks)
-
-    # --- bytes-level ragged API (the LEGACY serialize+copy path) ---
+    # --- bytes-level API (the serialize+copy path) ---
     #
-    # What HybridCodec routed feeder batches through before the
-    # DeviceTransport: every block repacked (pack copy) and pushed over
-    # the modeled link (transfer-serialize copy).  Kept as the "old"
-    # side of the transport A/B (bench --transport-phase).
+    # What HybridCodec routes a batch through when no transport takes
+    # it: every block repacked (pack copy) and pushed over the modeled
+    # link (transfer-serialize copy).
 
     def _bytes_level(self, nblocks: int, nbytes: int) -> None:
         self.submissions += 1
@@ -186,12 +155,19 @@ class SyntheticLinkCodec:
                                     for sh, _p, _r in items))
         return self._codec().rs_reconstruct_ragged(items)
 
-    def scrub_ragged(self, items):
-        out = []
-        for blocks, hashes, fetch_parity in items:
-            ok, parity, _n = self.scrub_submit(blocks, hashes)
-            out.append((ok, parity if fetch_parity else None))
-        return out
+    def scrub_encode_batch(self, blocks: Sequence[bytes],
+                           hashes: Sequence[Hash],
+                           fetch_parity: bool = True):
+        self._bytes_level(len(blocks), sum(len(b) for b in blocks))
+        if not self.compute_real:
+            # timing mode: the caller's hashes are trusted correct-by-
+            # construction; parity is None (fetch_parity=False flows)
+            return np.ones((len(blocks),), dtype=bool), None
+        return self.cpu.scrub_encode_batch(blocks, hashes, fetch_parity)
+
+    def batch_verify(self, blocks: Sequence[bytes],
+                     hashes: Sequence[Hash]) -> np.ndarray:
+        return self.scrub_encode_batch(blocks, hashes, False)[0]
 
     # --- the transport device API (ops/transport.py) ---
     #

@@ -76,23 +76,20 @@ def secret_from_file(path: str) -> str:
 @dataclass
 class CodecConfig:
     """TPU block-codec settings (new vs reference — the BlockCodec seam)."""
-    # Default is the production path: CPU floor + opportunistic device
-    # stealing.  The device codec builds on a BACKGROUND thread
-    # (make_codec passes build_device="async"), so a daemon on a host
-    # with no/dead accelerator boots instantly on the CPU floor and the
-    # TPU joins in if/when its backend initializes — a tpu-native
+    # Default is the production path: the CPU floor, and the device
+    # behind the link gate.  The device codec builds on a BACKGROUND
+    # thread (make_codec passes build_device="async"), so a daemon on a
+    # host with no/dead accelerator boots instantly on the CPU floor and
+    # the TPU joins in if/when its backend initializes — a tpu-native
     # framework whose stock config never touched the TPU would undercut
     # its own thesis.  Set "cpu" to pin the floor, "tpu" to require the
     # device.
-    backend: str = "hybrid"         # hybrid (cpu + device stealing) | cpu | tpu
+    backend: str = "hybrid"         # hybrid (cpu floor + gated device) | cpu | tpu
     hash_algo: str = "blake2s"      # blake2s (TPU-offloadable) | blake2b | sha256
     rs_data: int = 8                # Reed-Solomon k (0 = replication only, no RS)
     rs_parity: int = 4              # Reed-Solomon m
     batch_blocks: int = 256         # blocks per device batch (scrub/resync producers)
     shard_mesh: int = 1             # devices to shard codec batches over
-    # hybrid backend work-stealing quantum; single source of truth is the
-    # CodecParams default (codec.py: cache-resident CPU-side groups)
-    hybrid_group_blocks: int = _CODEC_DEFAULTS.hybrid_group_blocks
     # persist scrub-time RS parity sidecars enabling zero-network local
     # reconstruction of corrupted/lost blocks (the decode-repair half of
     # the BlockCodec north star).  Opt-in: costs ~m/k extra disk (+50%
@@ -112,17 +109,8 @@ class CodecConfig:
     # data_replication_mode = "none" for the erasure-coded storage class
     # (1 + m/k × storage tolerating m codeword-node losses).
     parity_distribute: bool = False
-    hybrid_window: int = 1          # hybrid backend: device in-flight groups
-    # Device submission width (blocks) for the hybrid feeder.  MEMORY
-    # IMPLICATION (round-5 ADVICE #4): host staging + device HBM hold up
-    # to (hybrid_window + 1) × device_batch_blocks × block_size at once
-    # — 2 GiB at the defaults (window 1 × 1024 blocks × 1 MiB).  The
-    # codec clamps this width at construction so the bound never
-    # exceeds max_device_staging_mib; see ops/codec.py CodecParams for
-    # the full derivation.
-    device_batch_blocks: int = _CODEC_DEFAULTS.device_batch_blocks
-    # Cap (MiB) on the in-flight staging claim above.  Raise it only on
-    # hosts with the RAM/HBM headroom for wider windows.
+    # Cap (MiB) on what the device transport stages in flight (all its
+    # slots together).  Raise it only on hosts with the RAM/HBM headroom.
     max_device_staging_mib: int = _CODEC_DEFAULTS.max_device_staging_mib
     # --- continuous-batching feeder for the FOREGROUND data path
     # (ops/feeder.py): in-flight PUT block-id hashing, write-time RS
@@ -197,9 +185,6 @@ class CodecConfig:
             batch_blocks=self.batch_blocks,
             compression_level=compression_level,
             shard_mesh=self.shard_mesh,
-            hybrid_group_blocks=self.hybrid_group_blocks,
-            hybrid_window=self.hybrid_window,
-            device_batch_blocks=self.device_batch_blocks,
             max_device_staging_mib=self.max_device_staging_mib,
             transport=self.transport,
             transport_staging_slots=self.transport_staging_slots,
@@ -215,8 +200,7 @@ class TableTunables:
     """[table] — metadata-plane scaling knobs (docs/OBSERVABILITY.md
     "Metadata plane"): batched Merkle digestion, batched anti-entropy
     descent and bucket-sharded listing fan-out.  Every knob has a
-    `<= 1` escape hatch that restores the serial/per-node behavior
-    (the bench's paired A/B baseline)."""
+    `<= 1` escape hatch that restores the serial/per-node behavior."""
 
     # todo items drained per batched Merkle pass (table/merkle.py):
     # shared trie path nodes are rewritten and re-hashed ONCE per batch

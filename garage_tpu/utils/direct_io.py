@@ -6,7 +6,7 @@ GiB/s at ~92% CPU (the page-cache copy burns the core), while O_DIRECT
 reads the same files at 2.9 GiB/s at ~7% CPU.  For the scrub pipeline
 the difference is structural — with buffered reads, read and verify
 cannot overlap on one core and sustained throughput collapses to the
-harmonic mean of disk and codec (BENCH_r04's 0.24 GiB/s); with O_DIRECT
+harmonic mean of disk and codec (0.24 GiB/s in round 4); with O_DIRECT
 the core belongs to the codec and sustained approaches the codec rate.
 
 Bypassing the page cache is also the RIGHT semantic for scrub: a scrub

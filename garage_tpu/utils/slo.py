@@ -29,8 +29,7 @@ without snapshot diffing).  Injectable clock; everything bounded
 
 Exported: ``slo_error_budget_remaining{endpoint,slo}`` and
 ``slo_burn_rate{endpoint,slo,window}``; read side is admin
-``slo_status`` / CLI ``slo status`` and the per-phase ``slo_report``
-block bench.py embeds.
+``slo_status`` / CLI ``slo status``.
 """
 
 from __future__ import annotations
@@ -273,24 +272,6 @@ class SloTracker:
                 out.append((
                     {"endpoint": ep, "slo": slo, "window": "slow"},
                     round(self.burn_rate(ep, slo, self.tun.slow_window_s), 6)))
-        return out
-
-    def report(self) -> Dict[str, dict]:
-        """Raw per-endpoint window counts — bench.py aggregates these
-        across cluster nodes into its per-phase ``slo_report`` (burn
-        rates must be recomputed over the MERGED counts, not averaged)."""
-        now = self.clock()
-        out: Dict[str, dict] = {}
-        for ep, ring in self._rings.items():
-            obj = self.objective(ep)
-            ft, fe, fs = ring.window(now, self.tun.fast_window_s)
-            st, se, ss = ring.window(now, self.tun.slow_window_s)
-            out[ep] = {
-                "availability_target": obj["availability"],
-                "latency_target_ms": round(obj["latency_s"] * 1000.0, 1),
-                "fast": {"total": ft, "err": fe, "slow": fs},
-                "slow": {"total": st, "err": se, "slow": ss},
-            }
         return out
 
     def status(self) -> List[dict]:

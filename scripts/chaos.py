@@ -162,7 +162,7 @@ async def run(phases, secs):
     import aiohttp
     import numpy as np
 
-    import bench
+    from garage_tpu.testing.local_cluster import S3, mk_cluster
     from garage_tpu.testing.faults import (
         FAST_CHAOS_HEALTH,
         FAST_CHAOS_RPC,
@@ -175,7 +175,7 @@ async def run(phases, secs):
     with tempfile.TemporaryDirectory(prefix="garage_chaos_") as tmp:
         from pathlib import Path
 
-        garages, server, port, kid, secret = await bench._mk_cluster(
+        garages, server, port, kid, secret = await mk_cluster(
             Path(tmp), n=3, repl="3", db="memory",
             codec_cfg={"rs_data": 0, "rs_parity": 0, "backend": "cpu"},
             rpc_cfg=FAST_CHAOS_RPC, health_cfg=FAST_CHAOS_HEALTH)
@@ -183,7 +183,7 @@ async def run(phases, secs):
         await inj.add_network_faults(rng=random.Random(7))
         try:
             async with aiohttp.ClientSession() as session:
-                s3 = bench._S3(session, port, kid, secret)
+                s3 = S3(session, port, kid, secret)
                 st, _b, _h = await s3.req("PUT", "/chaos")
                 assert st == 200, f"bucket create: {st}"
                 for phase in phases:
@@ -393,7 +393,7 @@ async def run_repair_storm(secs):
     import aiohttp
     import numpy as np
 
-    import bench
+    from garage_tpu.testing.local_cluster import S3, mk_cluster
     from garage_tpu.testing.faults import (
         FAST_CHAOS_RPC,
         FaultInjector,
@@ -407,7 +407,7 @@ async def run_repair_storm(secs):
     with tempfile.TemporaryDirectory(prefix="garage_storm_") as tmp:
         from pathlib import Path
 
-        garages, server, port, kid, secret = await bench._mk_cluster(
+        garages, server, port, kid, secret = await mk_cluster(
             Path(tmp), n=6, repl="3", data_repl="none", db="memory",
             codec_cfg={"rs_data": 2, "rs_parity": 2,
                        "store_parity": True, "parity_on_write": True,
@@ -416,7 +416,7 @@ async def run_repair_storm(secs):
         inj = FaultInjector(garages)
         try:
             async with aiohttp.ClientSession() as session:
-                s3 = bench._S3(session, port, kid, secret)
+                s3 = S3(session, port, kid, secret)
                 st, _b, _h = await s3.req("PUT", "/storm")
                 assert st == 200, f"bucket create: {st}"
                 acked = {}

@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402
+from garage_tpu.testing.local_cluster import S3, mk_cluster  # noqa: E402
 
 BLOCK = 1 << 20
 PART = 32 << 20
@@ -51,12 +51,12 @@ async def drive() -> float:
     try:
         # 2 nodes, 2 replicas: every block leaves the gateway through
         # the netapp frame pump to the peer (plus a local write)
-        garages, server, port, kid, secret = await bench._mk_cluster(
+        garages, server, port, kid, secret = await mk_cluster(
             tmp, n=2, repl="2", codec_cfg={"backend": "cpu"})
         rng = np.random.default_rng(9)
         base = rng.integers(0, 256, PART, dtype=np.uint8)
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/pbkt")
             assert st == 200
             st, body, _h = await s3.req("POST", "/pbkt/big",

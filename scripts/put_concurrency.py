@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402
+from garage_tpu.testing.local_cluster import S3, mk_cluster  # noqa: E402
 
 BLOCK = 1 << 20
 N_SERIAL = 48
@@ -48,11 +48,11 @@ async def drive(n_nodes, repl, label, out):
 
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="putconc_"))
     try:
-        garages, server, port, kid, secret = await bench._mk_cluster(
+        garages, server, port, kid, secret = await mk_cluster(
             tmp, n=n_nodes, repl=repl, codec_cfg={"backend": "cpu"})
         rng = np.random.default_rng(2)
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/bkt")
             assert st == 200
             await s3.req("PUT", "/bkt/warm",

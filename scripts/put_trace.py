@@ -13,7 +13,7 @@ sys.path.insert(0, "/root/repo")
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402
+from garage_tpu.testing.local_cluster import S3, mk_cluster  # noqa: E402
 
 N = 60
 BLOCK = 1 << 20
@@ -28,7 +28,7 @@ async def main():
 
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="put_trace_"))
     try:
-        garages, server, port, kid, secret = await bench._mk_cluster(
+        garages, server, port, kid, secret = await mk_cluster(
             tmp, n=1, repl="none", codec_cfg={"backend": "cpu"})
         g = garages[0]
         tracer = g.system.tracer
@@ -37,7 +37,7 @@ async def main():
         rng = np.random.default_rng(1)
         lat = []
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/bkt")
             assert st == 200
             await s3.req("PUT", "/bkt/warmup",

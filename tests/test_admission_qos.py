@@ -544,7 +544,7 @@ async def test_gossiped_pressure_sheds_at_gateway(tmp_path):
 
     import aiohttp
 
-    import bench
+    from garage_tpu.testing.local_cluster import S3
     from garage_tpu.testing.sim_cluster import SimCluster
 
     cluster = SimCluster(
@@ -555,8 +555,7 @@ async def test_gossiped_pressure_sheds_at_gateway(tmp_path):
         g0 = cluster.garages[0]
         gate = g0.admission
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, cluster.port, cluster.key_id,
-                           cluster.secret)
+            s3 = S3(session, cluster.port, cluster.key_id, cluster.secret)
             st, _b, _h = await s3.req("PUT", "/pressbkt")
             assert st == 200
             # first object request teaches the probe the placement

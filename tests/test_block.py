@@ -407,15 +407,14 @@ async def test_streaming_get_midstream_failover(tmp_path):
 
 async def test_scrub_with_hybrid_codec(tmp_path):
     """The production scrub worker runs with codec backend='hybrid'
-    (config-selected): corruption detection works identically while the
-    work-stealing engine splits batches between CPU and the device
-    backend (JAX CPU platform here)."""
+    (config-selected): corruption detection works identically whichever
+    side of the link gate runs a batch (the device backend is the JAX
+    CPU platform here)."""
     systems, managers = await make_block_cluster(tmp_path, n=1, mode="1")
     m = managers[0]
     from garage_tpu.ops import make_codec
 
-    m.codec = make_codec("hybrid", rs_data=4, rs_parity=2,
-                         hybrid_group_blocks=8)
+    m.codec = make_codec("hybrid", rs_data=4, rs_parity=2)
     datas = [os.urandom(20_000) for _ in range(24)]
     hashes = [blake2s_sum(d) for d in datas]
     for h, d in zip(hashes, datas):

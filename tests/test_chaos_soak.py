@@ -27,9 +27,9 @@ import time
 import numpy as np
 import pytest
 
-import bench
 from garage_tpu.rpc.layout import ClusterLayout, NodeRole
 from garage_tpu.testing.faults import FaultInjector
+from garage_tpu.testing.local_cluster import S3, mk_cluster
 
 SOAK_S = float(os.environ.get("GARAGE_SOAK_SECONDS", "40"))
 HEAL_CAP_S = max(180.0, SOAK_S / 2)
@@ -49,7 +49,7 @@ async def _drain_resync(garages, deadline):
 async def test_chaos_soak(tmp_path):
     import aiohttp
 
-    garages, server, port, kid, secret = await bench._mk_cluster(
+    garages, server, port, kid, secret = await mk_cluster(
         tmp_path, n=6, repl="3", data_repl="none", db="sqlite",
         codec_cfg={
             "rs_data": 2, "rs_parity": 2,
@@ -173,7 +173,7 @@ async def test_chaos_soak(tmp_path):
         stop.set()
 
     async with aiohttp.ClientSession() as session:
-        s3 = bench._S3(session, port, kid, secret)
+        s3 = S3(session, port, kid, secret)
         st, _b, _h = await s3.req("PUT", "/soak")
         assert st == 200
         await asyncio.gather(client_loop(s3), chaos_loop())

@@ -43,9 +43,7 @@ class TestBlake2s:
         """The TPU path unrolls all 10 rounds; CPU uses a rolled scan.
         Both must be bit-identical.  Runs EAGERLY (un-jitted): XLA-CPU
         compile of the unrolled body hangs under the forced-8-device test
-        platform; op-by-op eager avoids the compile entirely.  (On real
-        TPU the unrolled graph is exercised by bench.py, which asserts
-        every digest against hashlib-derived expectations.)"""
+        platform; op-by-op eager avoids the compile entirely."""
         import jax.numpy as jnp
 
         from garage_tpu.ops.tpu_blake2s import compress, compress_rolled

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from garage_tpu.ops.codec import CodecParams
+from garage_tpu.ops.feeder import CodecFeeder
 from garage_tpu.ops.hybrid_codec import HybridCodec
 from garage_tpu.testing.synthetic_device import SyntheticLinkCodec
 from garage_tpu.utils.data import Hash
@@ -23,9 +24,6 @@ pytestmark = pytest.mark.asyncio
 
 
 def _mk_batch(n=256, size=1 << 16, seed=0):
-    """Big enough (16 MiB at the defaults) that the CPU floor cannot
-    drain the whole deque before the feeder claims its first merge —
-    the 1-core CI host needs real work for the steal to be observable."""
     rng = np.random.default_rng(seed)
     blocks = [rng.integers(0, 256, size, dtype=np.uint8).tobytes()
               for _ in range(n)]
@@ -37,47 +35,47 @@ def _mk_batch(n=256, size=1 << 16, seed=0):
 def _params(**kw):
     kw.setdefault("rs_data", 8)
     kw.setdefault("rs_parity", 4)
-    kw.setdefault("hybrid_group_blocks", 16)
     return CodecParams(**kw)
 
 
+def _scrub_through_feeder(hy, blocks, hashes):
+    """One background scrub batch as the ScrubWorker submits it: the
+    feeder refreshes the gate and hands an open-gate batch to the
+    transport."""
+    f = CodecFeeder(hy, slo_ms=1.0, max_batch_blocks=256)
+    try:
+        return f.submit_scrub(blocks, hashes, False).result(timeout=60)
+    finally:
+        f.shutdown()
+
+
 def test_stage_histograms_and_bytes_by_side_scrapeable():
-    """An open-gate hybrid pass must leave per-stage histograms and
+    """An open-gate scrub batch must leave per-stage histograms and
     bytes-by-side counters in the registry from which tpu_frac > 0 is
     computable — the acceptance bar of the observability tentpole."""
     params = _params()
     blocks, hashes = _mk_batch()
-    # work stealing is timing-dependent: on a loaded host the CPU side
-    # can occasionally drain the whole deque before the feeder's first
-    # claim — retry a fresh pass (bounded) rather than flake
-    for _attempt in range(3):
-        reg = MetricsRegistry()
-        dev = SyntheticLinkCodec(params, link_gibs=100.0,
-                                 compute_real=True)
-        hy = HybridCodec(params, device_codec=dev, metrics=reg)
-        out = hy.scrub_many([(blocks, hashes)], fetch_parity=False)
-        assert all(ok.all() for ok, _p in out)
-        _cpu_b, tpu_b = hy.pop_stats()
-        if tpu_b > 0:
-            break
-    assert tpu_b > 0, "synthetic device took no work through an open gate"
+    reg = MetricsRegistry()
+    dev = SyntheticLinkCodec(params, link_gibs=100.0, compute_real=True)
+    hy = HybridCodec(params, device_codec=dev, metrics=reg)
+    ok, _parity = _scrub_through_feeder(hy, blocks, hashes)
+    assert ok.all()
 
-    # scrapeable ratio: the counters, not pop_stats, carry the split
-    assert hy.obs.bytes_total["tpu"] > 0
+    # scrapeable ratio: the counters carry the split
+    assert hy.obs.bytes_total["tpu"] == 256 << 16
     assert hy.obs.tpu_frac() > 0.0
     text = reg.render()
     assert 'codec_bytes_total{side="tpu"}' in text
-    assert 'codec_bytes_total{side="cpu"}' in text
     assert "codec_stage_duration_seconds_bucket" in text
 
-    # per-stage attribution exists for the device pipeline stages the
-    # hybrid engine itself records (the synthetic device has no internal
+    # per-stage attribution exists for the gate's probe and the
+    # transport's pipeline stages (the synthetic device has no internal
     # h2d/kernel refinement — a real TpuCodec adds those)
     stats = hy.obs.stage_stats()
-    for stage in ("feeder_wait/tpu", "host_staging/tpu",
-                  "device_submit/tpu", "sync_collect/tpu"):
+    for stage in ("probe/tpu", "host_staging/tpu", "device_submit/tpu",
+                  "sync_collect/tpu"):
         assert stage in stats and stats[stage]["count"] > 0, stats.keys()
-    assert any(k.startswith("cpu_span/") for k in stats), stats.keys()
+    hy.close()
 
 
 def test_gate_event_ring_open_and_hold():
@@ -86,31 +84,64 @@ def test_gate_event_ring_open_and_hold():
     dev = SyntheticLinkCodec(params, link_gibs=50.0, compute_real=True)
     hy = HybridCodec(params, device_codec=dev)
     blocks, hashes = _mk_batch()
-    hy.scrub_many([(blocks, hashes)], fetch_parity=False)
+    _scrub_through_feeder(hy, blocks, hashes)
     kinds = {(e["kind"], e.get("reason")) for e in hy.obs.events_list()}
     assert ("probe", "ok") in kinds, kinds
     assert ("gate", "open") in kinds, kinds
     probe_evt = [e for e in hy.obs.events_list() if e["kind"] == "probe"][-1]
     assert probe_evt["gibs"] == pytest.approx(50.0)
+    hy.close()
 
-    # below-threshold link: the ring must carry the hold with the rate.
-    # The feeder is deliberately not joined (hedged-tail design), so the
-    # gate event may land moments after scrub_many returns — poll.
-    import time
-
+    # below-threshold link: the ring must carry the hold with the rate
     p2 = _params(hybrid_min_link_gibs=1.0)
     dev2 = SyntheticLinkCodec(p2, link_gibs=0.001, compute_real=True)
     hy2 = HybridCodec(p2, device_codec=dev2)
-    hy2.scrub_many([(blocks, hashes)], fetch_parity=False)
-    deadline = time.monotonic() + 10.0
-    holds = []
-    while time.monotonic() < deadline and not holds:
-        holds = [e for e in hy2.obs.events_list()
-                 if e["kind"] == "gate" and e["reason"] == "hold"]
-        time.sleep(0.02)
+    ok, _parity = _scrub_through_feeder(hy2, blocks, hashes)
+    assert ok.all()
+    holds = [e for e in hy2.obs.events_list()
+             if e["kind"] == "gate" and e["reason"] == "hold"]
     assert holds, hy2.obs.events_list()
     assert holds[-1]["gibs"] == pytest.approx(0.001)
     assert hy2.obs.bytes_total["tpu"] == 0
+    hy2.close()
+
+
+async def test_gate_and_link_gibs_reported_on_the_feeder_road(tmp_path):
+    """`codec info`, the admin stats and the codec_link_gibs gauge name
+    the gate's verdict on a codec that has only ever been driven
+    through CodecFeeder (every node): the verdict's telemetry is
+    written where the probe is taken, whoever asked for it."""
+    from garage_tpu.admin.handler import AdminRpcHandler
+
+    g = await _mk_garage(tmp_path)
+    try:
+        for link, gate in ((100.0, "open"), (0.001, "hold")):
+            params = _params()
+            dev = SyntheticLinkCodec(params, link_gibs=link,
+                                     compute_real=True)
+            hy = HybridCodec(params, device_codec=dev)
+            assert hy.info()["gate"] is None     # nothing probed yet
+            g.block_manager.codec = hy
+            blocks, hashes = _mk_batch(n=32)
+            ok, _parity = await asyncio.to_thread(
+                _scrub_through_feeder, hy, blocks, hashes)
+            assert ok.all()
+            info = hy.info()
+            assert info["gate"] == gate
+            assert info["link_gibs"] == pytest.approx(link)
+            assert info["link_stages"]
+            # the engine's three fields went with it
+            assert not [k for k in info if "blocks" in k or k == "window"]
+            admin = AdminRpcHandler(g, register_endpoint=False)
+            stats = (await admin._cmd_stats({}))["codec"]
+            assert stats["gate"] == gate
+            assert stats["link_gibs"] == pytest.approx(link)
+            gauge, = [ln for ln in g.system.metrics.render().splitlines()
+                      if ln.startswith("codec_link_gibs ")]
+            assert float(gauge.split()[-1]) == pytest.approx(link)
+            hy.close()
+    finally:
+        await g.shutdown()
 
 
 def test_event_ring_is_bounded():
@@ -126,34 +157,9 @@ def test_event_ring_is_bounded():
     assert evs[-1]["seq"] == 100
 
 
-def test_staging_clamp_emits_event():
-    params = _params(device_batch_blocks=8192, hybrid_window=3,
-                     max_device_staging_mib=1024)
-    hy = HybridCodec(params, build_device=False)
-    # (window+1)=4 × width must fit in 1024 MiB at 1 MiB blocks → 256
-    assert hy.device_batch_blocks == 256
-    clamps = [e for e in hy.obs.events_list() if e["kind"] == "staging_clamp"]
-    assert clamps and clamps[0]["requested"] == 8192
-    assert clamps[0]["clamped"] == 256
-
-    # the clamp honors the CONFIGURED block size, not a 1 MiB
-    # assumption: 4 MiB blocks quarter the allowed width
-    p4 = _params(device_batch_blocks=8192, hybrid_window=3,
-                 max_device_staging_mib=1024, block_size=4 << 20)
-    hy4 = HybridCodec(p4, build_device=False)
-    assert hy4.device_batch_blocks == 64
-
-    # defaults don't clamp (1024 blocks × 2 in flight × 1 MiB = 2 GiB
-    # under the 4 GiB default cap)
-    hy_def = HybridCodec(_params(), build_device=False)
-    assert hy_def.device_batch_blocks == 1024
-    assert not [e for e in hy_def.obs.events_list()
-                if e["kind"] == "staging_clamp"]
-
-
 def test_fused_latch_sync_failure_demotes(monkeypatch):
-    """Round-5 ADVICE #1: sync-time kernel failures (surfacing at
-    np.asarray in the hybrid collect) must feed the fused-scrub demotion
+    """Sync-time kernel failures (surfacing at np.asarray in the
+    transport's collect) must feed the fused-scrub demotion
     latch, and the failure counter must reset only after a successful
     host-side materialization."""
     from garage_tpu.ops.tpu_codec import PALLAS_MAX_TRANSIENT_FAILS, TpuCodec
@@ -213,12 +219,8 @@ def test_hybrid_collect_reports_sync_failure_to_device():
     class _SyncFailDevice(SyntheticLinkCodec):
         last_submit_variant = "pallas"
 
-        def scrub_submit(self, blocks, hashes):
-            class _Boom:
-                def __array__(self, *a, **kw):
-                    raise RuntimeError("UNAVAILABLE: sync failed")
-            self.submissions += 1
-            return _Boom(), None, len(blocks)
+        def scrub_collect(self, out, fetch_parity):
+            raise RuntimeError("UNAVAILABLE: sync failed")
 
         def note_sync_failure(self, e, variant=None):
             noted.append((type(e).__name__, variant))
@@ -226,20 +228,17 @@ def test_hybrid_collect_reports_sync_failure_to_device():
         def note_sync_success(self, variant=None):
             noted.append(("ok", variant))
 
-    blocks, hashes = _mk_batch()
-    # bounded retry: the CPU side can drain the deque before the feeder
-    # claims anything on a loaded host (no submission → nothing to fail)
-    for _attempt in range(3):
-        dev = _SyncFailDevice(params, link_gibs=100.0)
-        hy = HybridCodec(params, device_codec=dev)
-        out = hy.scrub_many([(blocks, hashes)], fetch_parity=False)
-        assert all(ok.all() for ok, _p in out), \
-            "CPU did not absorb the failure"
-        if ("RuntimeError", "pallas") in noted:
-            break
-    assert ("RuntimeError", "pallas") in noted, noted
+    blocks, hashes = _mk_batch(n=32)
+    dev = _SyncFailDevice(params, link_gibs=100.0)
+    hy = HybridCodec(params, device_codec=dev)
+    ok, _parity = _scrub_through_feeder(hy, blocks, hashes)
+    assert ok.all(), "CPU did not absorb the failure"
+    assert dev.array_submissions == 1
+    assert noted == [("RuntimeError", "pallas")], noted
     kinds = {e["kind"] for e in hy.obs.events_list()}
-    assert "sync_failure" in kinds
+    assert {"transport_error", "transport_fallback"} <= kinds
+    assert hy.obs.bytes_total == {"cpu": 32 << 16, "tpu": 0}
+    hy.close()
 
 
 def test_slow_op_log_always_on():
@@ -312,13 +311,7 @@ async def test_admin_codec_info_events_and_slow_ops(tmp_path):
                          tracer=g.system.tracer)
         g.block_manager.codec = hy
         blocks, hashes = _mk_batch()
-        await asyncio.to_thread(
-            hy.scrub_many, [(blocks, hashes)], False)
-        for _attempt in range(2):
-            if hy.obs.bytes_total["tpu"] > 0:
-                break  # stealing is timing-dependent; retry a pass
-            await asyncio.to_thread(
-                hy.scrub_many, [(blocks, hashes)], False)
+        await asyncio.to_thread(_scrub_through_feeder, hy, blocks, hashes)
 
         admin = AdminRpcHandler(g, register_endpoint=False)
         info = await admin._cmd_codec_info({})
@@ -371,7 +364,7 @@ async def test_metrics_endpoint_serves_codec_families(tmp_path):
                          tracer=g.system.tracer)
         g.block_manager.codec = hy
         blocks, hashes = _mk_batch()
-        await asyncio.to_thread(hy.scrub_many, [(blocks, hashes)], False)
+        await asyncio.to_thread(_scrub_through_feeder, hy, blocks, hashes)
 
         srv = AdminApiServer(g)
         await srv.start("127.0.0.1:0")
@@ -382,13 +375,13 @@ async def test_metrics_endpoint_serves_codec_families(tmp_path):
                 assert r.status == 200
                 text = await r.text()
         # tpu_frac computable from the exposition alone
-        cpu_b = tpu_b = None
+        cpu_b, tpu_b = 0.0, None   # a side with no bytes has no series
         for line in text.splitlines():
             if line.startswith('codec_bytes_total{side="cpu"}'):
                 cpu_b = float(line.split()[-1])
             if line.startswith('codec_bytes_total{side="tpu"}'):
                 tpu_b = float(line.split()[-1])
-        assert cpu_b is not None and tpu_b is not None, "families missing"
+        assert tpu_b is not None, "families missing"
         assert tpu_b > 0 and tpu_b / (cpu_b + tpu_b) > 0
         assert "codec_stage_duration_seconds_bucket" in text
         assert "tracer_slow_op_max_seconds" in text

@@ -363,8 +363,7 @@ def test_native_runtime_compaction_bounds_log(tmp_path):
     d.close()
 
 
-# --- memory-db WAL recovery diagnostics + snapshot durability
-#     (round-5 ADVICE #2 and #3) ---
+# --- memory-db WAL recovery diagnostics + snapshot durability ---
 
 
 def _mem_wal_path(p):

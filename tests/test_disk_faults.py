@@ -673,11 +673,11 @@ async def test_chaos_flaky_disk_plus_enospc(tmp_path):
     import aiohttp
     import numpy as np
 
-    import bench
+    from garage_tpu.testing.local_cluster import S3, mk_cluster
     from garage_tpu.net.frame import PRIO_HIGH
     from garage_tpu.testing.faults import FAST_CHAOS_RPC, FaultInjector
 
-    garages, server, port, kid, secret = await bench._mk_cluster(
+    garages, server, port, kid, secret = await mk_cluster(
         tmp_path, n=3, repl="3", db="memory",
         codec_cfg={"rs_data": 0, "rs_parity": 0, "backend": "cpu"},
         rpc_cfg=FAST_CHAOS_RPC)
@@ -691,7 +691,7 @@ async def test_chaos_flaky_disk_plus_enospc(tmp_path):
         fd = inj.flaky_disk(2, prob=0.3)
         inj.fill_disk(2)
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/dchaos")
             assert st == 200, st
             errors = []

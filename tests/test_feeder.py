@@ -298,7 +298,7 @@ def test_hybrid_ragged_routes_cpu_when_gated():
 
 
 def test_hybrid_ragged_routes_unmetered_device():
-    """A scripted device with no probe_link hook and no warm_scrub
+    """A scripted device with no probe_link hook and no metered_link
     marker is 'unmetered' — _probe_link treats it as a healthy link and
     ragged_side() must agree (regression: the unmetered verdict never
     enters the probe cache, so reading only _link_rate routed every
@@ -310,7 +310,7 @@ def test_hybrid_ragged_routes_unmetered_device():
     params = CodecParams(rs_data=K, rs_parity=M)
 
     class _BareDevice(CpuCodec):
-        """CPU math posing as a device: no probe_link, no warm_scrub."""
+        """CPU math posing as a device: no probe_link, no metered_link."""
 
     hy = HybridCodec(params, device_codec=_BareDevice(params),
                      build_device="sync")

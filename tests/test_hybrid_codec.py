@@ -1,10 +1,11 @@
-"""HybridCodec: work-stealing split between CPU and device backends.
+"""HybridCodec: the CPU floor and the device behind the link gate.
 
-Checks the hybrid scheduler's contract: results are bit-identical to the
-CPU codec whichever backend processed a group, the device contributes when
-healthy, and a slow or broken device never blocks or corrupts a scrub
-(the CPU absorbs the deque).  Runs on the virtual CPU platform — "device"
-here is the JAX CPU backend or a scripted fake.
+Checks the bytes-level contract: results are bit-identical to the CPU
+codec whichever side of the gate ran a batch, the whole batch goes to
+the side the gate names, and a broken device never fails or corrupts a
+call (the CPU floor runs it).  Runs on the virtual CPU platform —
+"device" here is the JAX CPU backend or a scripted fake.  The road the
+product runs (feeder → transport) is tests/test_transport.py's.
 """
 
 import hashlib
@@ -25,9 +26,24 @@ K, M = 4, 2
 def _params(**kw):
     kw.setdefault("rs_data", K)
     kw.setdefault("rs_parity", M)
-    kw.setdefault("hybrid_group_blocks", 8)
-    kw.setdefault("hybrid_window", 2)
     return CodecParams(**kw)
+
+
+# The gate's verdict on a REAL device codec is a wall-clock rate (16 MiB
+# through the JAX CPU backend, under five other test workers): where a
+# test asserts the side, the threshold decides it (0 opens on any probe
+# that completes, _SHUT holds on any), never the clock.
+_OPEN, _SHUT = 0.0, 1e9
+
+
+def _attached(hy):
+    """Wait for make_codec's background device attach."""
+    for _ in range(200):
+        if hy.tpu is not None:
+            break
+        time.sleep(0.05)
+    assert hy.tpu is not None
+    return hy
 
 
 def _mk_blocks(n, size=2048, seed=0):
@@ -40,30 +56,23 @@ def _mk_blocks(n, size=2048, seed=0):
 
 
 class _FakeDevice:
-    """Scripted device codec: CPU math with controllable latency/failure."""
+    """Scripted device codec (unmetered: no probe hook, so the gate
+    treats it as healthy): CPU math with a controllable failure."""
 
-    def __init__(self, params, delay=0.0, fail=False):
+    def __init__(self, params, fail=False):
         self.cpu = CpuCodec(params)
         self.params = params
-        self.delay = delay
         self.fail = fail
         self.submitted = 0
 
-    def scrub_submit(self, blocks, hashes):
+    def scrub_encode_batch(self, blocks, hashes, fetch_parity=True):
         self.submitted += 1
         if self.fail:
             raise RuntimeError("injected device failure")
-        if self.delay:
-            time.sleep(self.delay)
-        ok = self.cpu.batch_verify(blocks, hashes)
-        k = self.params.rs_data
-        pad = (-len(blocks)) % k
-        maxlen = max(len(b) for b in blocks)
-        arr = np.zeros((len(blocks) + pad, maxlen), dtype=np.uint8)
-        for i, b in enumerate(blocks):
-            arr[i, : len(b)] = np.frombuffer(b, dtype=np.uint8)
-        parity = self.cpu.rs_encode(arr.reshape(-1, k, maxlen))
-        return ok, parity, len(blocks)
+        return self.cpu.scrub_encode_batch(blocks, hashes, fetch_parity)
+
+    def batch_verify(self, blocks, hashes):
+        return self.scrub_encode_batch(blocks, hashes, False)[0]
 
 
 def test_hybrid_matches_cpu_with_corruption():
@@ -72,9 +81,15 @@ def test_hybrid_matches_cpu_with_corruption():
     bad[7] = b"\xff" + blocks[7][1:]
     bad[23] = blocks[23][:-1] + b"\x00"
     blocks = [bad[i] for i in range(len(blocks))]
-    hy = make_codec("hybrid", **vars(_params()))
+    hy = _attached(make_codec(
+        "hybrid", **vars(_params(hybrid_min_link_gibs=_OPEN))))
     cpu = CpuCodec(_params())
+    # a bytes-level call is background work with no feeder in front of
+    # it: it takes the gate's probe itself ([codec] feeder = false)
+    assert hy.ragged_side() == "cpu" and hy.last_gate is None
     ok = hy.batch_verify(blocks, hashes)
+    assert hy.ragged_side() == "tpu" and hy.last_gate == "open", hy.info()
+    assert hy.obs.bytes_total == {"cpu": 0, "tpu": 40 * 2048}
     assert ok.shape == (40,)
     expect = cpu.batch_verify(blocks, hashes)
     assert np.array_equal(ok, expect)
@@ -98,12 +113,16 @@ def test_hybrid_parity_identical_across_backends():
     # canonical parity must equal the whole-batch CPU reference, including
     # a partial trailing group exercising the device-side shape trim
     blocks, hashes = _mk_blocks(19, size=1000)
-    hy = HybridCodec(_params())
-    ok, parity = hy.scrub_encode_batch(blocks, hashes)
-    assert ok.all()
     expect = _cpu_reference_parity(blocks)
-    assert parity.shape == expect.shape
-    assert np.array_equal(parity, expect)
+    for side, min_link in (("cpu", _SHUT), ("tpu", _OPEN)):
+        hy = HybridCodec(_params(hybrid_min_link_gibs=min_link))
+        ok, parity = hy.scrub_encode_batch(blocks, hashes)
+        assert ok.all()
+        assert parity.shape == expect.shape
+        assert np.array_equal(parity, expect)
+        assert hy.ragged_side() == side
+        assert hy.obs.bytes_total[side] == 19000, hy.obs.bytes_total
+        hy.close()
 
 
 def test_scrub_encode_batch_contract_tpu_vs_hybrid():
@@ -113,7 +132,7 @@ def test_scrub_encode_batch_contract_tpu_vs_hybrid():
 
     blocks, hashes = _mk_blocks(19, size=768, seed=5)
     tpu = TpuCodec(_params())
-    hy = HybridCodec(_params())
+    hy = HybridCodec(_params(hybrid_min_link_gibs=_OPEN))
     ok_t, par_t = tpu.scrub_encode_batch(blocks, hashes)
     ok_h, par_h = hy.scrub_encode_batch(blocks, hashes)
     assert np.array_equal(ok_t, ok_h)
@@ -126,107 +145,67 @@ def test_scrub_encode_batch_contract_tpu_vs_hybrid():
     assert np.array_equal(ok_t2, ok_h2)
 
 
-def test_hybrid_steals_from_slow_device():
-    # device sleeps per group: the CPU must drain most of the deque and the
-    # call must complete well before the device could have done it alone
+def test_hybrid_routes_the_whole_batch_to_the_side_the_gate_names():
+    # one routing rule: a healthy device takes the call whole (one
+    # submission, every byte on its side), no split with the floor
     p = _params()
-    dev = _FakeDevice(p, delay=0.15)
+    dev = _FakeDevice(p)
     hy = HybridCodec(p, device_codec=dev)
-    blocks, hashes = _mk_blocks(80)
-    t0 = time.monotonic()
-    ok = hy.batch_verify(blocks, hashes)
-    dt = time.monotonic() - t0
+    blocks, hashes = _mk_blocks(37)
+    ok, parity = hy.scrub_encode_batch(blocks, hashes)
     assert ok.all()
-    bytes_cpu, bytes_tpu = hy.pop_stats()
-    assert bytes_cpu > 0, "CPU side never stole work"
-    assert bytes_cpu + bytes_tpu == sum(len(b) for b in blocks)
-    ngroups = 10
-    assert dt < dev.delay * ngroups, "CPU stealing did not shorten the pass"
+    assert np.array_equal(parity, _cpu_reference_parity(blocks))
+    assert hy.batch_verify(blocks, hashes).all()
+    assert dev.submitted == 2
+    assert hy.obs.bytes_total == {"cpu": 0, "tpu": 2 * 37 * 2048}
 
 
 def test_hybrid_absorbs_device_failure():
+    # a device that raises costs the caller nothing: the CPU floor's
+    # answer, the failure in the event ring, every byte on the cpu side
+    # (fails if _routed's fallback is removed)
     p = _params()
-    hy = HybridCodec(p, device_codec=_FakeDevice(p, fail=True))
+    dev = _FakeDevice(p, fail=True)
+    hy = HybridCodec(p, device_codec=dev)
     blocks, hashes = _mk_blocks(32)
+    blocks[5] = b"\x00" * 2048
     ok, parity = hy.scrub_encode_batch(blocks, hashes)
-    assert ok.all()
+    assert not ok[5] and ok.sum() == 31
     assert np.array_equal(parity, _cpu_reference_parity(blocks))
-    _, bytes_tpu = hy.pop_stats()
-    assert bytes_tpu == 0
+    assert np.array_equal(hy.batch_verify(blocks, hashes), ok)
+    assert dev.submitted == 2
+    assert hy.obs.bytes_total == {"cpu": 2 * 32 * 2048, "tpu": 0}
+    fails = [e for e in hy.obs.events_list() if e["kind"] == "sync_failure"]
+    assert len(fails) == 2 and fails[0]["reason"] == "RuntimeError"
 
 
 def test_hybrid_real_device_backend_equivalence():
-    # the real TpuCodec as device (JAX CPU platform here): full pipeline
-    # through jitted kernels, concurrent feeder thread included.
-    # make_codec builds the device codec asynchronously (daemon-safe);
-    # wait for the attach before asserting it participates.
+    # the real TpuCodec as device (JAX CPU platform here): the bytes-
+    # level call through its jitted kernels.  make_codec builds the
+    # device codec asynchronously (daemon-safe); wait for the attach
+    # before asserting it participates.
     blocks, hashes = _mk_blocks(48, size=512, seed=3)
-    hy = make_codec("hybrid", **vars(_params()))
-    for _ in range(200):
-        if hy.tpu is not None:
-            break
-        time.sleep(0.05)
-    assert hy.tpu is not None
+    hy = _attached(make_codec(
+        "hybrid", **vars(_params(hybrid_min_link_gibs=_OPEN))))
     ok, parity = hy.scrub_encode_batch(blocks, hashes)
+    assert hy.last_gate == "open", hy.info()
     assert ok.all()
     assert np.array_equal(parity, _cpu_reference_parity(blocks))
-
-
-def test_hybrid_scrub_many_stream():
-    # multi-batch stream through one deque; per-batch result slicing with a
-    # corruption planted in the middle batch
-    hy = HybridCodec(_params())
-    stream = []
-    for s in range(3):
-        blocks, hashes = _mk_blocks(16, seed=s)
-        stream.append((list(blocks), hashes))
-    stream[1][0][5] = b"\x00" * 2048
-    out = hy.scrub_many(stream, fetch_parity=True)
-    assert len(out) == 3
-    ok0, par0 = out[0]
-    ok1, _ = out[1]
-    assert ok0.all() and out[2][0].all()
-    assert not ok1[5] and ok1.sum() == 15
-    assert np.array_equal(par0, _cpu_reference_parity(stream[0][0]))
-    assert np.array_equal(out[2][1], _cpu_reference_parity(stream[2][0]))
-    bytes_cpu, bytes_tpu = hy.pop_stats()
-    assert bytes_cpu + bytes_tpu == 3 * 16 * 2048
-
-
-def test_hybrid_scrub_many_unaligned_batches_parity_is_per_batch():
-    # batch sizes NOT multiples of the group quantum: groups are cut at
-    # batch edges, so each batch's parity comes from its own blocks only
-    hy = HybridCodec(_params())  # group_blocks rounds to 8
-    b0, h0 = _mk_blocks(11, size=256, seed=10)
-    b1, h1 = _mk_blocks(13, size=256, seed=11)
-    out = hy.scrub_many([(b0, h0), (b1, h1)], fetch_parity=True)
-    cpu = CpuCodec(_params())
-    g = hy.group_blocks
-    for (blocks, _h), (ok, parity) in zip([(b0, h0), (b1, h1)], out):
-        assert ok.all() and len(ok) == len(blocks)
-        # reference: per-group codewords WITHIN this batch only (groups are
-        # cut at batch edges, then at the g quantum)
-        expect_rows = []
-        for lo in range(0, len(blocks), g):
-            gb = blocks[lo:lo + g]
-            pad = (-len(gb)) % K
-            arr = np.zeros((len(gb) + pad, 256), dtype=np.uint8)
-            for i, b in enumerate(gb):
-                arr[i] = np.frombuffer(b, dtype=np.uint8)
-            expect_rows.append(cpu.rs_encode(arr.reshape(-1, K, 256)))
-        expect = np.concatenate(expect_rows, axis=0)
-        assert parity.shape == expect.shape and np.array_equal(parity, expect)
+    assert hy.obs.bytes_total["tpu"] == 48 * 512
 
 
 def test_hybrid_replication_only_config():
     # rs_data=0 (replication-only, no RS) must construct and verify fine
-    p = CodecParams(rs_data=0, rs_parity=0, hybrid_group_blocks=8)
-    hy = HybridCodec(p, build_device=False)
+    p = CodecParams(rs_data=0, rs_parity=0)
     blocks, hashes = _mk_blocks(20)
-    ok = hy.batch_verify(blocks, hashes)
-    assert ok.all()
-    ok2, parity = hy.scrub_encode_batch(blocks, hashes)
-    assert ok2.all() and parity is None
+    for hy in (HybridCodec(p, build_device=False),
+               HybridCodec(p, device_codec=_FakeDevice(p))):
+        ok = hy.batch_verify(blocks, hashes)
+        assert ok.all()
+        ok2, parity = hy.scrub_encode_batch(blocks, hashes)
+        assert ok2.all() and parity is None
+        (ok3, parity3), = hy.scrub_ragged([(blocks, hashes, True)])
+        assert ok3.all() and parity3 is None
 
 
 def test_hybrid_build_device_false_skips_device():
@@ -238,7 +217,7 @@ def test_hybrid_build_device_false_skips_device():
 
 def test_hybrid_concurrent_calls_thread_safety():
     # two threads scrubbing through one codec instance must not cross wires
-    hy = HybridCodec(_params())
+    hy = HybridCodec(_params(hybrid_min_link_gibs=_OPEN))
     blocks_a, hashes_a = _mk_blocks(24, seed=1)
     blocks_b, hashes_b = _mk_blocks(24, seed=2)
     out = {}
@@ -255,86 +234,16 @@ def test_hybrid_concurrent_calls_thread_safety():
     assert out["a"].all() and out["b"].all()
 
 
-class _RecordingDevice(_FakeDevice):
-    """FakeDevice that records each submission's block count."""
-
-    def __init__(self, params, **kw):
-        super().__init__(params, **kw)
-        self.widths = []
-
-    def scrub_submit(self, blocks, hashes):
-        self.widths.append(len(blocks))
-        return super().scrub_submit(blocks, hashes)
-
-
-def test_hybrid_feeder_merges_groups_into_wide_submissions():
-    # The device hash kernel is one VPU lane per block, so the feeder must
-    # submit MERGED multi-group batches (device_batch_blocks wide), not the
-    # CPU-cache-sized stealing quantum.  A slow-ish device ensures the
-    # deque is deep when the feeder grabs its first merge.
-    p = _params(device_batch_blocks=32)   # group=8 → merges up to 4 groups
-    dev = _RecordingDevice(p, delay=0.02)
-    hy = HybridCodec(p, device_codec=dev)
-    assert hy.device_batch_blocks == 32
-    blocks, hashes = _mk_blocks(160, seed=11)
-    ok, parity = hy.scrub_encode_batch(blocks, hashes)
-    assert ok.all()
-    assert np.array_equal(parity, _cpu_reference_parity(blocks))
-    assert dev.widths, "device never participated"
-    # first submission: deque has 20 groups → steal-half = 10 groups,
-    # capped by the 32-block device batch → 4 groups merged
-    assert max(dev.widths) > p.hybrid_group_blocks, \
-        f"no merging happened: {dev.widths}"
-    assert max(dev.widths) <= 32
-
-
-def test_hybrid_merged_split_with_corruption_and_unaligned_tail():
-    # Per-group result splitting of a merged submission: corruption flags
-    # must land on the right blocks and parity must stay per-batch even
-    # when the final group is not k-aligned (18 = 4 full groups of 4 + 2).
-    p = _params(hybrid_group_blocks=4, batch_blocks=16)
-    dev = _RecordingDevice(p, delay=0.01)
-    hy = HybridCodec(p, device_codec=dev)
-    blocks, hashes = _mk_blocks(18, seed=12)
-    blocks[3] = b"\x00" * len(blocks[3])
-    blocks[17] = blocks[17][:-1] + b"\x7f"
-    ok, parity = hy.scrub_encode_batch(blocks, hashes)
-    expect_ok = CpuCodec(p).batch_verify(blocks, hashes)
-    assert np.array_equal(ok, expect_ok)
-    assert not ok[3] and not ok[17]
-    assert ok.sum() == 16
-    assert np.array_equal(parity, _cpu_reference_parity(blocks))
-
-
-def test_hybrid_merge_respects_scrub_many_batch_cuts():
-    # Merged device submissions must never let an RS codeword straddle a
-    # scrub_many batch edge: per-batch parity equals each batch's own
-    # CPU reference even with non-aligned batch lengths.
-    p = _params(hybrid_group_blocks=4, batch_blocks=64)
-    dev = _RecordingDevice(p, delay=0.01)
-    hy = HybridCodec(p, device_codec=dev)
-    b0, h0 = _mk_blocks(14, seed=13)   # non-aligned tail (14 % 4 != 0)
-    b1, h1 = _mk_blocks(22, seed=14)   # non-aligned tail
-    out = hy.scrub_many([(b0, h0), (b1, h1)], fetch_parity=True)
-    assert len(out) == 2
-    assert out[0][0].all() and out[1][0].all()
-    assert np.array_equal(out[0][1], _cpu_reference_parity(b0))
-    assert np.array_equal(out[1][1], _cpu_reference_parity(b1))
-
-
 def test_hybrid_link_gate_cedes_to_cpu_when_probe_below_threshold():
-    # With the threshold set impossibly high, the feeder must claim
-    # nothing (probe gate) and the pass still completes correctly on CPU.
-    hy = make_codec("hybrid", **vars(_params(hybrid_min_link_gibs=1e9)))
-    for _ in range(200):
-        if hy.tpu is not None:
-            break
-        time.sleep(0.05)
-    assert hy.tpu is not None
+    # With the threshold set impossibly high, the probe holds the gate:
+    # the device gets nothing and the call completes correctly on CPU.
+    hy = _attached(
+        make_codec("hybrid", **vars(_params(hybrid_min_link_gibs=_SHUT))))
     blocks, hashes = _mk_blocks(64, seed=21)
     ok, parity = hy.scrub_encode_batch(blocks, hashes)
+    assert hy.last_gate == "hold" and hy.ragged_side() == "cpu"
     assert ok.all()
     assert np.array_equal(parity, _cpu_reference_parity(blocks))
-    bytes_cpu, bytes_tpu = hy.pop_stats()
-    assert bytes_tpu == 0, "feeder claimed work through a gated link"
-    assert bytes_cpu == sum(len(b) for b in blocks)
+    assert hy.obs.bytes_total == {
+        "cpu": sum(len(b) for b in blocks), "tpu": 0}, \
+        "the device got work through a gated link"

@@ -203,47 +203,39 @@ def test_probe_event_carries_stages_and_stage_copy_bytes():
         tr.shutdown()
 
 
-def _wait_gate_event(hy, reason, timeout=15.0):
-    """The gate verdict lands on the feeder thread, which may outlive a
-    CPU-finished pass — poll the ring."""
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        evs = [e for e in hy.obs.events_list()
-               if e["kind"] == "gate" and e["reason"] == reason]
-        if evs:
-            return evs[-1]
-        time.sleep(0.01)
-    raise AssertionError(f"no gate event with reason={reason!r}")
+def _gate_event(hy, reason):
+    evs = [e for e in hy.obs.events_list()
+           if e["kind"] == "gate" and e["reason"] == reason]
+    assert evs, f"no gate event with reason={reason!r}"
+    return evs[-1]
 
 
 def test_gate_events_carry_stage_breakdown_open_and_hold():
     """Gate verdicts — open AND shut — carry the per-stage breakdown of
     the probe that decided them, so a held gate names WHERE the round
-    trip went without reopening."""
-    p_open = _params()
-    hy = HybridCodec(p_open, device_codec=SyntheticLinkCodec(
-        p_open, link_gibs=50.0, compute_real=True))
-    try:
-        blocks, hashes = _blocks(n=64)
-        ok, parity = hy.scrub_encode_batch(blocks, hashes)
-        assert ok.all()
-        ev = _wait_gate_event(hy, "open")
-        assert ev["stages"] and ev["dominant_stage"] in STAGES
-        assert hy.probe_stages() and hy.info()["link_stages"]
-    finally:
-        hy.close()
+    trip went without reopening.  The probe is the one the feeder asks
+    for before a background batch (refresh_gate)."""
+    from garage_tpu.ops.feeder import CodecFeeder
 
-    p_hold = _params(hybrid_min_link_gibs=1e9)
-    hy = HybridCodec(p_hold, device_codec=SyntheticLinkCodec(
-        p_hold, link_gibs=50.0, compute_real=True))
-    try:
-        blocks, hashes = _blocks(n=64, seed=7)
-        ok, parity = hy.scrub_encode_batch(blocks, hashes)
-        assert ok.all()
-        ev = _wait_gate_event(hy, "hold")
-        assert ev["stages"] and ev["dominant_stage"] in STAGES
-    finally:
-        hy.close()
+    for params, reason in ((_params(), "open"),
+                           (_params(hybrid_min_link_gibs=1e9), "hold")):
+        hy = HybridCodec(params, device_codec=SyntheticLinkCodec(
+            params, link_gibs=50.0, compute_real=True))
+        f = CodecFeeder(hy, slo_ms=1.0, max_batch_blocks=64)
+        try:
+            blocks, hashes = _blocks(n=64, seed=7)
+            ok, parity = f.submit_scrub(blocks, hashes).result(timeout=30)
+            assert ok.all()
+            for kind_ev in (_gate_event(hy, reason),
+                            [e for e in hy.obs.events_list()
+                             if e["kind"] == "probe"][-1]):
+                assert kind_ev["stages"]
+                assert kind_ev["dominant_stage"] in STAGES
+            assert hy.probe_stages() and hy.info()["link_stages"]
+            assert hy.last_gate == reason
+        finally:
+            f.shutdown()
+            hy.close()
 
 
 # --- controlled sweep harness -------------------------------------------

@@ -503,8 +503,7 @@ async def test_new_families_promlint(tmp_path):
 @pytest.mark.slow
 async def test_mini_scale_100k(tmp_path):
     """100k objects through the real table engine: batched Merkle drain,
-    sharded deep listing, counters exact — the tier-2 scale proof (the
-    bench's --metadata-phase drives 1M)."""
+    sharded deep listing, counters exact — the tier-2 scale proof."""
     from test_model import complete_version
 
     from garage_tpu.model.s3.object_table import Object
@@ -531,8 +530,7 @@ async def test_mini_scale_100k(tmp_path):
     await asyncio.to_thread(drain_batched, g.object_table, 512)
     assert g.object_table.data.merkle_todo_len() == 0
     # deep sharded listing over a 10k-key prefix agrees with the key set
-    # (listing ALL 100k via quorum XML pages is minutes of pure decode —
-    # the bench's --metadata-phase covers the full-bucket walks)
+    # (listing ALL 100k via quorum XML pages is minutes of pure decode)
     listed = await _list_all(client, "scalebkt", 8, garages,
                              prefix="obj01", max_keys="1000")
     assert len(listed["keys"]) == sum(

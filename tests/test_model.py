@@ -615,7 +615,7 @@ async def test_layout_change_migrates_data(tmp_path):
 async def make_ec_cluster(tmp_path, n, rs=(4, 2), fast_flush=True):
     """n-node erasure-coded cluster: meta "3", data "none", RS(k, m)
     write-time distributed parity.  Shared by the distributed-parity
-    tests (bench.py's _mk_cluster is the bench-side equivalent)."""
+    tests."""
     from garage_tpu.rpc.layout import ClusterLayout, NodeRole
 
     garages = []

@@ -16,9 +16,9 @@ import time
 import numpy as np
 import pytest
 
-import bench
 from garage_tpu.net import NetApp, gen_node_key
 from garage_tpu.testing.faults import FAST_CHAOS_RPC, FaultInjector, FaultyLink
+from garage_tpu.testing.local_cluster import S3, mk_cluster
 from garage_tpu.utils.error import RpcError
 
 pytestmark = pytest.mark.asyncio
@@ -160,7 +160,7 @@ CHAOS_RPC = FAST_CHAOS_RPC
 
 
 async def _mk_chaos_cluster(tmp_path, rpc_cfg=None):
-    garages, server, port, kid, secret = await bench._mk_cluster(
+    garages, server, port, kid, secret = await mk_cluster(
         tmp_path, n=3, repl="3", db="memory",
         codec_cfg={"rs_data": 0, "rs_parity": 0, "backend": "cpu"},
         rpc_cfg=rpc_cfg or CHAOS_RPC)
@@ -188,7 +188,7 @@ async def test_chaos_degraded_phases(tmp_path):
     nprng = np.random.default_rng(13)
     try:
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/chaos")
             assert st == 200, st
 
@@ -409,7 +409,7 @@ async def test_chaos_net_soak(tmp_path):
 
     try:
         async with aiohttp.ClientSession() as session:
-            s3 = bench._S3(session, port, kid, secret)
+            s3 = S3(session, port, kid, secret)
             st, _b, _h = await s3.req("PUT", "/nsoak")
             assert st == 200
             await asyncio.gather(traffic(s3), chaos())

@@ -1,8 +1,7 @@
 """Pallas BLAKE2s kernel: bit-identity vs hashlib and the XLA scan.
 
 Runs the kernel in Pallas interpret mode on the CPU platform — no TPU
-needed for correctness (the on-device rate evidence lives in
-DEVICE_CAPTURE.json, round 5).
+needed for correctness (chip_smoke.py runs the kernel on the chip).
 """
 
 import functools

@@ -1,9 +1,8 @@
 """Bit-identity of the Pallas GF(2^8) kernel against the numpy oracle.
 
 Runs the kernel through the Pallas INTERPRETER (no TPU needed), so what
-is verified is the kernel's math, not Mosaic codegen; the device-rate
-comparison against the XLA formulation happens in bench.py on real
-hardware (pallas_gf_gibs)."""
+is verified is the kernel's math, not Mosaic codegen (chip_smoke.py
+runs the kernel on the chip)."""
 
 import numpy as np
 import pytest

@@ -4,8 +4,8 @@ The generator is a pure function of its config, so every property is
 testable without a cluster: determinism (same seed ⇒ bit-identical
 trace), the Zipf hot-set shape, the size-mixture bands, the diurnal
 arrival envelope, and the op-mix fractions.  These are the acceptance
-teeth behind "a chaos run is exactly reproducible": bench
---replay-phase quotes the same trace_signature this suite pins down.
+teeth behind "a chaos run is exactly reproducible": the
+trace_signature this suite pins down.
 """
 
 import math

@@ -313,7 +313,7 @@ def test_feeder_routes_inline_when_transport_closed():
 def test_scrub_and_foreground_share_one_feeder_queue():
     """Background scrub batches and foreground verifies enter the device
     through the SAME feeder → transport queue: the device codec's
-    bytes-level scrub_submit (the old behind-the-feeder's-back path) is
+    bytes-level calls (the old behind-the-feeder's-back path) are
     never called, and both classes appear in the transport's meter."""
     p = _params()
     dev = SyntheticLinkCodec(p, link_gibs=100.0, compute_real=True)
@@ -471,8 +471,8 @@ def test_pending_scrub_does_not_stall_foreground_peers_window():
 def test_background_batch_refreshes_closed_gate():
     """With the gate unprobed (cold daemon), a BACKGROUND scrub batch
     pays the TTL-cached probe and re-opens the device route for itself
-    — the feeder-era replacement for the stealing feeder's per-pass
-    probe.  Foreground-only traffic never probes cold."""
+    (nobody else probes on its behalf).  Foreground-only traffic never
+    probes cold."""
     p = _params()
     dev = SyntheticLinkCodec(p, link_gibs=100.0, compute_real=True)
     hy = HybridCodec(p, device_codec=dev)
@@ -489,6 +489,120 @@ def test_background_batch_refreshes_closed_gate():
     assert dev.array_submissions >= 1, "scrub did not reach the device"
     f.shutdown()
     hy.close()
+
+
+# --- one contract for every road from a batch of blocks to its answer ---
+#
+# The work-stealing engine that used to sit beside these roads is gone;
+# what its tests held of the SYSTEM is held here, on the roads that run:
+# the ScrubWorker's (feeder → transport), the same with the gate shut
+# (the feeder's inline CPU dispatch), and the bytes-level call a caller
+# without a feeder makes (block/repair.py, FeederClosed in
+# ops/feeder.py).  A submission wider than the staging bound being cut
+# and reassembled is test_staging_bound_clamps_and_reassembles_bit_
+# identically above.
+
+_ROUTES = ("transport", "gate_shut", "no_feeder")
+_CODECS = {"rs84": (8, 4), "rs42": (4, 2), "rep": (0, 0)}
+
+
+def _scrub_by(route, hy, blocks, hashes):
+    if route == "no_feeder":
+        return hy.scrub_encode_batch(blocks, hashes, True)
+    f = CodecFeeder(hy, slo_ms=1.0, max_batch_blocks=256)
+    try:
+        return f.submit_scrub(blocks, hashes, True).result(timeout=30)
+    finally:
+        f.shutdown()
+
+
+@pytest.mark.parametrize("codec", sorted(_CODECS))
+@pytest.mark.parametrize("route", _ROUTES)
+def test_every_road_answers_as_the_cpu_codec(route, codec):
+    """`ok` and parity equal CpuCodec's for a batch with corrupt lanes
+    and an unaligned ragged tail, and the batch ran where the route
+    says: whole, on one side."""
+    k, m = _CODECS[codec]
+    p = _params(rs_data=k, rs_parity=m)
+    dev = SyntheticLinkCodec(
+        p, link_gibs=0.001 if route == "gate_shut" else 100.0,
+        compute_real=True)
+    hy = HybridCodec(p, device_codec=dev)
+    blocks, hashes = _blocks(n=2 * max(k, 4) + 3, seed=11)
+    blocks[1] = b"\xff" + blocks[1][1:]
+    blocks[-1] = blocks[-1][:-1] + b"\x00"
+    nbytes = sum(map(len, blocks))
+    rok, rpar = CpuCodec(p).scrub_encode_batch(blocks, hashes, True)
+    assert not rok[1] and not rok[-1] and rok.sum() == len(blocks) - 2
+    try:
+        # no_feeder: the bytes-level call takes the gate's probe itself
+        ok, par = _scrub_by(route, hy, blocks, hashes)
+    finally:
+        hy.close()
+    assert ok.tolist() == rok.tolist()
+    if k == 0:
+        assert par is None and rpar is None
+    else:
+        assert par.shape == rpar.shape and (par == rpar).all()
+    side = "cpu" if route == "gate_shut" else "tpu"
+    assert hy.last_gate == ("hold" if route == "gate_shut" else "open")
+    assert hy.obs.bytes_total[side] == nbytes, hy.obs.bytes_total
+    assert hy.obs.bytes_total["cpu" if side == "tpu" else "tpu"] == 0
+    assert (dev.array_submissions, dev.submissions) == {
+        "transport": (1, 0), "gate_shut": (0, 0), "no_feeder": (0, 1),
+    }[route]
+
+
+def test_coalesced_submissions_get_parity_of_their_own_blocks():
+    """Two submissions whose sizes are not multiples of k, coalesced
+    into ONE transport batch: each gets (ok, parity) of its own blocks
+    only — no RS codeword straddles two submissions."""
+    tr, dev, cpu = _transport()
+    subs = [_blocks(n=11, seed=21), _blocks(n=13, seed=22)]
+    subs[1][0][4] = b"\x00" * len(subs[1][0][4])
+    items = [TransportItem("scrub", (b, h), len(b), sum(map(len, b)))
+             for b, h in subs]
+    tr.submit_items("scrub", items)
+    for it, (b, h) in zip(items, subs):
+        ok, par = it.future.result(timeout=30)
+        rok, rpar = cpu.scrub_encode_batch(b, h, True)
+        assert ok.tolist() == rok.tolist()
+        assert par.shape == rpar.shape and (par == rpar).all()
+    assert dev.array_submissions == 1, "the two were not coalesced"
+    tr.shutdown()
+
+
+@pytest.mark.parametrize("route", ("transport", "inline", "no_feeder"))
+def test_a_device_that_raises_costs_no_error_on_any_road(route):
+    """The device dies under the batch: the caller gets the CPU floor's
+    answer and the ring names the failure.  `inline` is the feeder's
+    dispatch through the bytes-level calls (no transport armed)."""
+    p = _params(transport=route != "inline")
+
+    class _Raises(SyntheticLinkCodec):
+        def scrub_encode_batch(self, *a, **kw):
+            raise RuntimeError("device gone")
+
+        scrub_encode_submit = scrub_encode_batch
+        scrub_encode_submit_resident = scrub_encode_batch
+
+    dev = _Raises(p, link_gibs=100.0, compute_real=True)
+    hy = HybridCodec(p, device_codec=dev)
+    assert (hy.transport is None) == (route == "inline")
+    hy.refresh_gate()
+    assert hy.ragged_side() == "tpu"
+    blocks, hashes = _blocks(n=K * 2 + 1, seed=31)
+    blocks[2] = blocks[2][:-1] + b"\x00"
+    rok, rpar = CpuCodec(p).scrub_encode_batch(blocks, hashes, True)
+    try:
+        ok, par = _scrub_by(route, hy, blocks, hashes)
+    finally:
+        hy.close()
+    assert ok.tolist() == rok.tolist() and (par == rpar).all()
+    kinds = {e["kind"] for e in hy.obs.events_list()}
+    assert ("transport_error" if route == "transport"
+            else "sync_failure") in kinds, kinds
+    assert hy.obs.bytes_total == {"cpu": sum(map(len, blocks)), "tpu": 0}
 
 
 # --- CPU encode-schedule cache (satellite) ------------------------------
