@@ -27,7 +27,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import msgpack
 import numpy as np
@@ -109,7 +109,8 @@ class ParityStore:
         GF-linear, so the parity is identical to a k-member codeword
         whose tail members are zero, and reconstruction counts the zero
         shards as always-available pieces.  Called by the scrub worker
-        (full rows whose members all verified) and the write-path
+        (full rows whose members all verified and whose sidecar
+        `rows_lacking_sidecar` did not find) and the write-path
         accumulator (possibly partial).  → whether a sidecar was
         written (False: one with this content was there and got a fresh
         mtime)."""
@@ -120,13 +121,54 @@ class ParityStore:
             return self._put_codeword(hashes, lengths, parity)
 
     def _put_codeword(self, hashes, lengths, parity) -> bool:
+        return self._file(hashes, lengths, int(parity.shape[0]),
+                          lambda: parity)
+
+    def rows_lacking_sidecar(self, hashes: Sequence[Hash]) -> List[int]:
+        """Which of a scrub batch's codewords have no sidecar: `hashes`
+        are the batch's members in order (carry first), row r the k of
+        them from r·k; a trailing partial row is no codeword yet and is
+        never named.  The rows returned are those whose parity has to
+        leave the device; every other row's sidecar is on disk, with the
+        content this (k, m) would give it again (`_gid`), in one of the
+        data dirs.  No I/O but os.path.exists; call it off the loop."""
+        k, m = self.codec.params.rs_data, self.codec.params.rs_parity
+        return [
+            r for r in range(len(hashes) // k)
+            if self._find_group_path(
+                bytes(self._gid(k, m, hashes[r * k:(r + 1) * k]))) is None]
+
+    def refresh_codewords(self, rows: Sequence[tuple]) -> Tuple[int, int]:
+        """File, in one call, a batch's verified codewords whose sidecar
+        was on disk when `rows_lacking_sidecar` was asked: `rows` holds
+        (member hashes, member blocks) a codeword.  Each is touched,
+        counted and indexed as `put_codeword` does a codeword it finds —
+        without its parity, which stayed on the device.  A file that is
+        gone by now (a purge, an operator) is encoded here and written,
+        so that its codeword does not wait a pass for its sidecar.
+        → (touched, written)."""
+        m = self.codec.params.rs_parity
+        written = 0
+        with self.codec.obs.timeline.span("refresh codewords", "scrub-io",
+                                          cat="scrub", record=False):
+            for hashes, blocks in rows:
+                written += self._file(
+                    hashes, [len(b) for b in blocks], m,
+                    lambda: self.codec.rs_encode_blocks(blocks)[0])
+        return len(rows) - written, written
+
+    def _file(self, hashes, lengths, m: int, parity_of) -> bool:
+        """One codeword filed: counted, its sidecar touched — or, where
+        it has none, written from `parity_of()`, (m, maxlen) — and its
+        members indexed.  → whether it was written."""
         k = self.codec.params.rs_data
         assert 0 < len(hashes) <= k, (len(hashes), k)
         if self.m_bytes is not None:
-            self.m_bytes.inc(int(parity.nbytes), part="parity")
+            # a sidecar holds m rows as long as its longest member
+            self.m_bytes.inc(m * int(max(lengths)), part="parity")
             self.m_bytes.inc(int(sum(lengths)), part="covered")
-        gid = self._gid(k, int(parity.shape[0]), hashes)
-        existing = self._find_group_path(bytes(gid))
+        gid = bytes(self._gid(k, m, hashes))
+        existing = self._find_group_path(gid)
         if existing is not None:
             # gid hashes the member set AND the (version, k, m) geometry,
             # so an existing file has identical content: a fresh mtime
@@ -140,6 +182,8 @@ class ParityStore:
             # manifest built only on the miss path: in steady state most
             # codewords take the touch shortcut, and serializing + hashing
             # ~m rows of parity per codeword per pass would dominate it
+            parity = parity_of()
+            rows = [parity[i].tobytes() for i in range(parity.shape[0])]
             manifest = {
                 "v": MANIFEST_VERSION,
                 "k": k,
@@ -147,20 +191,17 @@ class ParityStore:
                 "maxlen": int(parity.shape[1]),
                 "hashes": [bytes(h) for h in hashes],
                 "lengths": [int(n) for n in lengths],
-                "parity": [parity[i].tobytes() for i in range(parity.shape[0])],
-                "parity_sums": [
-                    bytes(blake2s_sum(parity[i].tobytes()))
-                    for i in range(parity.shape[0])
-                ],
+                "parity": rows,
+                "parity_sums": [bytes(blake2s_sum(row)) for row in rows],
             }
-            path = self._group_path(bytes(gid))
+            path = self._group_path(gid)
             os.makedirs(os.path.dirname(path), exist_ok=True)
             tmp = path + ".tmp"
             with open(tmp, "wb") as f:
                 f.write(msgpack.packb(manifest, use_bin_type=True))
             os.replace(tmp, path)
         for h in hashes:
-            self.index.insert(bytes(h), bytes(gid))
+            self.index.insert(bytes(h), gid)
         return existing is None
 
     # --- repair path -------------------------------------------------------

@@ -554,9 +554,8 @@ class ScrubWorker(Worker):
         await self._heal(lost)
         if plain_blocks:
             store = mgr.parity_store
-            want_parity = (
-                store is not None and mgr.codec.params.rs_data > 0
-            )
+            k = mgr.codec.params.rs_data
+            files_parity = store is not None and k > 0
             # prepend the carry (already-verified blocks from previous
             # batches) so RS codewords align to k across batch boundaries
             # — a per-prefix batch rarely holds k blocks by itself.  The
@@ -564,10 +563,19 @@ class ScrubWorker(Worker):
             # the trailing partial row's parity is recomputed next batch:
             # bounded waste (< k blocks per batch) accepted to keep the
             # verify+encode a single codec call
-            carry_b, carry_h = self._parity_carry if want_parity else ([], [])
+            carry_b, carry_h = self._parity_carry if files_parity else ([], [])
             nc = len(carry_b)
             all_b = carry_b + plain_blocks
             all_h = carry_h + plain_hashes
+            # the rows whose parity has to come back: the codewords
+            # that have no sidecar yet.  A store in steady state names
+            # none, and nothing but the verdicts leaves the device
+            want_parity = False
+            if files_parity:
+                want_parity = await asyncio.to_thread(
+                    store.rows_lacking_sidecar, all_h)
+                self._segment("parity_write", "parity ask",
+                              rows=len(all_h) // k, lacking=len(want_parity))
             nbytes = sum(len(b) for b in plain_blocks)
             if self._acct is not None:
                 self._acct.batches += 1
@@ -648,18 +656,23 @@ class ScrubWorker(Worker):
                     acc.add(h, DataBlock.plain(b))
                 self._segment("coverage_refresh", "coverage refresh",
                               candidates=len(cand), refreshed=refreshed)
-            if want_parity and parity is not None:
+            if files_parity:
                 # persist RS sidecars for every COMPLETE codeword whose
                 # members all verified — this is what makes a later
                 # corruption locally repairable with zero network
-                # (the BlockCodec north star's decode-repair half)
-                k = mgr.codec.params.rs_data
+                # (the BlockCodec north star's decode-repair half).
+                # The rows that lacked one are written from the parity
+                # that came back, a thread hop a row; all the others
+                # are refreshed in one hop, without their parity
                 nrows = len(all_b) // k
+                sound = [row for row in range(nrows)
+                         if all(ok[row * k:(row + 1) * k])]
+                fresh = set(want_parity) if parity is not None else ()
                 written = touched = par_bytes = 0
-                for row in range(nrows):
-                    lo = row * k
-                    if not all(ok[lo:lo + k]):
+                for row in sound:
+                    if row not in fresh:
                         continue
+                    lo = row * k
                     # trim to the row's own width: pad columns beyond the
                     # longest member are zero parity (GF-linear) and would
                     # bloat the sidecar to the batch-global maxlen
@@ -675,6 +688,16 @@ class ScrubWorker(Worker):
                         par_bytes += row_parity.nbytes
                     else:
                         touched += 1
+                kept = [(all_h[row * k:(row + 1) * k],
+                         all_b[row * k:(row + 1) * k])
+                        for row in sound if row not in fresh]
+                if kept:
+                    # a file gone since it was asked for is encoded and
+                    # written there: `regained`, no parity of the batch's
+                    n, regained = await asyncio.to_thread(
+                        store.refresh_codewords, kept)
+                    touched += n
+                    written += regained
                 self._segment("parity_write", "parity write", rows=nrows,
                               written=written, touched=touched,
                               bytes=par_bytes)

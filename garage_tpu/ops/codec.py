@@ -149,6 +149,17 @@ def mhash_stream() -> IncrementalHash:
     return IncrementalHash(hashlib.blake2b(digest_size=32))
 
 
+def parity_by_row(wanted: int, rows: int) -> bool:
+    """Whether `wanted` of a scrub batch's `rows` parity rows cross the
+    link a row at a time (one small program and one fetch each) rather
+    than as the whole array.  Read off the batch, from one measurement
+    on a v5e (PERF.md §6, PR 33): a (4, 1 MiB) row costs 1.5 ms to
+    slice out and fetch and ~1 ms once a batch, the whole (32, 4,
+    1 MiB) array 38 ms, 1.2 ms a row — so by the row up to three rows
+    in four."""
+    return 4 * wanted < 3 * rows
+
+
 class BlockCodec:
     """Batch codec interface; see module docstring for the contract.
 
@@ -382,17 +393,27 @@ class BlockCodec:
 
     def scrub_encode_batch(self, blocks: Sequence[bytes],
                            hashes: Sequence[Hash],
-                           fetch_parity: bool = True):
+                           fetch_parity=True):
         """Fused scrub step: verify + RS(k, m) parity per codeword of k
-        consecutive blocks.  Returns (ok (B,), parity
-        (ceil(B/k), m, maxlen) | None); short blocks zero-pad to maxlen
-        (zero data → zero parity, GF-linear).  Device backends override
-        with a single fused dispatch; this default serves the CPU path."""
+        consecutive blocks.  Returns (ok (B,), parity): with
+        `fetch_parity` True every row, (ceil(B/k), m, maxlen), short
+        blocks zero-padded to maxlen (zero data → zero parity,
+        GF-linear); False or empty, None; a sequence of row indexes,
+        those rows alone as {row: (m, width ≥ the row's longest
+        member)} — `parity[row]` reads either.  Device backends
+        override with a single fused dispatch; this default serves the
+        CPU path, which encodes the rows asked for and no others."""
         ok = self.batch_verify(blocks, hashes)
-        parity = None
-        if fetch_parity and self.params.rs_data > 0 and blocks:
-            parity = self.rs_encode_blocks(blocks)
-        return ok, parity
+        k = self.params.rs_data
+        if not fetch_parity or k <= 0 or not blocks:
+            return ok, None
+        if fetch_parity is True:
+            return ok, self.rs_encode_blocks(blocks)
+        rows = sorted(fetch_parity)     # a ragged last row comes last
+        if not 0 <= rows[0] <= rows[-1] < -(-len(blocks) // k):
+            raise ValueError(f"parity rows {rows} of {len(blocks)} blocks")
+        members = [b for r in rows for b in blocks[r * k:(r + 1) * k]]
+        return ok, dict(zip(rows, self.rs_encode_blocks(members)))
 
     # --- Reed-Solomon ---
     def rs_encode(self, data: np.ndarray) -> np.ndarray:

@@ -307,11 +307,14 @@ class CodecFeeder:
             peers=peers, cls=cls))
 
     def submit_scrub(self, blocks: Sequence[bytes], hashes: Sequence,
-                     want_parity: bool = True, cls: str = "bg"):
+                     want_parity=True, cls: str = "bg"):
         """One scrub/resync batch (scrub_encode_batch semantics: fused
-        verify + per-codeword RS parity).  Future resolves to
-        (ok (B,), parity | None).  This is how the background producers
-        ride the SAME feeder queue as foreground verifies — the scrub
+        verify + per-codeword RS parity; `want_parity` True for every
+        row's parity, False for none, or the indexes of the rows — the
+        item's own k-groups — that are wanted, which the transport
+        fetches and no others).  Future resolves to (ok (B,), parity |
+        None), `parity[row]` the parity of a wanted row.  This is how
+        the background producers ride the SAME feeder queue as foreground verifies — the scrub
         worker no longer talks to the device behind the feeder's back —
         entering the device transport as class "bg" (demoted behind
         foreground under governor pressure)."""
@@ -363,7 +366,7 @@ class CodecFeeder:
             return self.codec.rs_reconstruct(shards, present, rows)
 
     async def scrub_async(self, blocks: Sequence[bytes], hashes: Sequence,
-                          want_parity: bool = True):
+                          want_parity=True):
         import asyncio
 
         try:

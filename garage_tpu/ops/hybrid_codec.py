@@ -515,14 +515,14 @@ class HybridCodec(BlockCodec):
                             nbytes=sum(len(b) for b in blocks), probe=True)
 
     def scrub_encode_batch(self, blocks: Sequence[bytes], hashes: Sequence[Hash],
-                           fetch_parity: bool = True):
+                           fetch_parity=True):
         """Fused verify + RS(k,m) parity on the side the gate names.
 
-        Same contract as TpuCodec.scrub_encode_batch: (ok (B,), parity
-        (ceil(B/k), m, maxlen) | None).  With fetch_parity=False (or
-        rs_data=0, the replication-only config: verify-only), parity is
-        None — device-side parity stays on the device (callers that
-        discard parity avoid paying device→host bandwidth).
+        BlockCodec.scrub_encode_batch's contract: (ok (B,), parity) —
+        every row, the rows `fetch_parity` names, or None.  With
+        fetch_parity False or empty (or rs_data=0, the replication-only
+        config: verify-only) device-side parity stays on the device:
+        callers pay device→host bandwidth for the rows they file.
         """
         if not blocks:
             return np.zeros((0,), dtype=bool), None

@@ -32,7 +32,7 @@ import numpy as np
 
 from ..utils.data import Hash
 from . import compile_listener, gf256
-from .codec import BlockCodec, CodecParams
+from .codec import BlockCodec, CodecParams, parity_by_row
 from .compile_cache import ensure_compile_cache
 from .device_pool import miss_buckets
 from .tpu_blake2s import blake2s_batch, digests_to_bytes
@@ -121,40 +121,28 @@ def gf_apply(shards_u32: jax.Array, K: jax.Array) -> jax.Array:
 
 def bytes_view_u32(x_u8: jax.Array) -> jax.Array:
     """uint8 (..., 4n) → uint32 (..., n) little-endian (byte j of each lane
-    = input byte 4i+j, matching pack order in u32_view_bytes).  Bitcast:
+    = input byte 4i+j; host_bytes is the way back, on the host).  Bitcast:
     a relayout, not arithmetic — see tpu_blake2s.bytes_to_words."""
     from .tpu_blake2s import bytes_to_words
 
     return bytes_to_words(x_u8)
 
 
-def u32_view_bytes(x_u32: jax.Array) -> jax.Array:
-    """Inverse of bytes_view_u32."""
-    from .tpu_blake2s import _BITCAST_PACK
-
-    if _BITCAST_PACK:
-        out = jax.lax.bitcast_convert_type(x_u32, jnp.uint8)
-        return out.reshape(x_u32.shape[:-1] + (-1,))
-    parts = jnp.stack(
-        [(x_u32 >> jnp.uint32(8 * j)).astype(jnp.uint8) for j in range(4)],
-        axis=-1,
-    )
-    return parts.reshape(x_u32.shape[:-1] + (-1,))
-
-
 def host_words(x_u8: np.ndarray) -> np.ndarray:
     """Host uint8 (..., 4n) as uint32 (..., n) in bytes_view_u32's byte
     order, before it goes to the device: a numpy view.  Outside a jit
     the device-side views are two eager programs a width each, and the
-    chip's compiler takes minutes over u32_view_bytes's reshape (163 s
+    chip's compiler takes minutes over a view of words as bytes (163 s
     for one heal of a short block inside a scrub pass: PERF.md, PR 29);
     every width a ragged store brings would pay that again."""
     return np.ascontiguousarray(x_u8).view("<u4")
 
 
 def host_bytes(x_u32) -> np.ndarray:
-    """A device uint32 (..., n) array on the host as uint8 (..., 4n), in
-    u32_view_bytes's byte order: the D2H copy and a numpy view."""
+    """A device uint32 (..., n) array on the host as uint8 (..., 4n),
+    little-endian (bytes_view_u32's inverse): the D2H copy and a numpy
+    view.  No program views words as bytes on the device any more: the
+    fused scrub's parity comes back through here too."""
     return np.asarray(x_u32).astype("<u4", copy=False).view(np.uint8)
 
 
@@ -170,12 +158,13 @@ def scrub_step_kernel(data_u8, lengths, expected, K_enc, k: int):
     """The fused scrub hot op — ONE device dispatch per batch: verify all
     B blocks AND produce RS parity for every group of k blocks (north-star
     batch producer, SURVEY.md §3.4).  data_u8 (B, S) with B % k == 0;
-    returns (digests, ok, corrupt_count, parity (B//k, r, S))."""
+    returns (digests, ok, corrupt_count, parity (B//k, r, S ÷ 4) as
+    uint32 words: the host views what it fetches as bytes, host_bytes,
+    and a row can be sliced out on the device, parity_row)."""
     h, ok, bad = verify_kernel(data_u8, lengths, expected)
     u32 = bytes_view_u32(data_u8)
     groups = u32.reshape(u32.shape[0] // k, k, u32.shape[-1])
-    parity = u32_view_bytes(gf_apply(groups, K_enc))
-    return h, ok, bad, parity
+    return h, ok, bad, gf_apply(groups, K_enc)
 
 
 # The jitted entry points, under the names their programs carry in a
@@ -251,6 +240,16 @@ def pool_adopt(pool, batch, dst):
 
 def pool_gather(pool, slots):
     return jnp.take(pool, slots, axis=0, mode="fill", fill_value=0)
+
+
+def parity_row(parity, row):
+    """One codeword's parity out of a scrub batch's, on the device:
+    (rows, m, words) and a row index → (m, words).  The index is an
+    argument, so a geometry has one such program whatever rows a pass
+    wants; in words, like everything the pool's programs touch."""
+    with jax.named_scope("parity_row"):
+        return jax.lax.dynamic_index_in_dim(parity, row, axis=0,
+                                            keepdims=False)
 
 
 # --- codec ------------------------------------------------------------------
@@ -528,15 +527,41 @@ class TpuCodec(BlockCodec):
         h = np.asarray(handle)[:n]
         return [Hash(d) for d in digests_to_bytes(h)]
 
-    def scrub_collect(self, out, fetch_parity: bool):
+    def scrub_collect(self, out, parity_rows):
         """Materialize one scrub_encode_submit result: (ok full-lane
-        bool array, parity full array | None) — per-entry trimming is
-        the transport's job (it knows the lane spans)."""
+        bool array, parity | None).  `parity_rows` says which rows of
+        the batch's parity leave the device: True every row, False or
+        empty none, else the rows' indexes.  `parity[r]` is row r's
+        (m, cols) bytes for every row asked for: the whole array, or a
+        dict of the rows fetched one by one (`_parity_rows`).  Trimming
+        to an entry's width is the transport's job (it knows the lane
+        spans)."""
         _h, ok, _bad, parity = out
-        self._mark_ready((ok, parity) if fetch_parity else ok)
-        ok = np.asarray(ok)
-        parity_np = np.asarray(parity) if fetch_parity else None
-        return ok, parity_np
+        if parity_rows is True:
+            parity_rows = range(parity.shape[0])
+        if not parity_rows:
+            self._mark_ready(ok)
+            return np.asarray(ok), None
+        if self.mesh is not None or not parity_by_row(
+                len(parity_rows), parity.shape[0]):
+            # (a sharded array's rows lie on other chips: the mesh
+            # brings the array)
+            self._mark_ready((ok, parity))
+            return np.asarray(ok), host_bytes(parity)
+        picked = self._parity_rows(parity, parity_rows)
+        self._mark_ready((ok, *picked))
+        return np.asarray(ok), {int(r): host_bytes(p)
+                                for r, p in zip(parity_rows, picked)}
+
+    def _parity_rows(self, parity, rows) -> list:
+        """The rows, each sliced out on the device by the geometry's
+        one parity_row program: all dispatched before any is fetched."""
+        rows_n, m, words = (int(d) for d in parity.shape)
+        if not all(0 <= r < rows_n for r in rows):
+            # on the device an index out of range is clamped, not refused
+            raise ValueError(f"parity rows {list(rows)} of {rows_n}")
+        key = ("parity_row", rows_n, m, 4 * words)
+        return [self._pool_dispatch(key, parity, np.int32(r)) for r in rows]
 
     def _gf_submit(self, u32, K, mat: np.ndarray):
         """Dispatch one GF apply WITHOUT synchronizing, preferring the
@@ -854,10 +879,8 @@ class TpuCodec(BlockCodec):
                 bad = jnp.sum(~ok, dtype=jnp.int32)
                 u32 = bytes_view_u32(data_u8)
                 groups = u32.reshape(u32.shape[0] // k, k, u32.shape[-1])
-                if pg is not None:
-                    parity = u32_view_bytes(pg(groups))
-                else:
-                    parity = u32_view_bytes(gf_apply(groups, K_enc))
+                parity = (pg(groups) if pg is not None
+                          else gf_apply(groups, K_enc))
                 return h, ok, bad, parity
 
             self._scrub_pallas_jit = jax.jit(scrub_fused_pallas,
@@ -924,18 +947,6 @@ class TpuCodec(BlockCodec):
         defeated the latch)."""
         if (variant or self.last_submit_variant) == "pallas":
             self._pallas_fused_fails = 0
-
-    def scrub_submit(self, blocks: Sequence[bytes], hashes: Sequence[Hash]):
-        """Enqueue one group's fused verify+encode WITHOUT synchronizing.
-
-        Returns (ok_dev, parity_dev, n): device arrays plus the true block
-        count.  Callers keep several groups in flight to hide the
-        host→device link latency, then sync each with `np.asarray(ok_dev)[:n]`.
-        """
-        with self.obs.stage("host_staging", "tpu"):
-            arr, lengths, expected = self._pad_group(blocks, hashes)
-        _h, ok, _bad, parity = self.scrub_encode_submit(arr, lengths, expected)
-        return ok, parity, len(blocks)
 
     def scrub_encode_submit(self, arr: np.ndarray, lengths: np.ndarray,
                             expected: np.ndarray):
@@ -1006,10 +1017,13 @@ class TpuCodec(BlockCodec):
 
     def pool_program_keys(self, lanes: int, cols: int) -> List[tuple]:
         """The pool programs a (lanes, cols) batch can dispatch: one
-        adopt, and one compose for every miss bucket."""
+        adopt, one compose for every miss bucket, and where the batch
+        is encoded the one that slices a row out of its parity."""
         geom = self._pool_geom + (int(lanes), int(cols))
+        k, m = self.params.rs_data, self.params.rs_parity
         return [("adopt",) + geom] + [
-            ("compose",) + geom + (mb,) for mb in miss_buckets(lanes)]
+            ("compose",) + geom + (mb,) for mb in miss_buckets(lanes)
+        ] + ([("parity_row", int(lanes) // k, m, int(cols))] if k else [])
 
     def pool_warm(self, lanes: int, cols: int) -> None:
         """Compile every pool program of a (lanes, cols) batch."""
@@ -1032,6 +1046,10 @@ class TpuCodec(BlockCodec):
         def spec(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+        if key[0] == "parity_row":
+            rows, m, cols = key[1:]
+            return jax.jit(parity_row).lower(
+                spec((rows, m, cols // 4), jnp.uint32), spec((), jnp.int32))
         op, npages, page = key[:3]
         pool = spec((npages, page // 4 // POOL_TILE, POOL_TILE), jnp.uint32)
         if op == "alloc":
@@ -1110,28 +1128,33 @@ class TpuCodec(BlockCodec):
         return host_bytes(pages).reshape(-1)[:int(length)].tobytes()
 
     def scrub_encode_batch(self, blocks: Sequence[bytes], hashes: Sequence[Hash],
-                           fetch_parity: bool = True):
+                           fetch_parity=True):
         """Synchronous fused verify+encode.  Contract shared with
-        HybridCodec.scrub_encode_batch: returns (ok (B,), parity
-        (ceil(B/k), m, maxlen) | None) — parity trimmed of lane/column
-        padding (pad rows/columns are zero blocks → zero parity); with
-        fetch_parity=False it stays on the device and None is returned."""
-        ok, parity, n = self.scrub_submit(blocks, hashes)
+        BlockCodec.scrub_encode_batch: returns (ok (B,), parity) — every
+        row's parity as (ceil(B/k), m, maxlen), trimmed of lane/column
+        padding (pad rows/columns are zero blocks → zero parity); the
+        rows `fetch_parity` names as a dict of (m, maxlen); with none
+        asked for it stays on the device and None is returned."""
+        with self.obs.stage("host_staging", "tpu"):
+            arr, lengths, expected = self._pad_group(blocks, hashes)
+        out = self.scrub_encode_submit(arr, lengths, expected)
+        n = len(blocks)
         variant = self.last_submit_variant
         try:
             with self.obs.stage("sync_collect", "tpu"):
-                ok = np.asarray(ok)[:n]
-                parity_np = (np.asarray(parity) if fetch_parity else None)
+                ok, parity = self.scrub_collect(out, fetch_parity)
         except Exception as e:
             self.note_sync_failure(e, variant)
             raise
         self.note_sync_success(variant)
-        if not fetch_parity:
+        ok = ok[:n]
+        if parity is None:
             return ok, None
-        k = self.params.rs_data
-        nrows = (n + k - 1) // k
         maxlen = max(len(b) for b in blocks)
-        return ok, parity_np[:nrows, :, :maxlen]
+        if isinstance(parity, dict):
+            return ok, {r: p[:, :maxlen] for r, p in parity.items()}
+        k = self.params.rs_data
+        return ok, parity[:(n + k - 1) // k, :, :maxlen]
 
 
 # --- multi-chip sharded variants (pod-scale batches) ------------------------

@@ -130,13 +130,16 @@ class TransportItem:
 
     def __init__(self, kind: str, payload, blocks: int, nbytes: int,
                  cls: str = "fg", deadline: Optional[float] = None,
-                 want_parity: bool = True, prefetch: bool = False):
+                 want_parity=True, prefetch: bool = False):
         self.kind = kind
         self.payload = payload
         self.blocks = blocks
         self.nbytes = nbytes
         self.cls = cls
         self.deadline = deadline
+        # scrub: True for every row's parity, False for none, or the
+        # rows wanted — indexes into the item's own k-groups — which
+        # are all that `_collect` brings back (CodecFeeder.submit_scrub)
         self.want_parity = want_parity
         # pool warm-up submission (DevicePool prefetch): results are
         # discarded, staging is attributed to pool_prefetch_bytes_total
@@ -199,8 +202,13 @@ class _Assembler:
             return np.concatenate(parts, axis=0)
         if kind == "encode":
             return _cat_parity(parts, self.item)
-        # scrub: (ok, parity|None) per part
+        # scrub: (ok, parity|None) per part; named rows come keyed by
+        # the item's own row numbers (`_part_parity`) and merge as they are
         ok = np.concatenate([p[0] for p in parts])
+        if not isinstance(self.item.want_parity, bool):
+            named = [p[1] for p in parts if p[1] is not None]
+            return ok, ({r: a for d in named for r, a in d.items()}
+                        if named else None)
         if any(p[1] is None for p in parts):
             return ok, None
         return ok, _cat_parity([p[1] for p in parts], self.item)
@@ -221,16 +229,48 @@ def _cat_parity(rows: Sequence[np.ndarray], item) -> np.ndarray:
     return np.concatenate(out, axis=0)
 
 
+def _wanted_rows(part: _Part, k: int):
+    """The rows of a scrub part, counted from the part's first, whose
+    parity its item wants: every row where it wants all, None where it
+    has no use for parity at all (`want_parity` False: no parity
+    store), and of the rows it names those that fall in this part —
+    parts are cut at multiples of k, so they are the item's rows less
+    `lo ÷ k`."""
+    want = part.item.want_parity
+    nrows = -(-(part.hi - part.lo) // k)
+    if isinstance(want, bool):
+        return range(nrows) if want else None
+    r0 = part.lo // k
+    return [r - r0 for r in want if r0 <= r < r0 + nrows]
+
+
+def _part_parity(part: _Part, k: int, rows, row_of):
+    """A scrub part's parity as its item is answered: `rows` are the
+    part's wanted rows (`_wanted_rows`) and `row_of(r)` is row r's
+    (m, ≥ width) bytes.  An item that wants every row gets the (rows,
+    m, widest member) array; one that names rows a dict keyed by ITS
+    row numbers, each trimmed to the row's own longest member."""
+    if not rows:
+        return None
+    blocks = part.item.payload[0][part.lo:part.hi]
+    if not isinstance(part.item.want_parity, bool):
+        r0 = part.lo // k
+        return {r0 + r: np.ascontiguousarray(row_of(r)[:, :max(
+            len(b) for b in blocks[r * k:(r + 1) * k])]) for r in rows}
+    ml = max(len(b) for b in blocks)
+    return np.stack([row_of(r)[:, :ml] for r in rows])
+
+
 class _Batch:
     """One staged device dispatch: parts (possibly from several items)
     of a single kind, within the staging budget."""
 
     __slots__ = ("kind", "parts", "nbytes", "blocks", "eff_deadline",
-                 "cls", "want_parity", "ts", "staged_est",
+                 "cls", "ts", "staged_est",
                  "t_enq", "t_pop", "t_stage0", "t_stage1", "t_adopt1",
                  "t_submit1", "t_ready", "compiled",
                  "pool_rows", "pool_hits", "pool_adopt", "pool_shape",
-                 "staged_payload",
+                 "staged_payload", "parity_rows", "parity_bytes",
                  "prefetch", "track", "lanes")
 
     def __init__(self, kind: str, cls: str):
@@ -240,7 +280,6 @@ class _Batch:
         self.nbytes = 0        # payload bytes (obs accounting)
         self.blocks = 0
         self.eff_deadline = 0.0
-        self.want_parity = False
         self.ts = 0.0
         self.staged_est = 0    # bucketed staging-buffer bytes (admission)
         # monotonic_ns stage boundary stamps feeding the device timeline
@@ -270,6 +309,10 @@ class _Batch:
         self.pool_adopt: Optional[list] = None
         self.pool_shape: Optional[tuple] = None
         self.staged_payload: Optional[int] = None
+        # of a collected scrub batch that has a use for parity: its
+        # rows that crossed the link, and their bytes
+        self.parity_rows: Optional[int] = None
+        self.parity_bytes: Optional[int] = None
         self.prefetch = False
         # timeline track of the slot it is staged in, and the lanes it
         # dispatches at (None where the staged shape has none)
@@ -389,6 +432,13 @@ class DeviceTransport:
                 "batch kind: part=payload is block data, part=pad the "
                 "zeros that fill rows to the bucketed width and the "
                 "geometry's empty lanes")
+            self.m_parity_rows = metrics.counter(
+                "scrub_parity_rows_total",
+                "Parity rows (codewords) of collected scrub batches "
+                "that have a use for parity: fetch=fetched crossed the "
+                "link, fetch=left stayed on the device (their sidecar "
+                "is on disk, or they are trailing members, no codeword "
+                "yet)")
             self.m_depth = metrics.gauge(
                 "transport_queue_depth",
                 "Batches waiting in the device transport queue, by class",
@@ -436,7 +486,7 @@ class DeviceTransport:
                             / self.budget_bytes))
         else:
             self.m_staged = self.m_depth = self.m_inflight = None
-            self.m_lane_bytes = None
+            self.m_lane_bytes = self.m_parity_rows = None
 
     def device_busy_now(self) -> float:
         """Cumulative device-busy seconds including the open interval."""
@@ -475,8 +525,7 @@ class DeviceTransport:
 
     # --- submission ---------------------------------------------------------
 
-    def submit_items(self, kind: str, items: Sequence, *,
-                     want_parity: bool = True) -> None:
+    def submit_items(self, kind: str, items: Sequence) -> None:
         """Enqueue a ragged batch of submissions (the feeder's dispatch
         unit).  Items' futures are resolved by the transport worker;
         raises TransportClosed without touching any future when the
@@ -485,7 +534,7 @@ class DeviceTransport:
             raise ValueError(f"unknown transport kind {kind!r}")
         if not self.supports(kind):
             raise TransportClosed(f"device lacks {self.REQUIRED[kind]}")
-        batches = self._plan(kind, items, want_parity)
+        batches = self._plan(kind, items)
         now = self.clock()
         t_ns = time.monotonic_ns()
         with self._cond:
@@ -551,8 +600,7 @@ class DeviceTransport:
 
     # --- batch planning (the staging-bound clamp) ---------------------------
 
-    def _plan(self, kind: str, items: Sequence,
-              want_parity: bool) -> List[_Batch]:
+    def _plan(self, kind: str, items: Sequence) -> List[_Batch]:
         """Split items into staged batches of ≤ chunk_bytes.  Oversized
         items are cut at codeword-aligned boundaries and reassembled by
         their _Assembler; co-submitted items coalesce into one dispatch
@@ -578,7 +626,6 @@ class DeviceTransport:
         for it in items:
             cls = getattr(it, "cls", "fg") or "fg"
             pf = bool(getattr(it, "prefetch", False))
-            wp = bool(getattr(it, "want_parity", want_parity))
             pieces = self._cut_points(kind, it, k)
             sink = _Assembler(it, len(pieces))
             if len(pieces) > 1:
@@ -615,7 +662,6 @@ class DeviceTransport:
                 lanes_ml[1] = max(lanes_ml[1], ml)
                 cur.staged_est = (cur.staged_est + est if kind == "decode"
                                   else est)
-                cur.want_parity = cur.want_parity or wp
         flush()
         return batches
 
@@ -899,7 +945,9 @@ class DeviceTransport:
                      prefetch=batch.prefetch, variant=variant,
                      lanes=batch.lanes)
         tl.event(f"collect {batch.kind}", track, batch.t_ready or t_c0,
-                 t_c1, cat="transport", blocks=batch.blocks)
+                 t_c1, cat="transport", blocks=batch.blocks,
+                 parity_rows=batch.parity_rows,
+                 parity_bytes=batch.parity_bytes)
         if batch.t_stage0:
             self.profiler.record(
                 batch.kind, batch.nbytes, batch.t_stage0,
@@ -1245,7 +1293,16 @@ class DeviceTransport:
                 # resident submissions return (handle, composed device
                 # input) — the input ref is the adoption source
                 out, input_ref = out
-            ok, parity = dev.scrub_collect(out, batch.want_parity)
+            # the rows to bring back, as rows of the batch: a part's
+            # lanes start at a multiple of k, `o`, so its rows at o ÷ k
+            k = max(1, self.params.rs_data)
+            wanted = [_wanted_rows(part, k) if self.params.rs_data > 0
+                      and n else None for part, (_o, n)
+                      in zip(batch.parts, spans)]
+            fetch = [o // k + r for rows, (o, _n) in zip(wanted, spans)
+                     for r in rows or ()]
+            ok, parity = dev.scrub_collect(out, fetch)
+            self._note_parity(batch, wanted, parity)
             pool = self.pool
             if (pool is not None and batch.pool_adopt
                     and input_ref is not None):
@@ -1265,20 +1322,9 @@ class DeviceTransport:
                         logger.warning("pool adoption failed",
                                        exc_info=True)
                 self.obs.note_substage("pool_adopt", sp.t1 - sp.t0)
-            k = max(1, self.params.rs_data)
-            results = []
-            for part, (o, n) in zip(batch.parts, spans):
-                p_slice = None
-                if (parity is not None
-                        and getattr(part.item, "want_parity", True)
-                        and self.params.rs_data > 0 and n):
-                    blocks = part.item.payload[0][part.lo:part.hi]
-                    ml = max(len(b) for b in blocks)
-                    r0, nr = o // k, (n + k - 1) // k
-                    p_slice = np.ascontiguousarray(
-                        parity[r0:r0 + nr, :, :ml])
-                results.append((ok[o:o + n], p_slice))
-            return results
+            return [(ok[o:o + n], _part_parity(
+                part, k, rows, lambda r, o=o: parity[o // k + r]))
+                for part, (o, n), rows in zip(batch.parts, spans, wanted)]
         if kind == "encode":
             out, spans = handle
             parity = np.asarray(dev.encode_collect(out)
@@ -1301,6 +1347,25 @@ class DeviceTransport:
                 results[pi] = np.ascontiguousarray(
                     dec[off:off + nrows, ..., :s])
         return results
+
+    def _note_parity(self, batch: _Batch, wanted: list, parity) -> None:
+        """Count a collected scrub batch's parity rows: `fetched` those
+        that crossed the link, `left` those that stayed on the device,
+        over the parts whose item has a use for parity (none: nothing
+        is counted, and the `collect scrub` event says nothing)."""
+        k = max(1, self.params.rs_data)
+        total = sum(-(-(part.hi - part.lo) // k)
+                    for part, rows in zip(batch.parts, wanted)
+                    if rows is not None)
+        if not total:
+            return
+        got = (() if parity is None else parity.values()
+               if isinstance(parity, dict) else parity)
+        batch.parity_rows = min(len(got), total)   # the array has pad rows
+        batch.parity_bytes = sum(int(a.nbytes) for a in got)
+        if self.m_parity_rows is not None:
+            self.m_parity_rows.inc(batch.parity_rows, fetch="fetched")
+            self.m_parity_rows.inc(total - batch.parity_rows, fetch="left")
 
     # --- CPU absorption of device failures ----------------------------------
 
@@ -1333,9 +1398,13 @@ class DeviceTransport:
                 elif batch.kind == "scrub":
                     b, h = it.payload
                     blocks = b[part.lo:part.hi]
-                    res = cpu.scrub_encode_batch(
-                        blocks, h[part.lo:part.hi],
-                        getattr(it, "want_parity", True))
+                    # the floor encodes the rows wanted and no others
+                    k = max(1, self.params.rs_data)
+                    rows = _wanted_rows(part, k)
+                    ok, par = cpu.scrub_encode_batch(
+                        blocks, h[part.lo:part.hi], list(rows or ()))
+                    res = ok, (None if par is None else _part_parity(
+                        part, k, rows, par.__getitem__))
                     nbytes = sum(len(x) for x in blocks)
                 elif batch.kind == "encode":
                     blocks = it.payload[part.lo:part.hi]
@@ -1415,7 +1484,7 @@ class DeviceTransport:
                            prefetch=True)
         it.future.add_done_callback(_swallow_result)
         try:
-            self.submit_items("scrub", [it], want_parity=False)
+            self.submit_items("scrub", [it])
         except TransportClosed:
             return 0
         self.obs.timeline.event(

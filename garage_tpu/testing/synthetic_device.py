@@ -25,7 +25,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ..ops.codec import CodecParams
+from ..ops.codec import CodecParams, parity_by_row
 from ..ops.cpu_codec import CpuCodec
 from ..utils.data import Hash
 
@@ -83,6 +83,7 @@ class SyntheticLinkCodec:
             CpuCodec(params) if compute_real else None)
         self.submissions = 0
         self.bytes_submitted = 0
+        self.bytes_fetched = 0      # parity brought back by scrub_collect
         # transport A/B attribution: the bytes-level path
         # (scrub_encode_batch, *_ragged) models the serialize+copy link — each block pays a
         # pack copy plus a transfer-serialize copy, exactly what the
@@ -268,11 +269,20 @@ class SyntheticLinkCodec:
         ready = self._link_ready_at(int(lengths.sum()))
         return self._scrub_math(arr, lengths, expected, ready)
 
-    def scrub_collect(self, out, fetch_parity: bool):
+    def scrub_collect(self, out, parity_rows):
+        """TpuCodec.scrub_collect's contract: every row's parity as the
+        array, named rows as a dict; `bytes_fetched` is the D2H."""
         _h, ok, _bad, parity = out
         self._mark_ready(ok.ready)
-        return np.asarray(ok), (np.asarray(parity) if fetch_parity
-                                and parity is not None else None)
+        if not parity_rows or parity is None:
+            return np.asarray(ok), None
+        got = np.asarray(parity)
+        if parity_rows is not True and parity_by_row(len(parity_rows),
+                                                     len(got)):
+            got = {int(r): got[r].copy() for r in parity_rows}
+        self.bytes_fetched += sum(int(p.nbytes) for p in (
+            got.values() if isinstance(got, dict) else [got]))
+        return np.asarray(ok), got
 
     # --- the DevicePool API (ops/device_pool.py) ---
     #

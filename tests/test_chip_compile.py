@@ -108,7 +108,9 @@ def test_pool_programs_hold_words(one_chip, lanes, cols):
     codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
     codec._pool_geom = (1024, 256 << 10)
     keys = codec.pool_program_keys(lanes, cols)
-    assert len(keys) == {256: 10, 64: 4}[lanes]
+    assert len(keys) == {256: 11, 64: 5}[lanes]
+    # the one that slices a row out of the batch's parity (ISSUE 33)
+    assert keys[-1] == ("parity_row", lanes // 8, 4, cols)
     for key in keys:
         c = TpuCodec.pool_lowered(key, one_chip).compile()
         assert "u8[" not in c.as_text(), key
@@ -123,6 +125,24 @@ def test_fused_scrub_takes_words(one_chip):
                 ) + bytes_in[1:]
     c = codec._scrub_pallas().lower(*words_in, 8).compile()
     assert c.as_text().count("tpu_custom_call") == 2
+    # words in, words out: the parity is viewed as bytes on the host
+    # (`host_bytes`), and a row of it is sliced out in words
+    assert "u8[" not in c.as_text()
+    assert c.out_info[3].dtype == jnp.uint32
+    assert c.out_info[3].shape == (32, 4, MIB // 4)
+
+
+def test_pool_warm_builds_the_row_program():
+    """`pool_warm` compiles the geometry's whole closed set, the row
+    program with it (here for the CPU's device, at a small size): a
+    pass that names rows builds nothing."""
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    codec._pool_geom = (16, 1024)
+    codec.pool_warm(16, 4096)
+    assert set(codec._pool_execs) == set(codec.pool_program_keys(16, 4096))
+    row = codec._pool_execs["parity_row", 2, 4, 4096]
+    parity = np.arange(2 * 4 * 1024, dtype=np.uint32).reshape(2, 4, 1024)
+    assert np.array_equal(np.asarray(row(parity, np.int32(1))), parity[1])
 
 
 def test_unrolled_xla_hash_small_lanes(one_chip):

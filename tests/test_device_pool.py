@@ -110,7 +110,7 @@ def _by_hand(tr, blocks, hashes, slot=0):
     `tr._collect` whenever the test wants the collect to happen."""
     it = TransportItem("scrub", (blocks, hashes), len(blocks),
                        sum(map(len, blocks)))
-    (batch,) = tr._plan("scrub", [it], True)
+    (batch,) = tr._plan("scrub", [it])
     return batch, tr._submit(batch, tr._stage(batch, slot))
 
 
@@ -380,7 +380,7 @@ def test_composed_batch_equals_lane_by_lane_composition():
     assert 0 < len(resident) < len(blocks)
     it = TransportItem("scrub", (blocks, hashes), len(blocks),
                        sum(map(len, blocks)))
-    (batch,) = tr._plan("scrub", [it], True)
+    (batch,) = tr._plan("scrub", [it])
     miss_arr, miss_rows, lengths, _expected, _spans = staged = \
         tr._stage(batch, 0)
     (_out, full), _spans = tr._submit(batch, staged)
@@ -429,11 +429,16 @@ def test_one_program_a_batch_each_way_from_a_closed_set():
     assert scrub_with_misses(0) == {"compose": 1, "adopt": 1}   # cold: 256
     closed = {("alloc", pool.npages, 512)} | set(
         dev.pool_program_keys(256, 1024))
-    assert set(dev._pool_execs) == closed and len(closed) == 11
+    assert set(dev._pool_execs) == closed and len(closed) == 12
     warm = compiled()
     assert warm > 0
     for n in (0, 1, 17, 255, 256):
         assert scrub_with_misses(n) == {"compose": 1, "adopt": int(n > 0)}
+    # rows of the parity named: the geometry's one row program, a
+    # dispatch a row, built with the rest of the set
+    ok, par = _scrub(tr, blocks, hashes, want_parity=[31, 2], timeout=300)
+    assert ok.all() and sorted(par) == [2, 31]
+    assert programs.get(op="parity_row") == 2
     assert compiled() == warm and set(dev._pool_execs) == closed
     assert programs.get(op="alloc") == 1
     # the bucket's pad rows are counted as pad, not as payload

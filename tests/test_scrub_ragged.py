@@ -142,6 +142,30 @@ async def _run(tmp_path) -> dict:
                         if e["name"] == "quarantine+heal"]
         with open(mgr.find_block(Hash(victim))[0], "rb") as f:
             got["healed"] = f.read()
+
+        # a third pass, two sidecars removed before it (ISSUE 33): their
+        # rows' parity is all that leaves the device
+        def counted():
+            return {
+                "rows": {f: reg.counter("scrub_parity_rows_total").get(
+                    fetch=f) for f in ("fetched", "left")},
+                "row_programs": reg.counter("pool_programs_total").get(
+                    op="parity_row"),
+                "sidecar_bytes": {p: sidecar_bytes(p)
+                                  for p in ("parity", "covered")}}
+
+        got["after_two"] = counted()
+        files = sorted(os.path.join(d, n) for d, _s, ns in os.walk(
+            mgr.parity_store.dir) for n in ns)
+        for f in files:
+            os.utime(f, (1, 1))
+        os.remove(files[0])
+        os.remove(files[3])
+        await _pass(worker)
+        got["after_three"] = counted()
+        got["third_mtimes"] = [os.stat(f).st_mtime for f in files]
+        got["third_sidecars"] = _sidecars(str(tmp_path / "n0" / "data"))
+        got["third_corruptions"] = worker.state.corruptions
     finally:
         if mgr.feeder is not None:
             mgr.feeder.shutdown()
@@ -199,6 +223,27 @@ def check_heal(got):
     assert len(got["healed"]) == length
 
 
+def check_refetch(got):
+    """Two sidecars removed before a pass: 2 of its 5 rows cross the
+    link, a dispatch of the geometry's row program each, and 3 stay;
+    the pass leaves the first pass's sidecars, every file touched, the
+    same bytes counted as filed."""
+    before, after = got["after_two"], got["after_three"]
+    # the first pass found no sidecar: every row of it was fetched
+    assert before["rows"]["fetched"] >= BLOCKS // K
+    rows = {f: after["rows"][f] - before["rows"][f] for f in after["rows"]}
+    # `left` counts a batch's trailing members too, no codeword yet
+    assert rows["fetched"] == 2 and 3 <= rows["left"] <= 3 + len(
+        got["submits"])
+    # (a dispatch a row; the whole array where a batch wants most of its rows)
+    assert after["row_programs"] - before["row_programs"] in (0, 1, 2)
+    assert got["third_corruptions"] == 0
+    assert got["third_sidecars"] == got["sidecars"]
+    assert min(got["third_mtimes"]) > 1
+    assert {p: after["sidecar_bytes"][p] - before["sidecar_bytes"][p]
+            for p in ("parity", "covered")} == got["sidecar_bytes"]
+
+
 def check_counters(got):
     """pad + payload is what the slots handed over; covered is the
     members' lengths and parity m rows of each codeword's longest."""
@@ -219,7 +264,7 @@ def check_counters(got):
 
 
 @pytest.mark.parametrize("check", [check_digests, check_sidecars, check_heal,
-                                   check_counters],
+                                   check_refetch, check_counters],
                          ids=lambda f: f.__name__[6:])
 def test_ragged_scrub_pass(passes, check):
     check(passes)
@@ -228,18 +273,16 @@ def test_ragged_scrub_pass(passes, check):
 @pytest.mark.parametrize("shape", [(1, 4, 1024), (3, 8, 7), (2, 1)])
 def test_host_views_are_the_device_views(shape):
     """`host_words` and `host_bytes` (numpy views either side of the
-    link) against the device-side bitcasts they stand in for outside a
-    jit."""
+    link): the first against the device-side bitcast it stands in for
+    outside a jit, the second as its inverse."""
     import jax.numpy as jnp
 
     from garage_tpu.ops.tpu_codec import (bytes_view_u32, host_bytes,
-                                          host_words, u32_view_bytes)
+                                          host_words)
 
     rng = np.random.default_rng(len(shape))
     raw = rng.integers(0, 256, shape[:-1] + (4 * shape[-1],), dtype=np.uint8)
     words = host_words(raw)
     assert words.shape == shape and words.dtype == np.uint32
     assert np.array_equal(words, np.asarray(bytes_view_u32(jnp.asarray(raw))))
-    back = host_bytes(jnp.asarray(words))
-    assert np.array_equal(back, np.asarray(u32_view_bytes(jnp.asarray(words))))
-    assert np.array_equal(back, raw)
+    assert np.array_equal(host_bytes(jnp.asarray(words)), raw)
