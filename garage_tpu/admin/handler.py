@@ -688,7 +688,12 @@ class AdminRpcHandler:
     async def _cmd_codec_info(self, msg) -> Dict:
         """Backend, effective params, gate state, byte split and
         per-stage attribution of the block manager's codec."""
+        from ..utils.zstd_compat import COMPRESSOR
+
         out = self.garage.block_manager.codec.info()
+        # `zlib-fallback` writes frames only the fallback reads: a test
+        # convenience, which the benchmark refuses to report through
+        out["compressor"] = COMPRESSOR
         out["heals"] = dict(self.garage.block_manager.heal_counts)
         feeder = self.garage.block_manager.feeder
         out["feeder"] = feeder.stats() if feeder is not None else None

@@ -333,6 +333,11 @@ class BlockManager:
                 "peer_sweep / distributed_decode = resync chain; "
                 "local_sidecar = local RS parity rebuild; rebuild = "
                 "fleet rebuild scheduler after a full-node loss)")
+            self.m_heal_stored = m.counter(
+                "block_heal_stored_total",
+                "Blocks rebuilt on this node and written through "
+                "store_rebuilt, by the form the configured compression "
+                "level gave them (zst | plain)")
             # gate-state gauges read THROUGH self.codec so a codec swap
             # (tests, future runtime rebuild) keeps /metrics truthful —
             # fn= observers on the codec itself would both pin the old
@@ -356,7 +361,7 @@ class BlockManager:
                 "device-side", fn=lambda: self.codec.obs.tpu_frac())
         else:
             self.m_read_dur = self.m_write_dur = None
-            self.m_heal = None
+            self.m_heal = self.m_heal_stored = None
             self.m_quarantine = self.m_quarantine_err = None
             self.m_repair_fetch = self.m_repair_repaired = None
             self.m_repair_overfetch = None
@@ -582,8 +587,28 @@ class BlockManager:
         return any(bytes(n) == bytes(self.system.id)
                    for n in self.replication.write_nodes(h))
 
+    async def block_for_storage(self, content: bytes) -> DataBlock:
+        """Content as this node's configuration stores it: compressed
+        at `compression_level` where that shrinks it (ref
+        block.rs:80-91), off the event loop."""
+        return await asyncio.to_thread(
+            DataBlock.from_buffer, content, self.compression_level)
+
+    async def store_rebuilt(self, h: Hash, content: bytes) -> None:
+        """Write a block whose content was reconstructed on this node
+        (sidecar heal, distributed decode, peer sweep, fleet rebuild)
+        in its stored form: a block that was `<id>.zst` comes back as
+        `<id>.zst`, since nothing compresses a plain file later."""
+        block = await self.block_for_storage(content)
+        wrote = await self.write_block(h, block)
+        if wrote and self.m_heal_stored is not None:
+            self.m_heal_stored.inc(
+                form="zst" if block.compressed else "plain")
+
     async def write_block(self, h: Hash, data: DataBlock,
-                          is_parity: bool = False) -> None:
+                          is_parity: bool = False) -> bool:
+        """→ whether a file was written (False: an equal-or-better copy
+        was already there)."""
         with self._span("write", h), maybe_time(self.m_write_dur):
             if is_parity and not self.is_parity_block(h):
                 self._parity_marks.insert(bytes(h), b"1")
@@ -599,6 +624,7 @@ class BlockManager:
                 # into further codewords would cascade encode rounds
                 # across the cluster for no durability the decode can use.
                 self.write_parity.add(h, data)
+            return wrote
 
     def _write_block_sync(self, h: Hash, data: DataBlock) -> bool:
         root = self.data_layout.primary_dir(h)
@@ -849,9 +875,7 @@ class BlockManager:
         # repair re-push) must carry the flag even when the caller
         # doesn't know the provenance
         is_parity = is_parity or self.is_parity_block(h)
-        block = await asyncio.to_thread(
-            DataBlock.from_buffer, data, self.compression_level
-        )
+        block = await self.block_for_storage(data)
         from ..rpc.rpc_helper import RequestStrategy
 
         async def send(node, timeout):
@@ -934,9 +958,7 @@ class BlockManager:
                 return DataBlock(b"".join(raw),
                                  compressed=bool(meta.get("compressed")),
                                  parity=bool(meta.get("parity")))
-            block = await asyncio.to_thread(
-                DataBlock.from_buffer, data, self.compression_level
-            )
+            block = await self.block_for_storage(data)
             return DataBlock(block.inner, block.compressed,
                              parity=bool(meta.get("parity")))
         return DataBlock(data, compressed=False,

@@ -466,15 +466,13 @@ class RebuildScheduler(Worker):
             if data is not None:
                 rows[want] = data
         healed = 0
-        from .block import DataBlock
-
         for t in targets:
             data = rows.get(t)
             if data is None:
                 continue
             mh = Hash(bytes(ent.members[t]))
             if mgr.is_assigned(mh):
-                await mgr.write_block(mh, DataBlock.plain(data))
+                await mgr.store_rebuilt(mh, data)
                 mgr.blocks_reconstructed += 1
                 mgr.note_heal("rebuild")
                 self.blocks_healed += 1
@@ -529,11 +527,12 @@ class RebuildScheduler(Worker):
         return None
 
     async def _push_row(self, h: Hash, data: bytes, node) -> bool:
-        from .block import DataBlock
         from .manager import _chunks
 
         mgr = self.manager
-        block = DataBlock.plain(data)
+        # its owner stores what it is sent: the form is decided here,
+        # as rpc_put_block decides it
+        block = await mgr.block_for_storage(data)
         try:
             await mgr.system.rpc.call(
                 mgr.endpoint, node,

@@ -244,6 +244,7 @@ class ScrubWorker(Worker):
         metrics = getattr(getattr(manager, "system", None), "metrics", None)
         self.m_segments = self.m_passes = None
         self.m_bytes = self.m_blocks = None
+        self.m_read = self.m_inflate_s = self.m_inflate_bytes = None
         if metrics is not None:
             self.m_segments = metrics.counter(
                 "scrub_pass_seconds_total",
@@ -260,6 +261,20 @@ class ScrubWorker(Worker):
                 "scrub_verified_blocks_total",
                 "Blocks the scrub handed to the codec: the lanes behind "
                 "scrub_verified_bytes_total")
+            self.m_read = metrics.counter(
+                "scrub_read_bytes_total",
+                "Bytes of block files the scrub read from disk, by the "
+                "form of the file (zst | plain): what the disk held of "
+                "scrub_verified_bytes_total's content")
+            self.m_inflate_s = metrics.counter(
+                "scrub_decompress_seconds_total",
+                "Seconds inside the scrub's zstd decompressions, summed "
+                "over the threads that ran them (the `decompress` "
+                "segment also holds their hops and waits)")
+            self.m_inflate_bytes = metrics.counter(
+                "scrub_decompress_bytes_total",
+                "Bytes the scrub's decompressions took (dir=in: the "
+                ".zst files) and gave (dir=out: their content)")
 
     def _roots(self) -> List[str]:
         return [d.path for d in self.manager.data_layout.data_dirs]
@@ -509,9 +524,10 @@ class ScrubWorker(Worker):
                           reads: Optional[List[Optional[bytes]]] = None) -> None:
         """Verify one batch through the codec; quarantine corrupt blocks.
 
-        Plain blocks go through codec.batch_verify (the device dispatch);
-        compressed blocks validate their zstd frame checksum on CPU, as in
-        the reference (block.rs:66-78)."""
+        Every block is verified on its content by the codec (the device
+        dispatch): a `.zst` copy is decompressed first, where the
+        reference validates its zstd frame checksum only
+        (block.rs:66-78)."""
         mgr = self.manager
         plain_idx, plain_blocks, plain_hashes = [], [], []
         if reads is None:
@@ -521,7 +537,8 @@ class ScrubWorker(Worker):
             )
             self._segment("read_wait", "read wait")
         lost = []           # (hash, path) to quarantine and heal
-        decompressed = 0
+        decompressed = inflate_ns = inflate_out = 0
+        read_bytes = {"zst": 0, "plain": 0}
         for i, ((h, path, compressed), raw) in enumerate(zip(batch, reads)):
             if raw is None:
                 continue
@@ -531,26 +548,42 @@ class ScrubWorker(Worker):
                 # ladder re-materialize a clean one
                 lost.append((h, path))
                 continue
+            read_bytes["zst" if compressed else "plain"] += len(raw)
+            data = raw
             if compressed:
                 # decompress so the codec verifies the CONTENT hash (a
                 # stronger check than the reference's zstd-checksum-only
                 # verify, block.rs:66-78) and the block joins a parity
                 # codeword — compressed blocks must be locally repairable
                 # too, not just the plain ones
-                data = await asyncio.to_thread(_try_decompress, raw)
+                content, ns = await asyncio.to_thread(
+                    _timed_decompress, _timeline(mgr), raw)
                 decompressed += 1
-                if data is None:
-                    lost.append((h, path))
-                    continue
-                plain_idx.append(i)
-                plain_blocks.append(data)
-                plain_hashes.append(h)
-            else:
-                plain_idx.append(i)
-                plain_blocks.append(raw)
-                plain_hashes.append(h)
+                inflate_ns += ns
+                # a frame that does not decode keeps its lane, as the
+                # file's own bytes: they fail the content hash like any
+                # corrupt block's, and the codewords after it keep their
+                # members (dropped here, every later row of the pass
+                # would shift by one, lack its sidecar and be encoded
+                # and written anew)
+                if content is not None:
+                    data = content
+                    inflate_out += len(content)
+            plain_idx.append(i)
+            plain_blocks.append(data)
+            plain_hashes.append(h)
+        if self.m_read is not None:
+            for form, n in read_bytes.items():
+                if n:
+                    self.m_read.inc(n, form=form)
         if decompressed:
-            self._segment("decompress", "decompress", blocks=decompressed)
+            if self.m_inflate_s is not None:
+                self.m_inflate_s.inc(inflate_ns / 1e9)
+                self.m_inflate_bytes.inc(read_bytes["zst"], dir="in")
+                self.m_inflate_bytes.inc(inflate_out, dir="out")
+            self._segment("decompress", "decompress", blocks=decompressed,
+                          bytes_in=read_bytes["zst"], bytes_out=inflate_out,
+                          self_ms=round(inflate_ns / 1e6, 3))
         await self._heal(lost)
         if plain_blocks:
             store = mgr.parity_store
@@ -736,9 +769,7 @@ class ScrubWorker(Worker):
         if store is not None:
             data = await asyncio.to_thread(store.try_reconstruct, h)
             if data is not None:
-                from .block import DataBlock
-
-                await self.manager.write_block(h, DataBlock.plain(data))
+                await self.manager.store_rebuilt(h, data)
                 self.manager.blocks_reconstructed += 1
                 self.manager.note_heal("local_sidecar")
                 return "local_sidecar"
@@ -947,8 +978,20 @@ def _try_decompress(raw: bytes) -> Optional[bytes]:
 
     try:
         return zstandard.ZstdDecompressor().decompress(raw)
-    except zstandard.ZstdError:
+    except (zstandard.ZstdError, MemoryError):
+        # MemoryError: a flipped frame-header bit can state a content
+        # size no allocation serves (bit 6 of byte 4 does)
         return None
+
+
+def _timed_decompress(timeline: Timeline, raw: bytes):
+    """→ (content or None, ns inside), under a `gt:decompress`
+    annotation of the thread that ran it: a block a section, so in the
+    profiler's trace only, never in the ring."""
+    with timeline.span("decompress", "scrub-io", cat="scrub",
+                       record=False) as sp:
+        data = _try_decompress(raw)
+    return data, sp.t1 - sp.t0
 
 
 def _move_into_place(mgr, src: str, dst: str) -> None:

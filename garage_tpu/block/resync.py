@@ -382,9 +382,7 @@ class BlockResyncManager:
                     mgr.parity_store.try_reconstruct, h
                 )
                 if data is not None:
-                    from .block import DataBlock
-
-                    await mgr.write_block(h, DataBlock.plain(data))
+                    await mgr.store_rebuilt(h, data)
                     mgr.blocks_reconstructed += 1
                     mgr.note_heal("local_sidecar")
                     return len(data)
@@ -416,9 +414,7 @@ class BlockResyncManager:
                     data = await mgr.parity_reconstructor(h)
                 if data is None:
                     raise
-                from .block import DataBlock
-
-                await mgr.write_block(h, DataBlock.plain(data))
+                await mgr.store_rebuilt(h, data)
                 if swept:
                     mgr.note_heal("peer_sweep")
                     logger.info("fetched displaced block %s via peer "

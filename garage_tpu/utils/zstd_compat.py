@@ -23,11 +23,14 @@ try:
     import zstandard  # noqa: F401  (the real wheel: re-exported as-is)
 
     HAVE_ZSTD = True
+    # which compressor writes this node's `.zst` files (`codec info`)
+    COMPRESSOR = f"zstandard {zstandard.__version__}"
 except ImportError:
     import types
     import zlib
 
     HAVE_ZSTD = False
+    COMPRESSOR = "zlib-fallback"
     _MAGIC = b"GTZF"
 
     class ZstdError(Exception):

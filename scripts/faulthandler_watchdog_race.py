@@ -11,13 +11,17 @@ CPython 3.12 dereferences it.  The watchdog thread blocks every signal,
 so the kernel kills the process with SIGSEGV and no handler runs: not
 faulthandler's own, not libtpu's, none a test installs.
 
-`benchmarks/kinds/scrub_passes.py` arms that watchdog every 0.5 s with
-a timeout of 1.5 s (`Watch`), so it fires whenever the event loop stands
-still that long while other threads work: the profiler's hand-over in
-the middle of a traced pass that outlasts `TRACE_S` (a pass that builds
-programs), or a long compile under the interpreter's lock.  PERF.md
-section 7 has the chip runs.  Needs no chip and no JAX: here the dumps
-come every millisecond, and the process dies within seconds (exit 139)."""
+Until PR 34 `benchmarks/kinds/scrub_passes.py` armed that watchdog
+every 0.5 s with a timeout of 1.5 s (`Watch`), so it fired whenever the
+event loop stood still that long while other threads worked: the
+profiler's hand-over in the middle of a traced pass, or a long compile
+under the interpreter's lock (the ledger's PRs 27 and 28, `run_failed`).
+No kind arms it now: `benchmarks/stalls.py` writes the stacks from a
+thread that holds the interpreter's lock, and `benchmarks/tests/
+test_stalls.py` keeps that nothing under `benchmarks/` arms the timed
+dump.  This script stays as the witness of the cause.  Needs no chip and
+no JAX: here the dumps come every millisecond, and the process dies
+within seconds (exit 139)."""
 
 import faulthandler
 import sys

@@ -2,9 +2,9 @@
 that tier-1 (`pytest tests/`) guards the readers and the cells: every
 test function of every `benchmarks/tests/test_*.py` is taken into this
 module under `test_<file>__<name>`.  One file, so one xdist worker
-(`--dist loadfile`) runs them, in three to six minutes."""
+(`--dist loadfile`) runs them, in five to six minutes (94 cases): the
+long pole of a tier-1 run on six workers."""
 
-import functools
 import importlib
 import pkgutil
 
@@ -24,50 +24,10 @@ def _the_deployments_codec(monkeypatch):
     monkeypatch.setattr(gconf, "config_from_dict", _orig_config_from_dict)
 
 
-def _fails_as_expected(fn, raises, why, match=None, only=None):
-    """`fn` as a strict expected failure: it has to raise `raises` (with
-    a message that matches `match`), and is then reported as xfail; a
-    run that raises anything else, or nothing, fails.  With `only`, that
-    holds for those parameter values and every other case runs as it
-    is."""
-    @functools.wraps(fn)
-    def run(*args, **kwargs):
-        if only is not None and only.isdisjoint((*args, *kwargs.values())):
-            return fn(*args, **kwargs)
-        with pytest.raises(raises, match=match):
-            fn(*args, **kwargs)
-        pytest.xfail(why)
-    return run
-
-
-# Two tests of `benchmarks/tests/` describe the manifest as PRs 25-26
-# left it, and no PR but a `benchmark` PR may edit a file there.  They
-# fail on the committed BENCHMARK.json since PR 29, here as under
-# `pytest benchmarks/tests`; here they are strict expected failures, so
-# the `benchmark` PR that repairs them (PERF.md section 7) has to take
-# these two lines out.  What they guard is held on the committed
-# manifest by `test_cells_sized.py`.
-_STALE = {
-    # a KeyError for any cell but the two in its dict of failing counts;
-    # only `ec84-warp.scrub` is excused, so the next cell fails here
-    ("test_cells", "test_the_control_is_not_correct"): dict(
-        raises=KeyError, match="^'ec84-warp.scrub'$",
-        only={"ec84-warp.scrub"},
-        why="test_cells.py names no failing count for ec84-warp.scrub"),
-    # PR 26's nine as the LAST of `per_layer`, and one of them listing
-    # `ec84-1m.scrub` alone
-    ("test_tracing_metrics",
-     "test_every_new_metric_is_in_the_manifest_with_its_file"): dict(
-        raises=AssertionError,
-        why="entries and a cell were appended after PR 26's"),
-}
-
 for _info in pkgutil.iter_modules(_suite.__path__):
     if not _info.name.startswith("test_"):
         continue
     _mod = importlib.import_module(f"{_suite.__name__}.{_info.name}")
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_") and callable(_obj):
-            if (_info.name, _name) in _STALE:
-                _obj = _fails_as_expected(_obj, **_STALE[_info.name, _name])
             globals()[f"test_{_info.name[5:]}__{_name[5:]}"] = _obj

@@ -318,7 +318,7 @@ class FakeBlockStore:
     def is_assigned(self, h):
         return True
 
-    async def write_block(self, h, block, is_parity=False):
+    async def store_rebuilt(self, h, content):
         self.writes.append(bytes(h))
         self.present.add(bytes(h))
 

@@ -77,7 +77,13 @@ async def _run(tmp_path) -> dict:
     codec = make_codec(
         "hybrid", metrics=system.metrics, tracer=system.tracer,
         block_size=BLOCK, rs_data=K, rs_parity=M, batch_blocks=16,
-        pool_mib=1, pool_page_kib=16)
+        pool_mib=1, pool_page_kib=16,
+        # the gate's verdict on the CPU's "device" is a wall-clock rate:
+        # where the checkout's compile cache is warm the first probe can
+        # read `hold` and the pass's first batch goes to the CPU side
+        # (3 rows fetched for 5: one run in four to eight); this test is
+        # about the device road, so the gate opens at any rate
+        hybrid_min_link_gibs=0.0)
     deadline = time.monotonic() + 120
     while not (codec.info().get("device_attached")
                and codec.info().get("transport")):
