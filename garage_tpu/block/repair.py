@@ -25,8 +25,10 @@ import asyncio
 import logging
 import os
 import random
+import threading
 import time
-from typing import List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -56,8 +58,32 @@ SCRUB_SEGMENTS = (
     "parity_write", "purge", "checkpoint", "tranquilize", "other",
 )
 
+# The scrub pass's I/O lane: the threads on which the read-ahead lists,
+# reads and inflates a batch, so that none of it stands in the loop's
+# default executor, where the worker's own hops go (`ScrubWorker._hop`).
+# A batch is cut into this many slices, each ONE submission that reads
+# its files in order.  Chosen on the chip (PERF.md §6, PR 36); not a knob.
+SCRUB_IO_THREADS = 4
+_SCRUB_IO: Optional[ThreadPoolExecutor] = None
+_SCRUB_IO_LOCK = threading.Lock()
+
 # spans of a manager with no codec observer (unit fakes) go nowhere
 _NO_TIMELINE = Timeline(size=1)
+
+
+def _scrub_io() -> ThreadPoolExecutor:
+    """The lane's one process-wide executor (workers are constructed per
+    node and per test; an executor each would leak threads)."""
+    global _SCRUB_IO
+    with _SCRUB_IO_LOCK:
+        if _SCRUB_IO is None:
+            from ..utils.cpuprof import register_thread
+            _SCRUB_IO = ThreadPoolExecutor(
+                max_workers=SCRUB_IO_THREADS,
+                thread_name_prefix="scrub-io",
+                initializer=lambda: register_thread("scrub-io"),
+            )
+        return _SCRUB_IO
 
 
 def _timeline(mgr) -> Timeline:
@@ -233,6 +259,10 @@ class ScrubWorker(Worker):
         # one verifies; checkpoints record the VERIFIED position, not the
         # iterator's (which runs one prefix ahead)
         self._ra_task: Optional[asyncio.Task] = None
+        # set while work() stands in `await` of the read-ahead: a batch
+        # read under it is submitted at once, so hinting it to the pool
+        # would stage, send and hash it twice (`_read_ahead`)
+        self._awaiting_read = False
         self._verified_pos = self.state.position
         # verified plain blocks carried between batches until a full RS
         # codeword (k blocks) accumulates for the parity sidecar store
@@ -245,6 +275,7 @@ class ScrubWorker(Worker):
         self.m_segments = self.m_passes = None
         self.m_bytes = self.m_blocks = None
         self.m_read = self.m_inflate_s = self.m_inflate_bytes = None
+        self.m_hop_wait = self.m_hints = None
         if metrics is not None:
             self.m_segments = metrics.counter(
                 "scrub_pass_seconds_total",
@@ -269,12 +300,22 @@ class ScrubWorker(Worker):
             self.m_inflate_s = metrics.counter(
                 "scrub_decompress_seconds_total",
                 "Seconds inside the scrub's zstd decompressions, summed "
-                "over the threads that ran them (the `decompress` "
-                "segment also holds their hops and waits)")
+                "over the I/O lane's threads that ran them, counted once "
+                "a block handed to the codec")
             self.m_inflate_bytes = metrics.counter(
                 "scrub_decompress_bytes_total",
                 "Bytes the scrub's decompressions took (dir=in: the "
                 ".zst files) and gave (dir=out: their content)")
+            self.m_hop_wait = metrics.counter(
+                "scrub_hop_wait_seconds_total",
+                "Seconds the scrub worker's off-loop steps stood in the "
+                "executor's queue, submission to the thread starting "
+                "them, by the pass segment that awaited them")
+            self.m_hints = metrics.counter(
+                "scrub_prefetch_hints_total",
+                "Batches the read-ahead hinted to the device pool "
+                "(hint=sent) or kept back because the worker was already "
+                "waiting for them (hint=skipped)")
 
     def _roots(self) -> List[str]:
         return [d.path for d in self.manager.data_layout.data_dirs]
@@ -399,6 +440,23 @@ class ScrubWorker(Worker):
         self._flush_account()
         self._acct = None
 
+    async def _hop(self, segment: str, fn, *args):
+        """An off-loop step the worker awaits, in the loop's default
+        executor (the read-ahead has threads of its own); how long it
+        stood in that executor's queue is counted to `segment`."""
+        t0 = time.monotonic_ns()
+        began = []
+
+        def run():
+            began.append(time.monotonic_ns())
+            return fn(*args)
+
+        try:
+            return await asyncio.to_thread(run)
+        finally:
+            if began and self.m_hop_wait is not None:
+                self.m_hop_wait.inc((began[0] - t0) / 1e9, segment=segment)
+
     # --- the batch scrub step ---
 
     async def work(self) -> WorkerState:
@@ -426,7 +484,11 @@ class ScrubWorker(Worker):
         # clear BEFORE awaiting: if the read fails, the next work() cycle
         # must retry a fresh read, not re-await the cached exception
         self._ra_task = None
-        item = await task
+        self._awaiting_read = True
+        try:
+            item = await task
+        finally:
+            self._awaiting_read = False
         self._segment("read_wait", "read wait")
         if item is None:
             # complete
@@ -446,10 +508,9 @@ class ScrubWorker(Worker):
                 # refreshed by NEITHER this pass nor the previous one,
                 # else orphans accumulate forever (one-pass grace keeps
                 # coverage for rows that failed verify this pass)
-                removed = await asyncio.to_thread(
-                    self.manager.parity_store.purge_stale,
-                    self._prev_pass_start,
-                )
+                removed = await self._hop(
+                    "purge", self.manager.parity_store.purge_stale,
+                    self._prev_pass_start)
                 self._segment("purge", "purge stale", removed=removed)
             self._checkpoint(force=True)
             self._segment("checkpoint", "checkpoint")
@@ -475,115 +536,110 @@ class ScrubWorker(Worker):
         return state
 
     async def _read_ahead(self):
-        """Next prefix's batch + file contents, read off-thread.  Returns
-        (batch, reads, iterator_position_after) or None at end-of-store."""
+        """Next batch, listed, read and inflated on the I/O lane's
+        threads.  Returns (batch, reads, iterator_position_after), `reads`
+        as `_read_batch` gives them, or None at end-of-store."""
         it = self.iterator
         if it is None:
             return None
+        mgr = self.manager
         t0 = time.monotonic_ns()
         # gather prefix dirs until `codec.batch_blocks` blocks: one prefix
         # holds ~1 block below ~8M blocks per node, and the device wants
         # wide batches (the fused kernel starts at 128 lanes)
-        want = max(1, self.manager.codec.params.batch_blocks)
-        batch = None
-        while batch is None or len(batch) < want:
-            more = await asyncio.to_thread(it.next_prefix)
-            if more is None:
-                break
-            batch = (batch or []) + more
+        batch = await asyncio.get_running_loop().run_in_executor(
+            _scrub_io(), _list_batch, it,
+            max(1, mgr.codec.params.batch_blocks))
         if batch is None:
             return None
-        reads = await asyncio.gather(
-            *[asyncio.to_thread(_try_read, self.manager, path)
-              for _h, path, _c in batch]
-        )
+        reads, slices = await _read_batch(mgr, batch)
+        # the lanes the real batch will have: (hash, what the codec takes)
+        lanes = [(h, r) for (h, _p, _c), r in zip(batch, reads)
+                 if isinstance(r, _Read)]
+        got = [r for _h, r in lanes]
         # the read-ahead itself, listing included, which overlaps the
         # worker's codec wait: on a track of its own, in no segment
-        _timeline(self.manager).event(
+        _timeline(mgr).event(
             "read files", "scrub-io", t0, time.monotonic_ns(), cat="scrub",
-            blocks=len(batch),
-            bytes=sum(len(r) for r in reads if isinstance(r, bytes)))
-        # hint the device pool about the upcoming prefix: the transport
-        # stages these blocks as background-class work WHILE the current
-        # batch computes (riding the PR 11 double buffer), so the next
-        # batch's H2D cost hides under compute and its scrub becomes a
-        # pool hit.  Plain blocks only — compressed copies are verified
-        # on their decompressed content, which we don't have yet.
-        feeder = self.manager.feeder
-        if feeder is not None:
-            p_blocks, p_hashes = [], []
-            for (h, _path, compressed), raw in zip(batch, reads):
-                if not compressed and isinstance(raw, bytes):
-                    p_blocks.append(raw)
-                    p_hashes.append(h)
-            if p_blocks:
-                feeder.prefetch_scrub(p_blocks, p_hashes)
-        return batch, list(reads), it.position
+            blocks=len(batch), bytes=sum(r.file_bytes for r in got),
+            inflated=sum(r.inflated for r in got),
+            inflate_ms=round(sum(r.inflate_ns for r in got) / 1e6, 3),
+            slices=slices)
+        # hint the device pool about the upcoming batch: the transport
+        # stages it as background-class work WHILE the current batch
+        # computes, so the next batch's H2D cost hides under compute and
+        # its scrub becomes a pool hit.  Every lane the real batch will
+        # have, so the hint has the batch's own geometry.  Only where it
+        # can win: with the worker already awaiting this batch the real
+        # submit is milliseconds away, the lookup would miss, and the
+        # transport would stage, send and hash the batch twice
+        feeder = mgr.feeder
+        if feeder is not None and lanes:
+            sent = not self._awaiting_read
+            if sent:
+                feeder.prefetch_scrub([r.data for r in got],
+                                      [h for h, _r in lanes])
+            if self.m_hints is not None:
+                self.m_hints.inc(hint="sent" if sent else "skipped")
+        return batch, reads, it.position
 
     async def scrub_batch(self, batch: List[Tuple[Hash, str, bool]],
-                          reads: Optional[List[Optional[bytes]]] = None) -> None:
+                          reads: Optional[list] = None) -> None:
         """Verify one batch through the codec; quarantine corrupt blocks.
 
         Every block is verified on its content by the codec (the device
-        dispatch): a `.zst` copy is decompressed first, where the
-        reference validates its zstd frame checksum only
-        (block.rs:66-78)."""
+        dispatch): a `.zst` copy is decompressed first (by the I/O lane,
+        the thread that read it), where the reference validates its zstd
+        frame checksum only (block.rs:66-78).
+
+        `reads` is the read-ahead's (`_read_batch`); without it the
+        batch goes through the same lane here.  A caller that read the
+        files itself may hand their bytes: those are sorted and inflated
+        on the worker's own path, the one case the segment `decompress`
+        is stamped for."""
         mgr = self.manager
         plain_idx, plain_blocks, plain_hashes = [], [], []
         if reads is None:
-            reads = await asyncio.gather(
-                *[asyncio.to_thread(_try_read, mgr, path)
-                  for _h, path, _c in batch]
-            )
+            reads, _slices = await _read_batch(mgr, batch)
             self._segment("read_wait", "read wait")
+        own = [i for i, r in enumerate(reads) if isinstance(r, bytes)]
+        if own:
+            reads = list(reads)
+            made = await self._hop(
+                "decompress", lambda: [
+                    _handed_over(_timeline(mgr), reads[i], batch[i][2])
+                    for i in own])
+            for i, r in zip(own, made):
+                reads[i] = r
+            self._segment("decompress", "decompress", blocks=len(own),
+                          inflated=sum(r.inflated for r in made))
         lost = []           # (hash, path) to quarantine and heal
-        decompressed = inflate_ns = inflate_out = 0
+        inflate_ns = inflate_out = 0
         read_bytes = {"zst": 0, "plain": 0}
-        for i, ((h, path, compressed), raw) in enumerate(zip(batch, reads)):
-            if raw is None:
+        for i, ((h, path, _c), r) in enumerate(zip(batch, reads)):
+            if r is None:
                 continue
-            if raw is _READ_ERROR:
+            if r is _READ_ERROR:
                 # unreadable on media: the copy is as lost as a content
                 # mismatch — quarantine it and let the sidecar/resync
                 # ladder re-materialize a clean one
                 lost.append((h, path))
                 continue
-            read_bytes["zst" if compressed else "plain"] += len(raw)
-            data = raw
-            if compressed:
-                # decompress so the codec verifies the CONTENT hash (a
-                # stronger check than the reference's zstd-checksum-only
-                # verify, block.rs:66-78) and the block joins a parity
-                # codeword — compressed blocks must be locally repairable
-                # too, not just the plain ones
-                content, ns = await asyncio.to_thread(
-                    _timed_decompress, _timeline(mgr), raw)
-                decompressed += 1
-                inflate_ns += ns
-                # a frame that does not decode keeps its lane, as the
-                # file's own bytes: they fail the content hash like any
-                # corrupt block's, and the codewords after it keep their
-                # members (dropped here, every later row of the pass
-                # would shift by one, lack its sidecar and be encoded
-                # and written anew)
-                if content is not None:
-                    data = content
-                    inflate_out += len(content)
+            read_bytes[r.form] += r.file_bytes
+            inflate_ns += r.inflate_ns
+            if r.inflated:
+                inflate_out += len(r.data)
             plain_idx.append(i)
-            plain_blocks.append(data)
+            plain_blocks.append(r.data)
             plain_hashes.append(h)
         if self.m_read is not None:
             for form, n in read_bytes.items():
                 if n:
                     self.m_read.inc(n, form=form)
-        if decompressed:
-            if self.m_inflate_s is not None:
+            if read_bytes["zst"]:
                 self.m_inflate_s.inc(inflate_ns / 1e9)
                 self.m_inflate_bytes.inc(read_bytes["zst"], dir="in")
                 self.m_inflate_bytes.inc(inflate_out, dir="out")
-            self._segment("decompress", "decompress", blocks=decompressed,
-                          bytes_in=read_bytes["zst"], bytes_out=inflate_out,
-                          self_ms=round(inflate_ns / 1e6, 3))
         await self._heal(lost)
         if plain_blocks:
             store = mgr.parity_store
@@ -605,8 +661,8 @@ class ScrubWorker(Worker):
             # none, and nothing but the verdicts leaves the device
             want_parity = False
             if files_parity:
-                want_parity = await asyncio.to_thread(
-                    store.rows_lacking_sidecar, all_h)
+                want_parity = await self._hop(
+                    "parity_write", store.rows_lacking_sidecar, all_h)
                 self._segment("parity_write", "parity ask",
                               rows=len(all_h) // k, lacking=len(want_parity))
             nbytes = sum(len(b) for b in plain_blocks)
@@ -635,10 +691,9 @@ class ScrubWorker(Worker):
                     ok, parity = await mgr.feeder.scrub_async(
                         all_b, all_h, want_parity)
                 else:
-                    ok, parity = await asyncio.to_thread(
-                        mgr.codec.scrub_encode_batch, all_b, all_h,
-                        want_parity,
-                    )
+                    ok, parity = await self._hop(
+                        "codec_wait", mgr.codec.scrub_encode_batch,
+                        all_b, all_h, want_parity)
             self._segment("codec_wait", "codec wait", blocks=len(all_b),
                           bytes=nbytes, carry=nc)
             await self._heal([batch[plain_idx[j]][:2]
@@ -683,7 +738,7 @@ class ScrubWorker(Worker):
                     ]
 
                 refreshed = 0
-                for h, b in await asyncio.to_thread(_uncovered):
+                for h, b in await self._hop("coverage_refresh", _uncovered):
                     self.coverage_refreshed += 1
                     refreshed += 1
                     acc.add(h, DataBlock.plain(b))
@@ -711,8 +766,8 @@ class ScrubWorker(Worker):
                     # bloat the sidecar to the batch-global maxlen
                     row_max = max(len(b) for b in all_b[lo:lo + k])
                     row_parity = np.asarray(parity[row])[:, :row_max]
-                    if await asyncio.to_thread(
-                        store.put_codeword,
+                    if await self._hop(
+                        "parity_write", store.put_codeword,
                         all_h[lo:lo + k],
                         [len(b) for b in all_b[lo:lo + k]],
                         row_parity,
@@ -727,8 +782,8 @@ class ScrubWorker(Worker):
                 if kept:
                     # a file gone since it was asked for is encoded and
                     # written there: `regained`, no parity of the batch's
-                    n, regained = await asyncio.to_thread(
-                        store.refresh_codewords, kept)
+                    n, regained = await self._hop(
+                        "parity_write", store.refresh_codewords, kept)
                     touched += n
                     written += regained
                 self._segment("parity_write", "parity write", rows=nrows,
@@ -761,13 +816,13 @@ class ScrubWorker(Worker):
         # a failing rename deletes the bad copy instead of silently
         # leaving it servable (the old _move_aside swallowed OSError)
         self.manager.pool_invalidate(h, "quarantine")
-        await asyncio.to_thread(self.manager.quarantine_path, path)
+        await self._hop("heal", self.manager.quarantine_path, path)
         # first line of defense: rebuild locally from the RS parity
         # sidecar — with every replica down this is the ONLY repair;
         # network resync stays as the fallback
         store = self.manager.parity_store
         if store is not None:
-            data = await asyncio.to_thread(store.try_reconstruct, h)
+            data = await self._hop("heal", store.try_reconstruct, h)
             if data is not None:
                 await self.manager.store_rebuilt(h, data)
                 self.manager.blocks_reconstructed += 1
@@ -952,7 +1007,7 @@ def _try_read(mgr, path: str):
     fundamentally healthy root read-only."""
     from .health import is_media_error
 
-    # one hop a block: in the profiler's trace, not in the ring, where
+    # a section a block: in the profiler's trace, not in the ring, where
     # the batch's `read files` event stands
     with _timeline(mgr).span("read file", "scrub-io", cat="scrub",
                              record=False):
@@ -971,6 +1026,74 @@ def _try_read(mgr, path: str):
             return _READ_ERROR
         mgr.health.note_ok(mgr._root_of(path), "scrub")
         return raw
+
+
+class _Read(NamedTuple):
+    """A block file as the I/O lane hands it over: what the codec takes."""
+    data: bytes       # the content; the file's own bytes where it is
+    #                   plain or its frame does not decode
+    file_bytes: int   # the file's length
+    form: str         # the file's: "zst" | "plain"
+    inflate_ns: int   # inside zstd
+    inflated: bool    # `data` came out of zstd
+
+
+def _list_batch(it: BlockStoreIterator, want: int):
+    """Prefix dirs until they hold `want` blocks, in one submission of
+    the lane; None when the walk is complete."""
+    batch = None
+    while batch is None or len(batch) < want:
+        more = it.next_prefix()
+        if more is None:
+            break
+        batch = (batch or []) + more
+    return batch
+
+
+def _handed_over(timeline: Timeline, raw: bytes, compressed: bool) -> _Read:
+    """A file's bytes as the codec takes them.  A `.zst` file is
+    decompressed so the codec verifies the CONTENT hash (a stronger
+    check than the reference's zstd-checksum-only verify,
+    block.rs:66-78) and the block joins a parity codeword: compressed
+    blocks must be locally repairable too.  A frame that does not
+    decode keeps its lane, as the file's own bytes: they fail the
+    content hash like any corrupt block's, and the codewords after it
+    keep their members (dropped, every later row of the pass would
+    shift by one, lack its sidecar and be encoded and written anew)."""
+    if not compressed:
+        return _Read(raw, len(raw), "plain", 0, False)
+    content, ns = _timed_decompress(timeline, raw)
+    if content is None:
+        return _Read(raw, len(raw), "zst", ns, False)
+    return _Read(content, len(raw), "zst", ns, True)
+
+
+def _read_slice(mgr, files) -> list:
+    """One submission of the lane: the slice's files read in order, each
+    inflated by the thread that read it.  `_try_read` through the
+    module's global name, once a file: what wraps it there (the
+    benchmark's `bench:scrub:file_read`) wraps every read."""
+    timeline = _timeline(mgr)
+    out = []
+    for _h, path, compressed in files:
+        raw = _try_read(mgr, path)
+        out.append(_handed_over(timeline, raw, compressed)
+                   if isinstance(raw, bytes) else raw)
+    return out
+
+
+async def _read_batch(mgr, batch) -> Tuple[list, int]:
+    """→ (the batch's reads in its order, slices): an item is a `_Read`,
+    None for a vanished file or `_READ_ERROR` (`_try_read`).  The batch
+    is cut into as many slices as the lane has threads; cancelled, the
+    slices that run finish and are dropped."""
+    loop = asyncio.get_running_loop()
+    per = max(1, -(-len(batch) // SCRUB_IO_THREADS))
+    parts = await asyncio.gather(*[
+        loop.run_in_executor(_scrub_io(), _read_slice, mgr,
+                             batch[lo:lo + per])
+        for lo in range(0, len(batch), per)])
+    return [r for part in parts for r in part], len(parts)
 
 
 def _try_decompress(raw: bytes) -> Optional[bytes]:

@@ -72,6 +72,7 @@ ROLE_SEGMENTS = {
     "merkle": "codec",
     "codec-hash": "codec",
     "aio-worker": "other",
+    "scrub-io": "disk",
     "event-loop": "api",
     "sampler": "other",
     "main": "other",
