@@ -23,6 +23,7 @@ Bit-identical to CpuCodec (tests/test_codec_equivalence.py).
 from __future__ import annotations
 
 import contextlib
+import math
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -188,6 +189,32 @@ def scrub_fused_xla(data_u8, lengths, expected, K_enc, k: int):
     return scrub_step_kernel(data_u8, lengths, expected, K_enc, k)
 
 
+def scrub_fused_pallas_step(pg, interpret: bool = False):
+    """The fused scrub step with BOTH hot ops as Pallas kernels: the
+    VMEM-resident blake2s (pallas_blake2s.py) and, where `pg` is given,
+    the GF mask-XOR apply (pallas_gf.py).  `lengths` and `expected`
+    have the device batch's lanes (TpuCodec.scrub_device_lanes); a
+    staged batch of fewer rows is zero-extended to them here, on the
+    device (a batch the pool composed has them already).  `interpret`
+    is the tests': the hash kernel in the Pallas interpreter."""
+    from .pallas_blake2s import blake2s_batch_pallas
+
+    def scrub_fused_pallas(data_u8, lengths, expected, K_enc, k):
+        pad = lengths.shape[0] - data_u8.shape[0]
+        if pad:
+            data_u8 = jnp.pad(data_u8, ((0, pad), (0, 0)))
+        h = blake2s_batch_pallas(data_u8, lengths, interpret=interpret)
+        ok = jnp.all(h == expected, axis=-1)
+        bad = jnp.sum(~ok, dtype=jnp.int32)
+        u32 = bytes_view_u32(data_u8)
+        groups = u32.reshape(u32.shape[0] // k, k, u32.shape[-1])
+        parity = (pg(groups) if pg is not None
+                  else gf_apply(groups, K_enc))
+        return h, ok, bad, parity
+
+    return scrub_fused_pallas
+
+
 # The device pool's programs (ops/device_pool.py).  Everything is
 # uint32 words: uint8 on the device costs the chip's compiler 10 s a
 # program and minutes for a view (PERF.md, PRs 29 and 30), and both
@@ -271,6 +298,15 @@ def _pallas_error_is_permanent(e: BaseException) -> bool:
     if isinstance(e, NotImplementedError):
         return True
     return any(s in msg for s in _PALLAS_PERMANENT_MARKERS)
+
+
+# The fewest lanes a scrub batch has on the device where the fused
+# Pallas road takes it (TpuCodec.scrub_device_lanes): one 128-lane row
+# of the hash kernel.  The kernel's cost goes by tiles of up to 8 rows,
+# not by lanes with content, so a 64-lane tail padded to one row costs
+# about what a row costs; the XLA scan it ran before took 25-50 x as
+# long a lane (PERF.md §6, PR 40: 128 against 256 measured there).
+SCRUB_LANE_FLOOR = 128
 
 
 class TpuCodec(BlockCodec):
@@ -411,10 +447,12 @@ class TpuCodec(BlockCodec):
     def staging_geometry(self, nlanes: int, maxlen: int,
                          kind: str) -> Tuple[int, int]:
         """(lanes, row_bytes) the transport must stage for a batch of
-        `nlanes` blocks of up to `maxlen` bytes: the compiled-executable
-        shape (power-of-two bucketing for XLA retrace avoidance, lane
-        alignment to whole codewords — and codewords-per-device when
-        sharded — for the fused scrub kernel's parity output)."""
+        `nlanes` blocks of up to `maxlen` bytes: the HOST's geometry,
+        which the staging budget counts (power-of-two bucketing for XLA
+        retrace avoidance, lane alignment to whole codewords — and
+        codewords-per-device when sharded — for the fused scrub
+        kernel's parity output).  A scrub batch's device program may
+        have more lanes than that: scrub_device_lanes."""
         cols = self._bucket(max(maxlen, 1))
         if kind in ("scrub", "encode"):
             lanes = self._batch_size(max(nlanes, 1))
@@ -422,6 +460,37 @@ class TpuCodec(BlockCodec):
         else:
             lanes = self._batch_size(max(nlanes, 1))
         return lanes, cols
+
+    def _mosaic_device(self) -> bool:
+        """Whether the codec's device is one Mosaic compiles for.  The
+        fused latch starts up everywhere and falls on a CPU only when
+        the first Pallas attempt fails; what the codec knows of its
+        device before any attempt is the platform."""
+        return self.device.platform == "tpu"
+
+    def scrub_device_lanes(self, lanes: int) -> int:
+        """Lanes of the batch the DEVICE hashes for a scrub batch staged
+        at `lanes` (staging_geometry's): where the fused Pallas road
+        will take the batch (its latch up, no mesh, a TPU) a count its
+        hash kernel does not tile — a pass's tail, a store smaller than
+        a batch — is raised to whole rows of SCRUB_LANE_FLOOR lanes
+        (and whole codewords).  The pad is built on the device
+        (pool_compose reads zeros for a lane without pages, the fused
+        program pads a staged batch) with length 0 and the empty
+        message's digest, so it verifies clean like the k-alignment
+        pad; the host stages, and the budget counts, `lanes` rows.
+        Elsewhere (a mesh, the CPU backend, a demoted latch) and for a
+        count no such row count fits, the batch keeps `lanes` and runs
+        the XLA fused program."""
+        from .pallas_blake2s import lanes_supported
+
+        pallas_road = (self._pallas_fused_ok and self.mesh is None
+                       and self._mosaic_device())
+        if not pallas_road or lanes_supported(lanes):
+            return lanes
+        step = math.lcm(SCRUB_LANE_FLOOR, self._lane_align())
+        raised = -(-lanes // step) * step
+        return raised if lanes_supported(raised) else lanes
 
     def _put(self, arr) -> jax.Array:
         """Batch-leading host array → the codec's device (split over
@@ -855,43 +924,35 @@ class TpuCodec(BlockCodec):
         if pad_lanes:
             arr = np.pad(arr, [(0, pad_lanes), (0, 0)])
             lengths = np.pad(lengths, (0, pad_lanes))
+        # the device's batch may have more lanes than were staged
+        lanes = self.scrub_device_lanes(arr.shape[0])
+        lengths = np.pad(lengths, (0, lanes - lengths.shape[0]))
         empty = np.frombuffer(
             _hl.blake2s(b"", digest_size=32).digest(), dtype="<u4"
         )
-        expected = np.broadcast_to(empty, (arr.shape[0], 8)).copy()
+        expected = np.broadcast_to(empty, (lanes, 8)).copy()
         expected[: len(blocks)] = np.stack(
             [np.frombuffer(bytes(h), dtype="<u4") for h in hashes]
         )
         return arr, lengths, expected
 
     def _scrub_pallas(self):
-        """The fused scrub jit with BOTH hot ops as Pallas kernels: the
-        VMEM-resident blake2s (pallas_blake2s.py) and the GF mask-XOR
-        apply (pallas_gf.py) when the latter's latch is up."""
+        """The fused scrub jit (scrub_fused_pallas_step), with the GF
+        kernel as Pallas too when that kernel's latch is up."""
         if self._scrub_pallas_jit is None:
-            from .pallas_blake2s import blake2s_batch_pallas
-
-            pg = self._pallas_for(self._enc_mat)
-
-            def scrub_fused_pallas(data_u8, lengths, expected, K_enc, k):
-                h = blake2s_batch_pallas(data_u8, lengths)
-                ok = jnp.all(h == expected, axis=-1)
-                bad = jnp.sum(~ok, dtype=jnp.int32)
-                u32 = bytes_view_u32(data_u8)
-                groups = u32.reshape(u32.shape[0] // k, k, u32.shape[-1])
-                parity = (pg(groups) if pg is not None
-                          else gf_apply(groups, K_enc))
-                return h, ok, bad, parity
-
-            self._scrub_pallas_jit = jax.jit(scrub_fused_pallas,
-                                             static_argnums=(4,))
+            self._scrub_pallas_jit = jax.jit(
+                scrub_fused_pallas_step(self._pallas_for(self._enc_mat)),
+                static_argnums=(4,))
         return self._scrub_pallas_jit
 
     def _use_pallas_scrub(self, nlanes: int) -> bool:
         """The Pallas fused scrub wants whole (…,128)-lane tiles in a
-        row count its hash kernel can tile; smaller padded batches
-        (a pass's tail) run the XLA variant instead of paying a 2-16x
-        lane pad."""
+        row count its hash kernel can tile.  On one chip every scrub
+        batch has such a count: scrub_device_lanes raises a smaller one
+        (a pass's tail) to a row, because a row of the kernel costs less
+        than the XLA variant's scan does from the second lane up.  The
+        XLA variant is the fallback: a mesh, a backend without Mosaic,
+        a demoted latch, a count no row count fits."""
         from .pallas_blake2s import lanes_supported
 
         return (self._pallas_fused_ok and self.mesh is None
@@ -952,7 +1013,9 @@ class TpuCodec(BlockCodec):
                             expected: np.ndarray):
         """Enqueue ONE device dispatch doing verify + RS(k,m) parity for a
         full batch; returns device arrays WITHOUT synchronizing, so callers
-        can pipeline batches and hide the dispatch latency.
+        can pipeline batches and hide the dispatch latency.  `lengths` and
+        `expected` may have more lanes than `arr` (scrub_device_lanes):
+        the device zero-extends the batch to them.
 
         Sets `last_submit_variant` ("pallas"|"xla") for the caller to
         thread into note_sync_{success,failure}: kernel failures surface
@@ -972,10 +1035,12 @@ class TpuCodec(BlockCodec):
     def _scrub_dispatch(self, data, dl, de):
         """Dispatch the fused kernel on a batch that is on the device:
         the Pallas variant where its latch and the lane count allow,
-        else (or after its failure) the XLA one."""
+        else (or after its failure) the XLA one.  `dl` and `de` have the
+        device batch's lanes (scrub_device_lanes), `data` those or the
+        rows that were staged."""
         self._mark_adopt()
         with self._dispatching("scrub"):
-            if self._use_pallas_scrub(data.shape[0]):
+            if self._use_pallas_scrub(dl.shape[0]):
                 try:
                     with self.obs.stage("kernel_dispatch", "tpu"):
                         out = self._scrub_pallas()(
@@ -985,6 +1050,9 @@ class TpuCodec(BlockCodec):
                     return out
                 except Exception as e:
                     self._note_fused_failure(e)
+            if dl.shape[0] != data.shape[0]:
+                # the lane pad was for the road that has just failed
+                dl, de = dl[:data.shape[0]], de[:data.shape[0]]
             if self.mesh is not None:
                 # a batch composed on the pool's device (the first)
                 # moves onto the mesh; one staged there already stays
@@ -1016,13 +1084,25 @@ class TpuCodec(BlockCodec):
         return self._pool_dispatch(("alloc",) + self._pool_geom)
 
     def pool_program_keys(self, lanes: int, cols: int) -> List[tuple]:
-        """The pool programs a (lanes, cols) batch can dispatch: one
-        adopt, one compose for every miss bucket, and where the batch
-        is encoded the one that slices a row out of its parity."""
+        """The pool programs a (lanes, cols) device batch can dispatch:
+        one adopt, one compose for every miss bucket, and where the
+        batch is encoded the one that slices a row out of its parity.
+        The miss rows are bucketed by the lanes that were STAGED
+        (transport._stage_scrub_pooled), so the buckets are those of
+        every staged count the device builds at `lanes`: `lanes`
+        itself, and under the lane floor the smaller counts
+        scrub_device_lanes raises to it."""
         geom = self._pool_geom + (int(lanes), int(cols))
         k, m = self.params.rs_data, self.params.rs_parity
+        staged, n = {int(lanes)}, 8
+        while n < lanes:
+            held = self.staging_geometry(n, 1, "scrub")[0]
+            if self.scrub_device_lanes(held) == lanes:
+                staged.add(held)
+            n <<= 1
+        buckets = sorted({mb for held in staged for mb in miss_buckets(held)})
         return [("adopt",) + geom] + [
-            ("compose",) + geom + (mb,) for mb in miss_buckets(lanes)
+            ("compose",) + geom + (mb,) for mb in buckets
         ] + ([("parity_row", int(lanes) // k, m, int(cols))] if k else [])
 
     def pool_warm(self, lanes: int, cols: int) -> None:

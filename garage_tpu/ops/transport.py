@@ -432,6 +432,13 @@ class DeviceTransport:
                 "batch kind: part=payload is block data, part=pad the "
                 "zeros that fill rows to the bucketed width and the "
                 "geometry's empty lanes")
+            self.m_device_lanes = metrics.counter(
+                "scrub_device_lanes_total",
+                "Lanes of the scrub batches the device hashed (real "
+                "batches and prefetch hints): part=content held a "
+                "block, part=pad is what fills the batch to whole "
+                "codewords, to its bucket and, where the fused Pallas "
+                "road takes it, to a row count its kernels tile")
             self.m_parity_rows = metrics.counter(
                 "scrub_parity_rows_total",
                 "Parity rows (codewords) of collected scrub batches "
@@ -487,6 +494,7 @@ class DeviceTransport:
         else:
             self.m_staged = self.m_depth = self.m_inflight = None
             self.m_lane_bytes = self.m_parity_rows = None
+            self.m_device_lanes = None
 
     def device_busy_now(self) -> float:
         """Cumulative device-busy seconds including the open interval."""
@@ -847,10 +855,20 @@ class DeviceTransport:
                        if batch.kind == "scrub" else None)
             shape = self._staged_shape(batch.kind, staged)
             batch.lanes = shape[0] if shape else None
+            content_lanes = None
+            if batch.kind == "scrub":
+                # the device's lanes with a block and without: the
+                # codeword, bucket and lane-floor pad (`_device_lanes`)
+                content_lanes = batch.blocks
+                if self.m_device_lanes is not None:
+                    self.m_device_lanes.inc(content_lanes, part="content")
+                    self.m_device_lanes.inc(batch.lanes - content_lanes,
+                                            part="pad")
             tl.event(f"submit {batch.kind}", track, batch.t_adopt1,
                      batch.t_submit1, cat="transport",
                      compiled=batch.compiled, shape=shape, variant=variant,
-                     lanes=batch.lanes, payload_bytes=payload_bytes,
+                     lanes=batch.lanes, content_lanes=content_lanes,
+                     payload_bytes=payload_bytes,
                      staged_bytes=staged_bytes)
             with self._cond:
                 if not self._inflight and self._busy_since is None:
@@ -1093,6 +1111,18 @@ class DeviceTransport:
             cols += (-cols) % self.HASH_ROW_ALIGN
         return lanes, cols
 
+    def _device_lanes(self, lanes: int, cols: int) -> int:
+        """Lanes of the batch the device builds from a scrub batch
+        staged at `_geometry`'s (lanes, cols): the device's own count
+        (TpuCodec.scrub_device_lanes: on one chip a count the Pallas
+        kernels tile) unless that batch would be larger on the device
+        than the largest the budget lets the host stage.  Decided here,
+        where the device batch is built, and not in `_geometry`: the
+        estimator and `_cut_points` keep counting what the slot holds."""
+        floor = getattr(self.device, "scrub_device_lanes", None)
+        dev_lanes = floor(lanes) if floor is not None else lanes
+        return dev_lanes if dev_lanes * cols <= self.chunk_bytes else lanes
+
     def _stage(self, batch: _Batch, slot: int):
         kind = batch.kind
         k = max(1, self.params.rs_data)
@@ -1136,13 +1166,17 @@ class DeviceTransport:
             maxlen = max((len(b) for b in flat), default=0)
             lanes, cols = self._geometry(lane, maxlen, kind)
             arr = self._slot_view(slot, lanes, cols)
-            lengths = np.zeros((lanes,), dtype=np.int32)
+            # a scrub batch's lengths and digests have the DEVICE's
+            # lanes, which zero-extends the staged rows to them
+            dev_lanes = (self._device_lanes(lanes, cols)
+                         if kind == "scrub" else lanes)
+            lengths = np.zeros((dev_lanes,), dtype=np.int32)
             self._write_blocks(arr, lengths, rows, flat)
             self._zero_gap_rows(arr, rows, lanes)
             if kind == "encode":
                 return arr.reshape(lanes // k, k, cols), spans
             expected = np.broadcast_to(
-                _empty_digest_words(), (lanes, 8)).astype(np.uint32)
+                _empty_digest_words(), (dev_lanes, 8)).astype(np.uint32)
             for r, h in zip(rows, hashes):
                 expected[r] = np.frombuffer(bytes(h), dtype="<u4")
             return arr, lengths, expected, spans
@@ -1208,9 +1242,13 @@ class DeviceTransport:
         maxlen = max((len(b) for _r, b, _h in entries), default=0)
         lanes, cols = self._geometry(lane, maxlen, "scrub")
         arr = self._slot_view(slot, lanes, cols)
-        lengths = np.zeros((lanes,), dtype=np.int32)
+        # the slot holds at most the `lanes` rows the budget counted;
+        # the batch the device composes may have more (the lane floor):
+        # lanes without pages, length 0, the empty message's digest
+        dev_lanes = self._device_lanes(lanes, cols)
+        lengths = np.zeros((dev_lanes,), dtype=np.int32)
         expected = np.broadcast_to(
-            _empty_digest_words(), (lanes, 8)).astype(np.uint32)
+            _empty_digest_words(), (dev_lanes, 8)).astype(np.uint32)
         resident: list = []   # (lane, slots) composed on device
         adopt: list = []      # (lane, key, length) adopted at collect
         miss_rows: List[int] = []
@@ -1248,10 +1286,10 @@ class DeviceTransport:
                 pool.note_miss(miss_bytes)
         elif miss_bytes:
             pool.note_miss(miss_bytes, prefetch=True)
-        batch.pool_rows = pool.row_index(lanes, cols, resident)
+        batch.pool_rows = pool.row_index(dev_lanes, cols, resident)
         batch.pool_hits = len(resident)
         batch.pool_adopt = adopt
-        batch.pool_shape = (lanes, cols)
+        batch.pool_shape = (dev_lanes, cols)
         batch.staged_payload = miss_bytes
         return (arr[:miss_bucket(ci, lanes)], miss_rows, lengths, expected,
                 spans)

@@ -267,6 +267,10 @@ class SyntheticLinkCodec:
         self.bytes_submitted += int(lengths.sum())
         self._mark_adopt("scrub", arr.shape)
         ready = self._link_ready_at(int(lengths.sum()))
+        if len(lengths) > arr.shape[0]:
+            # TpuCodec's contract: the device zero-extends the staged
+            # rows to the lanes `lengths` has (a test's lane floor)
+            arr = np.pad(arr, ((0, len(lengths) - arr.shape[0]), (0, 0)))
         return self._scrub_math(arr, lengths, expected, ready)
 
     def scrub_collect(self, out, parity_rows):

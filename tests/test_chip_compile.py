@@ -97,18 +97,32 @@ def test_fused_scrub_fits_the_chip_beside_the_pools(one_chip):
     assert need < HBM_BYTES, need
 
 
+def _one_chip_codec() -> TpuCodec:
+    """The codec as it is on one chip: under rehearsal its device is the
+    CPU's, so the test says what the chip would (ISSUE 40)."""
+    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    codec._mosaic_device = lambda: True
+    return codec
+
+
 @pytest.mark.parametrize("lanes,cols", [(256, MIB), (64, MIB),
-                                        (64, 64 << 10)])
+                                        (64, 64 << 10), (128, MIB),
+                                        (128, 64 << 10)])
 def test_pool_programs_hold_words(one_chip, lanes, cols):
     """The device pool's closed set for a geometry (a row of four pages,
     and one narrower than a page), at the shipped 1,024 x 256 KiB pool.
     uint8 on the device costs this compiler 10 s a program and minutes
     for a view of words as bytes (PERF.md, PRs 29-30): the pool, the
-    batch it composes and the fused kernel's input are words."""
-    codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    batch it composes and the fused kernel's input are words.  128
+    lanes is the tail's geometry on one chip (the lane floor): its
+    compose programs take the miss buckets of every staged count the
+    device raises to it, 8 and 16 rows among them."""
+    codec = _one_chip_codec()
     codec._pool_geom = (1024, 256 << 10)
     keys = codec.pool_program_keys(lanes, cols)
-    assert len(keys) == {256: 11, 64: 5}[lanes]
+    assert len(keys) == {256: 11, 64: 5, 128: 9}[lanes]
+    if lanes == 128:
+        assert [key[5] for key in keys[1:-1]] == [0, 8, 16, 32, 64, 96, 128]
     # the one that slices a row out of the batch's parity (ISSUE 33)
     assert keys[-1] == ("parity_row", lanes // 8, 4, cols)
     for key in keys:
@@ -118,10 +132,16 @@ def test_pool_programs_hold_words(one_chip, lanes, cols):
         assert _device_bytes(c) < 5 * 256 * MIB, key
 
 
-def test_fused_scrub_takes_words(one_chip):
+@pytest.mark.parametrize("lanes,staged", [(256, 256), (128, 128),
+                                          (128, 64)])
+def test_fused_scrub_takes_words(one_chip, lanes, staged):
+    """256 lanes: a pass's main batches; 128: its tail under the lane
+    floor, one row of the hash kernel (a tile as tall as the dimension),
+    composed by the pool at 128 lanes or, `staged` 64, zero-extended
+    from the staged rows inside the program."""
     codec = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
-    bytes_in = _scrub_shapes(256, MIB, codec, one_chip)
-    words_in = (S((256, MIB // 4), jnp.uint32, sharding=one_chip),
+    bytes_in = _scrub_shapes(lanes, MIB, codec, one_chip)
+    words_in = (S((staged, MIB // 4), jnp.uint32, sharding=one_chip),
                 ) + bytes_in[1:]
     c = codec._scrub_pallas().lower(*words_in, 8).compile()
     assert c.as_text().count("tpu_custom_call") == 2
@@ -129,7 +149,8 @@ def test_fused_scrub_takes_words(one_chip):
     # (`host_bytes`), and a row of it is sliced out in words
     assert "u8[" not in c.as_text()
     assert c.out_info[3].dtype == jnp.uint32
-    assert c.out_info[3].shape == (32, 4, MIB // 4)
+    assert c.out_info[3].shape == (lanes // 8, 4, MIB // 4)
+    assert c.out_info[1].shape == (lanes,)
 
 
 def test_pool_warm_builds_the_row_program():
@@ -197,3 +218,41 @@ def test_twelve_row_batches_are_declined(one_chip):
     jax.jit(blake2s_batch_pallas).lower(
         S((2048, 64 << 10), jnp.uint8, sharding=one_chip),
         S((2048,), jnp.int32, sharding=one_chip)).compile()
+
+
+def test_every_scrub_batch_on_one_chip_takes_the_pallas_road():
+    """ISSUE 40: on one chip the device's lane count of every scrub
+    batch is one the fused Pallas road takes; on a mesh, on the CPU
+    backend and after the latch fell the geometry is staging_geometry's
+    and a batch under 128 lanes runs the XLA program."""
+    def device_lanes(codec):
+        staged = {codec.staging_geometry(n, MIB, "scrub")[0]
+                  for n in range(1, 2049)}
+        return {n: codec.scrub_device_lanes(n) for n in staged}
+
+    chip = _one_chip_codec()
+    lanes = device_lanes(chip)
+    assert {n: d for n, d in lanes.items() if d != n} == {
+        8: 128, 16: 128, 32: 128, 64: 128}
+    assert all(chip._use_pallas_scrub(d) for d in lanes.values())
+    # whole codewords where k is no power of two; no row count fits
+    # k = 9 under the kernel's tile, and that batch keeps its lanes
+    k6 = TpuCodec(CodecParams(rs_data=6, rs_parity=3))
+    k6._mosaic_device = lambda: True
+    assert k6.scrub_device_lanes(12) == 384
+    k9 = TpuCodec(CodecParams(rs_data=9, rs_parity=3))
+    k9._mosaic_device = lambda: True
+    assert k9.scrub_device_lanes(72) == 72
+
+    demoted = _one_chip_codec()
+    demoted._note_fused_failure(NotImplementedError("no mosaic here"))
+    on_cpu = TpuCodec(CodecParams(rs_data=8, rs_parity=4))
+    mesh = TpuCodec(CodecParams(rs_data=8, rs_parity=4, shard_mesh=4))
+    mesh._mosaic_device = lambda: True
+    for codec in (demoted, on_cpu, mesh):
+        lanes = device_lanes(codec)
+        assert all(d == n for n, d in lanes.items())
+        assert not codec._use_pallas_scrub(lanes[min(lanes)])
+    assert not mesh._use_pallas_scrub(256)
+    assert not demoted._use_pallas_scrub(256)
+    assert on_cpu._use_pallas_scrub(256)    # the latch starts up
