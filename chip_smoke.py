@@ -347,12 +347,15 @@ async def scrub_pass(admin: Admin, timeout: float = 900.0) -> dict:
     tl = (await admin.cmd("device_timeline"))["traceEvents"]
     batches = []
     computed = []      # the variant of every `compute scrub` event
+    lane = {"read files": [], "read slice": []}     # the I/O lane's events
     prefetch = {}
     for e in tl:
         if e.get("ts", 0) < t0_us or e.get("ph") != "X":
             continue
         if e["name"] == "compute scrub":
             computed.append(e["args"].get("variant"))
+        elif e["name"] in lane:
+            lane[e["name"]].append(e)
         elif e["name"] == "stage scrub":
             prefetch[e["tid"]] = bool(e["args"].get("prefetch"))
         elif e["name"] == "submit scrub":
@@ -379,6 +382,7 @@ async def scrub_pass(admin: Admin, timeout: float = 900.0) -> dict:
         "events": events,
         "batches": batches,
         "computed": computed,
+        "lane": lane,
     }
 
 
@@ -401,8 +405,54 @@ def judge_on_device(smoke: Smoke, label: str, p: dict, scrubbed: int) -> None:
                 f"scrubbed {scrubbed}")
 
 
+def lane_faults(lane: dict) -> list:
+    """What is wrong with a pass's account of its I/O lane, as the ring
+    holds it: every `read files` event carries the account, has its
+    slices inside it, each slice's stages sum to its wall, and the
+    slices sum to the batch (all to the microsecond a term the ring
+    rounds to)."""
+    from garage_tpu.block.repair import SCRUB_IO_STAGES
+
+    faults = []
+    stages = SCRUB_IO_STAGES[1:]        # a slice's: all but `list`
+    carried = [f"{s}_ms" for s in SCRUB_IO_STAGES] + [
+        "cpu_ms", "slices_ms", "direct", "buffered"]
+    for b in lane["read files"]:
+        a = b["args"]
+        if any(k not in a for k in carried):
+            faults.append(f"no account on {a}")
+            continue
+        mine = [s["args"] for s in lane["read slice"]
+                if b["ts"] <= s["ts"] and s["ts"] + s["dur"]
+                <= b["ts"] + b["dur"] + 1]
+        if len(mine) != a["slices"]:
+            faults.append(f"{len(mine)} slices of {a['slices']} in the ring")
+            continue
+        for sa in mine:
+            off = sum(sa[f"{s}_ms"] for s in stages) - sa["wall_ms"]
+            if abs(off) > 0.004:
+                faults.append(f"a slice's stages miss its wall by {off} ms")
+        for key in carried[1:]:
+            of_slices = sum(sa["wall_ms" if key == "slices_ms" else key]
+                            for sa in mine)
+            if abs(of_slices - a[key]) > 0.001 * (len(mine) + 1):
+                faults.append(f"{key}: slices {of_slices}, batch {a[key]}")
+    return faults
+
+
 def judge_pass(smoke: Smoke, label: str, p: dict, scrubbed: int) -> None:
     judge_on_device(smoke, label, p, scrubbed)
+    # the I/O lane accounts for itself (block/repair.py `_read_slice`);
+    # whether its reads were O_DIRECT depends on where the store lives:
+    # reported, not judged
+    reads = {m: sum(b["args"].get(m, 0) for b in p["lane"]["read files"])
+             for m in ("direct", "buffered")}
+    faults = lane_faults(p["lane"])
+    smoke.check(f"{label}: every `read files` event carries the lane's "
+                "account and its slices' stages sum to their walls",
+                bool(p["lane"]["read files"]) and not faults,
+                f"reads {reads} in {len(p['lane']['read files'])} batches; "
+                f"{faults[:3]}")
     # on one chip a batch under 128 lanes (the pass's tail and its hint)
     # is padded on the device to a row the Pallas kernels tile
     # (TpuCodec.scrub_device_lanes): nothing is left to the XLA scan

@@ -35,6 +35,7 @@ import numpy as np
 from ..utils.background import Worker, WorkerState
 from ..utils.crdt import now_msec
 from ..utils.data import Hash
+from ..utils.direct_io import READ_MODES, accounting
 from ..utils.migrate import Migrated
 from ..utils.persister import Persister
 from ..utils.timeline import Timeline
@@ -66,6 +67,17 @@ SCRUB_SEGMENTS = (
 SCRUB_IO_THREADS = 4
 _SCRUB_IO: Optional[ThreadPoolExecutor] = None
 _SCRUB_IO_LOCK = threading.Lock()
+
+# What the lane's own time is partitioned into.  A slice (one submission
+# of the lane, `_read_slice`) is stamped from its submission to its last
+# file done: `queue` until a lane thread takes it, then `open`, `pread`
+# and `copy` inside each read (utils/direct_io.py `ReadAccount`),
+# `inflate` inside zstd, and `other`, the residue: `close`, the health
+# notes, `DiskIo._note`'s lock, the loop over the files, what a
+# `FaultyDisk` injects.  The stages of a slice sum to its wall exactly.
+# `list` is the listing's one submission, until its result.
+SCRUB_IO_STAGES = ("list", "queue", "open", "pread", "copy", "inflate",
+                   "other")
 
 # spans of a manager with no codec observer (unit fakes) go nowhere
 _NO_TIMELINE = Timeline(size=1)
@@ -110,6 +122,38 @@ class _PassAccount:
         prev, self.last = self.last, now
         self.ns[segment] += now - prev
         return prev, now
+
+
+class _LaneAccount:
+    """The I/O lane's account of one slice, or of a batch (its slices
+    added up): nanoseconds by `SCRUB_IO_STAGES` on the ring's clock,
+    the slices' walls (submission → last file done), the CPU their
+    threads used (`time.thread_time_ns`), and the files read and their
+    bytes by the read's mode (`direct_io.READ_MODES`)."""
+
+    def __init__(self):
+        self.ns = dict.fromkeys(SCRUB_IO_STAGES, 0)
+        self.wall_ns = self.cpu_ns = self.slices = 0
+        self.files = dict.fromkeys(READ_MODES, 0)
+        self.bytes = dict.fromkeys(READ_MODES, 0)
+
+    def add(self, other: "_LaneAccount") -> None:
+        for stage, ns in other.ns.items():
+            self.ns[stage] += ns
+        self.wall_ns += other.wall_ns
+        self.cpu_ns += other.cpu_ns
+        self.slices += other.slices
+        for mode in READ_MODES:
+            self.files[mode] += other.files[mode]
+            self.bytes[mode] += other.bytes[mode]
+
+    def args(self) -> dict:
+        """The account as a ring event carries it."""
+        out = {f"{stage}_ms": round(ns / 1e6, 3)
+               for stage, ns in self.ns.items()}
+        out["cpu_ms"] = round(self.cpu_ns / 1e6, 3)
+        out.update(self.files)
+        return out
 
 
 class BlockStoreIterator:
@@ -276,6 +320,8 @@ class ScrubWorker(Worker):
         self.m_bytes = self.m_blocks = None
         self.m_read = self.m_inflate_s = self.m_inflate_bytes = None
         self.m_hop_wait = self.m_hints = None
+        self.m_io_s = self.m_io_cpu = self.m_io_wall = None
+        self.m_io_bytes = self.m_io_files = None
         if metrics is not None:
             self.m_segments = metrics.counter(
                 "scrub_pass_seconds_total",
@@ -297,6 +343,32 @@ class ScrubWorker(Worker):
                 "Bytes of block files the scrub read from disk, by the "
                 "form of the file (zst | plain): what the disk held of "
                 "scrub_verified_bytes_total's content")
+            self.m_io_s = metrics.counter(
+                "scrub_io_seconds_total",
+                "Seconds of the scrub's I/O lane by stage, stamped where "
+                "the work happens: list (the listing's submission to its "
+                "result), and for every slice of a batch queue | open | "
+                "pread | copy | inflate | other, thread-seconds that sum "
+                "to the slices' walls (submission to last file done)")
+            self.m_io_cpu = metrics.counter(
+                "scrub_io_cpu_seconds_total",
+                "CPU seconds the I/O lane's threads used inside their "
+                "slices (thread_time): beside the stages that run on the "
+                "thread it says whether the lane computes or waits")
+            self.m_io_wall = metrics.counter(
+                "scrub_io_wall_seconds_total",
+                "Wall seconds of the I/O lane's batches, listing "
+                "included: each `read files` interval of the timeline, "
+                "the lane's critical path for a batch")
+            self.m_io_bytes = metrics.counter(
+                "scrub_io_bytes_total",
+                "Bytes of the block files the I/O lane read, by the "
+                "read's mode: direct (O_DIRECT all through) | buffered "
+                "(fell back at the open or mid-file)")
+            self.m_io_files = metrics.counter(
+                "scrub_io_files_total",
+                "Block files the I/O lane read, by the read's mode: the "
+                "files behind scrub_io_bytes_total")
             self.m_inflate_s = metrics.counter(
                 "scrub_decompress_seconds_total",
                 "Seconds inside the scrub's zstd decompressions, summed "
@@ -552,19 +624,16 @@ class ScrubWorker(Worker):
             max(1, mgr.codec.params.batch_blocks))
         if batch is None:
             return None
-        reads, slices = await _read_batch(mgr, batch)
+        t_listed = time.monotonic_ns()
+        reads, lane = await _read_batch(mgr, batch)
+        lane.ns["list"] = t_listed - t0
+        # the read-ahead itself, listing included, which overlaps the
+        # worker's codec wait: on a track of its own, in no segment
+        self._lane_done(batch, reads, lane, t0)
         # the lanes the real batch will have: (hash, what the codec takes)
         lanes = [(h, r) for (h, _p, _c), r in zip(batch, reads)
                  if isinstance(r, _Read)]
         got = [r for _h, r in lanes]
-        # the read-ahead itself, listing included, which overlaps the
-        # worker's codec wait: on a track of its own, in no segment
-        _timeline(mgr).event(
-            "read files", "scrub-io", t0, time.monotonic_ns(), cat="scrub",
-            blocks=len(batch), bytes=sum(r.file_bytes for r in got),
-            inflated=sum(r.inflated for r in got),
-            inflate_ms=round(sum(r.inflate_ns for r in got) / 1e6, 3),
-            slices=slices)
         # hint the device pool about the upcoming batch: the transport
         # stages it as background-class work WHILE the current batch
         # computes, so the next batch's H2D cost hides under compute and
@@ -583,6 +652,31 @@ class ScrubWorker(Worker):
                 self.m_hints.inc(hint="sent" if sent else "skipped")
         return batch, reads, it.position
 
+    def _lane_done(self, batch, reads, lane: "_LaneAccount",
+                   t0: int) -> None:
+        """A batch has come off the I/O lane: its `read files` event,
+        from `t0` to now, carrying the lane's account, and the same
+        numbers to the counters.  Once a batch, on the loop's thread; a
+        read-ahead dropped before this counts nothing."""
+        t1 = time.monotonic_ns()
+        _timeline(self.manager).event(
+            "read files", "scrub-io", t0, t1, cat="scrub",
+            blocks=len(batch), bytes=sum(lane.bytes.values()),
+            inflated=sum(r.inflated for r in reads if isinstance(r, _Read)),
+            slices=lane.slices, slices_ms=round(lane.wall_ns / 1e6, 3),
+            **lane.args())
+        if self.m_io_s is None:
+            return
+        for stage, ns in lane.ns.items():
+            if ns:
+                self.m_io_s.inc(ns / 1e9, stage=stage)
+        self.m_io_cpu.inc(lane.cpu_ns / 1e9)
+        self.m_io_wall.inc((t1 - t0) / 1e9)
+        for mode in READ_MODES:
+            if lane.files[mode]:
+                self.m_io_files.inc(lane.files[mode], mode=mode)
+                self.m_io_bytes.inc(lane.bytes[mode], mode=mode)
+
     async def scrub_batch(self, batch: List[Tuple[Hash, str, bool]],
                           reads: Optional[list] = None) -> None:
         """Verify one batch through the codec; quarantine corrupt blocks.
@@ -600,15 +694,16 @@ class ScrubWorker(Worker):
         mgr = self.manager
         plain_idx, plain_blocks, plain_hashes = [], [], []
         if reads is None:
-            reads, _slices = await _read_batch(mgr, batch)
+            t0 = time.monotonic_ns()
+            reads, lane = await _read_batch(mgr, batch)
+            self._lane_done(batch, reads, lane, t0)
             self._segment("read_wait", "read wait")
         own = [i for i, r in enumerate(reads) if isinstance(r, bytes)]
         if own:
             reads = list(reads)
             made = await self._hop(
                 "decompress", lambda: [
-                    _handed_over(_timeline(mgr), reads[i], batch[i][2])
-                    for i in own])
+                    _handed_over(reads[i], batch[i][2]) for i in own])
             for i, r in zip(own, made):
                 reads[i] = r
             self._segment("decompress", "decompress", blocks=len(own),
@@ -1007,25 +1102,21 @@ def _try_read(mgr, path: str):
     fundamentally healthy root read-only."""
     from .health import is_media_error
 
-    # a section a block: in the profiler's trace, not in the ring, where
-    # the batch's `read files` event stands
-    with _timeline(mgr).span("read file", "scrub-io", cat="scrub",
-                             record=False):
-        try:
-            raw = mgr.disk.read_file_direct(path)
-        except FileNotFoundError:
+    try:
+        raw = mgr.disk.read_file_direct(path)
+    except FileNotFoundError:
+        return None
+    except OSError as e:
+        if not is_media_error(e):
+            logger.warning("scrub: transient read error on %s "
+                           "(errno %s: %s)", path, e.errno, e)
             return None
-        except OSError as e:
-            if not is_media_error(e):
-                logger.warning("scrub: transient read error on %s "
-                               "(errno %s: %s)", path, e.errno, e)
-                return None
-            logger.error("scrub: read of %s failed (errno %s: %s)",
-                         path, e.errno, e)
-            mgr.health.note_error(mgr._root_of(path), "scrub", e)
-            return _READ_ERROR
-        mgr.health.note_ok(mgr._root_of(path), "scrub")
-        return raw
+        logger.error("scrub: read of %s failed (errno %s: %s)",
+                     path, e.errno, e)
+        mgr.health.note_error(mgr._root_of(path), "scrub", e)
+        return _READ_ERROR
+    mgr.health.note_ok(mgr._root_of(path), "scrub")
+    return raw
 
 
 class _Read(NamedTuple):
@@ -1050,7 +1141,7 @@ def _list_batch(it: BlockStoreIterator, want: int):
     return batch
 
 
-def _handed_over(timeline: Timeline, raw: bytes, compressed: bool) -> _Read:
+def _handed_over(raw: bytes, compressed: bool) -> _Read:
     """A file's bytes as the codec takes them.  A `.zst` file is
     decompressed so the codec verifies the CONTENT hash (a stronger
     check than the reference's zstd-checksum-only verify,
@@ -1062,38 +1153,64 @@ def _handed_over(timeline: Timeline, raw: bytes, compressed: bool) -> _Read:
     shift by one, lack its sidecar and be encoded and written anew)."""
     if not compressed:
         return _Read(raw, len(raw), "plain", 0, False)
-    content, ns = _timed_decompress(timeline, raw)
+    t0 = time.monotonic_ns()
+    content = _try_decompress(raw)
+    ns = time.monotonic_ns() - t0
     if content is None:
         return _Read(raw, len(raw), "zst", ns, False)
     return _Read(content, len(raw), "zst", ns, True)
 
 
-def _read_slice(mgr, files) -> list:
+def _read_slice(mgr, files, submitted_ns: int) -> Tuple[list, _LaneAccount]:
     """One submission of the lane: the slice's files read in order, each
-    inflated by the thread that read it.  `_try_read` through the
-    module's global name, once a file: what wraps it there (the
-    benchmark's `bench:scrub:file_read`) wraps every read."""
+    inflated by the thread that read it, and the slice's account
+    (`SCRUB_IO_STAGES`), which is also its `read slice` span on the
+    track `scrub-io`.  `_try_read` through the module's global name,
+    once a file: what wraps it there (the benchmark's
+    `bench:scrub:file_read`) wraps every read."""
     timeline = _timeline(mgr)
+    acct = _LaneAccount()
+    acct.slices = 1
     out = []
-    for _h, path, compressed in files:
-        raw = _try_read(mgr, path)
-        out.append(_handed_over(timeline, raw, compressed)
-                   if isinstance(raw, bytes) else raw)
-    return out
+    # the span's own stamps are the slice's: not in the ring until the
+    # account it carries is whole
+    with timeline.span("read slice", "scrub-io", cat="scrub",
+                       record=False) as sp:
+        cpu0 = time.thread_time_ns()
+        with accounting() as rd:
+            for _h, path, compressed in files:
+                raw = _try_read(mgr, path)
+                if isinstance(raw, bytes):
+                    raw = _handed_over(raw, compressed)
+                    acct.ns["inflate"] += raw.inflate_ns
+                out.append(raw)
+        acct.cpu_ns = time.thread_time_ns() - cpu0
+    acct.files, acct.bytes = rd.files, rd.bytes
+    acct.wall_ns = sp.t1 - submitted_ns
+    acct.ns.update(queue=sp.t0 - submitted_ns, open=rd.open_ns,
+                   pread=rd.pread_ns, copy=rd.copy_ns)
+    acct.ns["other"] = acct.wall_ns - sum(acct.ns.values())
+    timeline.event("read slice", "scrub-io", sp.t0, sp.t1, cat="scrub",
+                   files=len(files), bytes=sum(rd.bytes.values()),
+                   wall_ms=round(acct.wall_ns / 1e6, 3), **acct.args())
+    return out, acct
 
 
-async def _read_batch(mgr, batch) -> Tuple[list, int]:
-    """→ (the batch's reads in its order, slices): an item is a `_Read`,
-    None for a vanished file or `_READ_ERROR` (`_try_read`).  The batch
-    is cut into as many slices as the lane has threads; cancelled, the
-    slices that run finish and are dropped."""
+async def _read_batch(mgr, batch) -> Tuple[list, _LaneAccount]:
+    """→ (the batch's reads in its order, the lane's account of them):
+    an item is a `_Read`, None for a vanished file or `_READ_ERROR`
+    (`_try_read`).  The batch is cut into as many slices as the lane has
+    threads; cancelled, the slices that run finish and are dropped."""
     loop = asyncio.get_running_loop()
     per = max(1, -(-len(batch) // SCRUB_IO_THREADS))
     parts = await asyncio.gather(*[
         loop.run_in_executor(_scrub_io(), _read_slice, mgr,
-                             batch[lo:lo + per])
+                             batch[lo:lo + per], time.monotonic_ns())
         for lo in range(0, len(batch), per)])
-    return [r for part in parts for r in part], len(parts)
+    lane = _LaneAccount()
+    for _reads, acct in parts:
+        lane.add(acct)
+    return [r for reads, _acct in parts for r in reads], lane
 
 
 def _try_decompress(raw: bytes) -> Optional[bytes]:
@@ -1105,16 +1222,6 @@ def _try_decompress(raw: bytes) -> Optional[bytes]:
         # MemoryError: a flipped frame-header bit can state a content
         # size no allocation serves (bit 6 of byte 4 does)
         return None
-
-
-def _timed_decompress(timeline: Timeline, raw: bytes):
-    """→ (content or None, ns inside), under a `gt:decompress`
-    annotation of the thread that ran it: a block a section, so in the
-    profiler's trace only, never in the ring."""
-    with timeline.span("decompress", "scrub-io", cat="scrub",
-                       record=False) as sp:
-        data = _try_decompress(raw)
-    return data, sp.t1 - sp.t0
 
 
 def _move_into_place(mgr, src: str, dst: str) -> None:

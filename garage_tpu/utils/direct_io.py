@@ -24,16 +24,26 @@ which POSIX/ext4 allow).  Data is copied out of the mmap exactly once.
 Any OSError — O_DIRECT unsupported (tmpfs/overlay), mid-file EINVAL —
 falls back to a buffered read of the remainder, so this is never less
 available than open()/read().
+
+Which of the two a read was, and where its time went, is not in what it
+returns: a thread that wants to know installs a `ReadAccount`
+(`accounting()`), and every `read_file_direct` it then makes adds its
+stages and its mode there.  The scrub's I/O lane does, a slice at a
+time (block/repair.py `_read_slice`); nothing else pays more than the
+clock reads.
 """
 
 from __future__ import annotations
 
+import contextlib
 import mmap
 import os
 import threading
-from typing import List, Optional, Tuple
+import time
+from typing import Iterator, List, Optional, Tuple
 
 _PAGE = 4096
+_O_DIRECT = getattr(os, "O_DIRECT", 0)
 _CHUNK = 32 << 20  # preadv request size: one huge request measured ~40%
                    # slower on first touch; ≥4 MiB requests are equal
 
@@ -43,6 +53,42 @@ _CHUNK = 32 << 20  # preadv request size: one huge request measured ~40%
 # subsequent read run at device speed.  Scrub worker threads read
 # block-sized files repeatedly, so the cache converges immediately.
 _local = threading.local()
+
+READ_MODES = ("direct", "buffered")
+
+
+class ReadAccount:
+    """Where the `read_file_direct` calls of one thread put their time
+    while it is installed, by consecutive `monotonic_ns` stamps inside
+    the call: `open_ns` (`os.open` + `fstat` + the thread's buffer
+    growing; a refused O_DIRECT open too), `pread_ns` (the `preadv`
+    loop, or the whole buffered read where the open or a chunk fell
+    back) and `copy_ns` (the one copy out of the aligned buffer).  The
+    `close` between the last two is in none of them.  `files` and
+    `bytes` count the reads that came back, by `READ_MODES`: `direct`,
+    or `buffered` where the read fell back at the open or mid-file (or
+    the platform has no O_DIRECT)."""
+
+    __slots__ = ("open_ns", "pread_ns", "copy_ns", "files", "bytes")
+
+    def __init__(self):
+        self.open_ns = self.pread_ns = self.copy_ns = 0
+        self.files = dict.fromkeys(READ_MODES, 0)
+        self.bytes = dict.fromkeys(READ_MODES, 0)
+
+
+@contextlib.contextmanager
+def accounting() -> Iterator[ReadAccount]:
+    """A fresh `ReadAccount` for the calling thread's reads inside the
+    block.  It comes through whatever stands between the caller and
+    `read_file_direct` (the manager's `DiskIo`, a `FaultyDisk`)."""
+    acct = ReadAccount()
+    prev = getattr(_local, "acct", None)
+    _local.acct = acct
+    try:
+        yield acct
+    finally:
+        _local.acct = prev
 
 
 def _dest(cap: int) -> mmap.mmap:
@@ -54,27 +100,34 @@ def _dest(cap: int) -> mmap.mmap:
     return buf
 
 
-def _read_direct_raw(path: str) -> Optional[Tuple[memoryview, int]]:
-    """(view of a page-aligned per-thread buffer, valid byte count) via
-    O_DIRECT, or None if the open wants the buffered fallback.  The
-    view is only valid until this THREAD's next _read_direct_raw call —
-    callers copy out (once) before returning."""
-    flags = os.O_RDONLY | getattr(os, "O_DIRECT", 0)
+def _read_direct_raw(path: str) -> Optional[Tuple[memoryview, int, bool]]:
+    """(view of a page-aligned per-thread buffer, valid byte count,
+    whether every chunk came through O_DIRECT), or None if the open
+    wants the buffered fallback.  The view is only valid until this
+    THREAD's next _read_direct_raw call — callers copy out (once) before
+    returning.  Stamps `open` and `pread` of the thread's account."""
+    acct = getattr(_local, "acct", None)
+    t0 = time.monotonic_ns()
     try:
-        fd = os.open(path, flags)
+        fd = os.open(path, os.O_RDONLY | _O_DIRECT)
     except OSError:
+        if acct is not None:
+            acct.open_ns += time.monotonic_ns() - t0
         return None
     try:
         size = os.fstat(fd).st_size
         cap = max((size + _PAGE - 1) & ~(_PAGE - 1), _PAGE)
         dest = _dest(cap)
         mv = memoryview(dest)
+        t1 = time.monotonic_ns()
+        direct = bool(_O_DIRECT)
         off = 0
         while off < size:
             try:
                 n = os.preadv(fd, [mv[off:min(off + _CHUNK, cap)]], off)
             except OSError:
                 # mid-file refusal: finish buffered into the same dest
+                direct = False
                 rest = _read_buffered_from(path, off, size - off)
                 mv[off:off + len(rest)] = rest
                 off += len(rest)
@@ -82,7 +135,10 @@ def _read_direct_raw(path: str) -> Optional[Tuple[memoryview, int]]:
             if n <= 0:
                 break
             off += n
-        return mv, off
+        if acct is not None:
+            acct.open_ns += t1 - t0
+            acct.pread_ns += time.monotonic_ns() - t1
+        return mv, off, direct
     finally:
         os.close(fd)
 
@@ -96,12 +152,25 @@ def _read_buffered_from(path: str, offset: int, length: int) -> bytes:
 def read_file_direct(path: str) -> bytes:
     """Whole-file read via O_DIRECT with buffered fallback; exactly one
     copy out of the aligned buffer."""
+    acct = getattr(_local, "acct", None)
     raw = _read_direct_raw(path)
+    t0 = time.monotonic_ns()
     if raw is None:
         with open(path, "rb") as f:
-            return f.read()
-    mv, n = raw
-    return bytes(mv[:n])
+            data = f.read()
+        direct = False
+        if acct is not None:
+            acct.pread_ns += time.monotonic_ns() - t0
+    else:
+        mv, n, direct = raw
+        data = bytes(mv[:n])
+        if acct is not None:
+            acct.copy_ns += time.monotonic_ns() - t0
+    if acct is not None:
+        mode = "direct" if direct else "buffered"
+        acct.files[mode] += 1
+        acct.bytes[mode] += len(data)
+    return data
 
 
 def read_file_direct_blocks(path: str, block_size: int) -> List[bytes]:
@@ -115,7 +184,7 @@ def read_file_direct_blocks(path: str, block_size: int) -> List[bytes]:
             data = f.read()
         return [data[i:i + block_size]
                 for i in range(0, len(data), block_size)]
-    mv, n = raw
+    mv, n, _direct = raw
     return [bytes(mv[i:min(i + block_size, n)])
             for i in range(0, n, block_size)]
 
@@ -137,8 +206,7 @@ def write_file_direct(path: str, data: bytes, fsync: bool = False) -> None:
     """
     n = len(data)
     aligned = n & ~(_PAGE - 1)
-    flags = (os.O_WRONLY | os.O_CREAT | os.O_TRUNC
-             | getattr(os, "O_DIRECT", 0))
+    flags = os.O_WRONLY | os.O_CREAT | os.O_TRUNC | _O_DIRECT
     fd = -1
     if aligned:
         try:

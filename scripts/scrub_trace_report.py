@@ -22,7 +22,10 @@ and the node's `device_timeline` ring past the run, and reports
     and of `collect` is `pool adopt`; and the compile listener's
     counters by `where` and `from`;
   - every `scrub pass` the ring holds: its span beside the sum of its
-    segments, and its `other`.
+    segments, and its `other`; and beside it the I/O lane's own account
+    of the pass (`lane`): its `read files` events summed, the lane's
+    critical path (`wall_ms`) and its stages, whose slices' part sums
+    to the slices' walls (`slices_ms`).
 
 The benchmark's own reduction (`benchmarks/trace_reduce.py`) reads none
 of this yet; this script is how PERF.md's record of it was made.  The
@@ -132,6 +135,27 @@ def report(trace_dir: str, ring: list) -> dict:
     return out
 
 
+def lane_account(ring: list, root: dict) -> dict:
+    """The I/O lane's account of the pass whose `scrub pass` event is
+    `root`: the `read files` events that began inside it, summed.
+    `wall_ms` beside the pass's span says whether the lane bounds it;
+    `unaccounted_us` is the slices' walls less their six stages, which
+    the ring's rounding alone keeps from 0."""
+    from garage_tpu.block.repair import SCRUB_IO_STAGES
+
+    evs = [e for e in ring if e["name"] == "read files"
+           and root["ts"] <= e["ts"] <= root["ts"] + root["dur"]]
+    stages = [f"{s}_ms" for s in SCRUB_IO_STAGES]
+    acct = {k: round(sum(e["args"].get(k, 0) for e in evs), 3)
+            for k in stages + ["slices_ms", "cpu_ms", "direct", "buffered",
+                               "bytes"]}
+    acct["batches"] = len(evs)
+    acct["wall_ms"] = round(sum(e["dur"] for e in evs) / 1e3, 3)
+    acct["unaccounted_us"] = round(1e3 * (acct["slices_ms"] - sum(
+        acct[k] for k in stages[1:])), 1)        # all but `list`
+    return acct
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -161,7 +185,8 @@ def main(argv=None) -> int:
         {"span_us": e["dur"],
          "segments_us": round(sum(v for k, v in e["args"].items()
                                   if k.endswith("_ms")) * 1e3, 1),
-         "other_ms": e["args"].get("other_ms", 0.0)}
+         "other_ms": e["args"].get("other_ms", 0.0),
+         "lane": lane_account(kept["ring"], e)}
         for e in kept.get("ring", []) if e["name"] == "scrub pass"]
     print(json.dumps(rep), flush=True)
     return 0

@@ -3,7 +3,10 @@ listed, read and inflated on threads of the lane's own and handed over
 as what the codec takes; the worker's own hops go to the loop's default
 executor, which no read enters, and say how long they stood in its
 queue; the pool is hinted only when the worker is not already waiting
-for the batch."""
+for the batch.  The lane accounts for itself (ISSUE 41): every slice's
+stages, stamped where the work happens, sum to its wall, a batch's
+counters grow by what its `read files` event says, and each read says
+whether it was O_DIRECT."""
 
 import asyncio
 import base64
@@ -100,8 +103,12 @@ async def test_a_batch_comes_back_in_order_as_the_per_file_calls_give(
     fd.read_errno = errno.EIO
     m.disk = fd
 
-    reads, slices = await repair._read_batch(m, batch)
-    assert slices == min(threads, -(-len(batch) // -(-len(batch) // threads)))
+    reads, lane = await repair._read_batch(m, batch)
+    assert lane.slices == min(threads,
+                              -(-len(batch) // -(-len(batch) // threads)))
+    # the lane counts the reads that came back: neither the vanished
+    # file nor the one the faulty disk refused
+    assert sum(lane.files.values()) == len(batch) - 2
     assert len(reads) == len(batch)
     for i, ((h, path, compressed), r) in enumerate(zip(batch, reads)):
         raw = repair._try_read(m, path)         # the per-file call
@@ -253,11 +260,13 @@ async def test_a_read_ahead_dropped_mid_batch_leaves_no_task_and_counts_nothing(
     assert task.cancelled()
     assert asyncio.all_tasks() == {asyncio.current_task()}
     for counter in (w.m_read, w.m_inflate_s, w.m_inflate_bytes, w.m_bytes,
-                    w.m_hints):
+                    w.m_hints, w.m_io_s, w.m_io_cpu, w.m_io_wall,
+                    w.m_io_bytes, w.m_io_files):
         assert counter._vals == {}
     assert m.feeder.hints == []
-    assert [e for e in m.codec.obs.timeline.snapshot()
-            if e["name"] == "read files"] == []
+    # the slices that ran left their spans; the batch left none
+    names = {e["name"] for e in m.codec.obs.timeline.snapshot()}
+    assert "read files" not in names and "read slice" in names
     await shutdown(systems)
 
 
@@ -366,3 +375,283 @@ async def test_the_lane_calls_try_read_through_the_modules_global_name(
     assert sorted(calls) == sorted(p for _h, p, _c in _listing(m))
     assert w.state.corruptions == 0
     await shutdown(systems)
+
+
+# --- (g) the lane's own account (ISSUE 41) --------------------------------------
+
+ON_THREAD = ("open", "pread", "copy", "inflate", "other")
+
+
+def _slice_now(m, files):
+    """One slice on the calling thread, submitted as it starts."""
+    return repair._read_slice(m, files, time.monotonic_ns())
+
+
+async def test_a_slices_stages_sum_to_its_wall_and_a_batch_counts_what_its_event_says(
+        tmp_path):
+    from tests.test_table import shutdown
+
+    systems, m, contents = await _store(tmp_path, parity=True)
+    batch = _listing(m)
+    t_sub = time.monotonic_ns()
+    reads, acct = repair._read_slice(m, batch[:5], t_sub)
+    assert [r.data for r in reads] == [contents[bytes(h)]
+                                       for h, _p, _c in batch[:5]]
+    # to the nanosecond: `other` is the residue of consecutive stamps
+    assert sum(acct.ns.values()) == acct.wall_ns > 0
+    assert acct.ns["list"] == 0 and acct.ns["queue"] >= 0
+    assert all(acct.ns[s] > 0 for s in ("open", "pread", "other"))
+    assert acct.ns["inflate"] == sum(r.inflate_ns for r in reads) > 0
+    assert 0 < acct.cpu_ns
+    assert sum(acct.files.values()) == 5 and acct.slices == 1
+    assert sum(acct.bytes.values()) == sum(r.file_bytes for r in reads)
+    (sl,) = [e for e in m.codec.obs.timeline.snapshot()
+             if e["name"] == "read slice"]
+    assert sl["args"]["files"] == 5
+    assert sl["ts"] + sl["dur"] <= time.monotonic_ns() // 1000
+    assert abs(sl["args"]["wall_ms"] * 1e6 - acct.wall_ns) < 1e3
+
+    w = ScrubWorker(m)
+    for _ in range(2):
+        await _one_pass(w)
+    evs = [e for e in m.codec.obs.timeline.snapshot()[1:]
+           if e["cat"] == "scrub"]
+    batches = [e for e in evs if e["name"] == "read files"]
+    slices = [e for e in evs if e["name"] == "read slice"]
+    assert len(batches) == 2 and len(slices) == sum(
+        b["args"]["slices"] for b in batches) == 8
+    near = lambda a, b, terms=1: abs(a - b) <= 0.0006 * terms   # noqa: E731
+    for b in batches:
+        a = b["args"]
+        # the stages that belong to slices sum to the slices' walls,
+        # as far as the ring's rounding to a microsecond a term lets see
+        assert near(sum(a[f"{s}_ms"] for s in ("queue",) + ON_THREAD),
+                    a["slices_ms"], 7)
+        mine = [s for s in slices if b["ts"] <= s["ts"]
+                and s["ts"] + s["dur"] <= b["ts"] + b["dur"] + 1]
+        assert len(mine) == a["slices"]
+        for s in mine:
+            sa = s["args"]
+            assert near(sum(sa[f"{st}_ms"] for st in ("queue",) + ON_THREAD),
+                        sa["wall_ms"], 7)
+            # the span is the slice less its queue
+            assert abs(s["dur"] - (sa["wall_ms"] - sa["queue_ms"]) * 1e3) < 2.5
+        for key in [f"{st}_ms" for st in ("queue",) + ON_THREAD] + [
+                "cpu_ms", "direct", "buffered"]:
+            assert near(sum(s["args"][key] for s in mine), a[key], 5), key
+        assert a["direct"] + a["buffered"] == a["blocks"] == 16
+        assert a["list_ms"] > 0
+    # the counters' growth is the sum over the events
+    for stage in repair.SCRUB_IO_STAGES:
+        assert abs(w.m_io_s.get(stage=stage) * 1e3
+                   - sum(b["args"][f"{stage}_ms"] for b in batches)) < 0.002
+    assert abs(w.m_io_cpu.get() * 1e3
+               - sum(b["args"]["cpu_ms"] for b in batches)) < 0.002
+    assert abs(w.m_io_wall.get() * 1e6 - sum(b["dur"] for b in batches)) < 2.5
+    for mode in ("direct", "buffered"):
+        assert w.m_io_files.get(mode=mode) == sum(
+            b["args"][mode] for b in batches)
+    assert (w.m_io_bytes.get(mode="direct") + w.m_io_bytes.get(mode="buffered")
+            == sum(b["args"]["bytes"] for b in batches)
+            == w.m_read.get(form="zst") + w.m_read.get(form="plain"))
+    # the lane's critical path holds its longest slice, and the listing
+    for b in batches:
+        assert b["dur"] >= max(s["dur"] for s in slices
+                               if b["ts"] <= s["ts"] <= b["ts"] + b["dur"])
+    await shutdown(systems)
+
+
+async def test_scrub_batch_on_its_own_counts_the_lane_too(tmp_path):
+    from tests.test_table import shutdown
+
+    systems, m, _contents = await _store(tmp_path)
+    w = ScrubWorker(m)
+    await w.scrub_batch(_listing(m))
+    (ev,) = [e for e in m.codec.obs.timeline.snapshot()
+             if e["name"] == "read files"]
+    assert ev["args"]["list_ms"] == 0 and ev["args"]["slices"] == 4
+    assert w.m_io_s.get(stage="list") == 0 < w.m_io_s.get(stage="pread")
+    assert (w.m_io_files.get(mode="direct")
+            + w.m_io_files.get(mode="buffered")) == 16
+    assert abs(w.m_io_wall.get() * 1e6 - ev["dur"]) < 1.5
+    await shutdown(systems)
+
+
+async def test_a_slow_preadv_lands_in_pread(tmp_path, monkeypatch):
+    from garage_tpu.utils import direct_io
+    from tests.test_table import shutdown
+
+    systems, m, _contents = await _store(tmp_path)
+    # an open that every filesystem takes, so the loop over preadv runs
+    monkeypatch.setattr(direct_io, "_O_DIRECT", 0)
+    real = os.preadv
+
+    def slow(fd, bufs, off):
+        time.sleep(0.05)
+        return real(fd, bufs, off)
+
+    monkeypatch.setattr(direct_io.os, "preadv", slow)
+    _reads, acct = _slice_now(m, _listing(m)[:4])
+    assert acct.ns["pread"] >= 4 * 0.05e9
+    assert acct.ns["copy"] + acct.ns["other"] + acct.ns["open"] < 0.1e9
+    assert sum(acct.ns.values()) == acct.wall_ns
+    # a sleeping thread uses no CPU: the share that says "it waits"
+    assert acct.cpu_ns < 0.5 * acct.ns["pread"]
+    assert acct.files == {"direct": 0, "buffered": 4}
+    await shutdown(systems)
+
+
+async def test_a_lane_whose_threads_are_held_lands_in_queue(tmp_path):
+    from tests.test_table import shutdown
+
+    systems, m, _contents = await _store(tmp_path)
+    release = threading.Event()
+    held = [repair._scrub_io().submit(release.wait, 10)
+            for _ in range(repair.SCRUB_IO_THREADS)]
+    task = asyncio.ensure_future(repair._read_batch(m, _listing(m)))
+    await asyncio.sleep(0.15)
+    assert not task.done()
+    release.set()
+    _reads, lane = await task
+    assert all(f.result(timeout=10) for f in held)
+    assert lane.slices == 4 and lane.ns["queue"] >= 4 * 0.14e9
+    assert sum(lane.ns[s] for s in ON_THREAD) < lane.ns["queue"]
+    assert sum(lane.ns.values()) == lane.wall_ns
+    await shutdown(systems)
+
+
+@pytest.mark.parametrize("fault, mode", [
+    ("the open refuses O_DIRECT", "buffered"),
+    ("a chunk fails mid-file", "buffered"),
+    ("none", "direct"),
+])
+async def test_a_read_says_whether_it_was_o_direct(tmp_path, monkeypatch,
+                                                   fault, mode):
+    """Whatever the test directory's filesystem makes of O_DIRECT: the
+    opens are steered here."""
+    from garage_tpu.utils import direct_io
+    from tests.test_table import shutdown
+
+    if not direct_io._O_DIRECT:
+        pytest.skip("no O_DIRECT on this platform")
+    systems, m, contents = await _store(tmp_path)
+    real_open, real_preadv = os.open, os.preadv
+    asked = []
+
+    def open_(path, flags, *a, **kw):
+        if flags & os.O_DIRECT:
+            asked.append(path)
+            if fault == "the open refuses O_DIRECT":
+                raise OSError(errno.EINVAL, "no O_DIRECT here", path)
+        return real_open(path, flags & ~os.O_DIRECT, *a, **kw)
+
+    def preadv(fd, bufs, off):
+        if fault == "a chunk fails mid-file":
+            raise OSError(errno.EINVAL, "unaligned")
+        return real_preadv(fd, bufs, off)
+
+    monkeypatch.setattr(direct_io.os, "open", open_)
+    monkeypatch.setattr(direct_io.os, "preadv", preadv)
+    batch = _listing(m)[:6]
+    reads, acct = _slice_now(m, batch)
+    monkeypatch.undo()
+    assert sorted(asked) == sorted(p for _h, p, _c in batch)
+    assert [r.data for r in reads] == [contents[bytes(h)]
+                                       for h, _p, _c in batch]
+    other = "buffered" if mode == "direct" else "direct"
+    assert acct.files == {mode: 6, other: 0}
+    assert acct.bytes[mode] == sum(r.file_bytes for r in reads)
+    assert acct.bytes[other] == 0
+    # a read that never reached the aligned buffer has no copy out of it
+    assert (acct.ns["copy"] == 0) == (fault == "the open refuses O_DIRECT")
+    assert acct.ns["pread"] > 0
+    await shutdown(systems)
+
+
+async def test_a_faulty_disk_still_wraps_the_read_unchanged(tmp_path):
+    from tests.test_table import shutdown
+
+    systems, m, contents = await _store(tmp_path)
+    batch = _listing(m)[:4]
+    fd = FaultyDisk(m.disk)
+    fd.latency = 0.04
+    m.disk = fd
+    reads, acct = _slice_now(m, batch)
+    assert [r.data for r in reads] == [contents[bytes(h)]
+                                       for h, _p, _c in batch]
+    # what the wrapper injects is the slice's residue, not the read's
+    assert acct.ns["other"] >= 4 * 0.04e9 > acct.ns["pread"]
+    assert sum(acct.files.values()) == 4
+    assert sum(acct.ns.values()) == acct.wall_ns
+    fd.latency = 0.0
+    fd.path_prefix = batch[2][1]
+    fd.read_errno = errno.EIO
+    reads, acct = _slice_now(m, batch)
+    assert reads[2] is _READ_ERROR and fd.injected["read"] == 1
+    assert sum(acct.files.values()) == 3
+    assert sum(acct.bytes.values()) == sum(
+        r.file_bytes for r in reads if isinstance(r, _Read))
+    fd.clear()
+    fd.path_prefix = None
+    fd.bitrot_prob = 1.0
+    reads, acct = _slice_now(m, batch[:1])
+    assert fd.injected["bitrot"] == 1 and sum(acct.files.values()) == 1
+    assert reads[0].data != contents[bytes(batch[0][0])]
+    await shutdown(systems)
+
+
+# --- (h) the six metric files, through the benchmark's own reader ---------------
+
+IO_S = 'scrub_io_seconds_total{stage="%s"}'
+IO_B = 'scrub_io_bytes_total{mode="%s"}'
+IO_F = 'scrub_io_files_total{mode="%s"}'
+LANE_BEFORE = {
+    IO_S % "list": 1.0, IO_S % "queue": 2.0, IO_S % "open": 1.0,
+    IO_S % "pread": 10.0, IO_S % "copy": 1.0, IO_S % "other": 1.0,
+    "scrub_io_cpu_seconds_total": 5.0, "scrub_io_wall_seconds_total": 4.0,
+    IO_B % "buffered": 2.0**30, IO_F % "buffered": 1024.0,
+    "scrub_verified_bytes_total": 2.0**30,
+}
+LANE_AFTER = {
+    IO_S % "list": 1.25, IO_S % "queue": 2.5, IO_S % "open": 1.5,
+    IO_S % "pread": 14.0, IO_S % "copy": 2.0, IO_S % "inflate": 2.0,
+    IO_S % "other": 1.5,
+    "scrub_io_cpu_seconds_total": 9.0, "scrub_io_wall_seconds_total": 7.0,
+    IO_B % "buffered": 2 * 2.0**30, IO_B % "direct": 3 * 2.0**30,
+    IO_F % "buffered": 2048.0, IO_F % "direct": 3072.0,
+    "scrub_verified_bytes_total": 5 * 2.0**30,
+}
+
+
+@pytest.mark.parametrize("metric, value", [
+    ("scrub_io_lane_ms_per_gib", 3000.0 / 4),           # 3 s over 4 GiB
+    ("scrub_io_queue_ms_per_gib", 500.0 / 4),
+    ("scrub_io_pread_mib_s.scrub", 4 * 1024 / 4.0),     # 4 GiB in 4 s
+    ("scrub_io_open_us.scrub", 0.5e6 / 4096),           # 0.5 s, 4,096 files
+    ("scrub_io_cpu_share.scrub", 100 * 4.0 / 8.0),      # 4 s of the five's 8
+    ("scrub_io_direct_share.scrub", 75.0),              # 3 GiB of 4
+])
+def test_each_lane_metric_file_reads_its_value_and_nothing_without_the_families(
+        metric, value):
+    from benchmarks import harness
+
+    cell = harness.Cell("rep3-1m.scrub")
+    assert metric in {m["name"] for m in cell.per_layer()}
+
+    def window(before, after):
+        return {"before": {"metrics": before, "codec_info": {},
+                           "mono_us": 0},
+                "after": {"metrics": after, "codec_info": {},
+                          "mono_us": 10_000_000},
+                "timeline": []}
+
+    got = cell.read_per_layer(window(LANE_BEFORE, LANE_AFTER))
+    assert got[metric]["value"] == pytest.approx(value)
+    unit = {m["name"]: m["unit"] for m in cell.per_layer()}[metric]
+    assert got[metric]["unit"] == unit
+    # a program without the lane's families (the parent): left out, not 0
+    old = {"scrub_verified_bytes_total": 2.0**30}
+    assert metric not in cell.read_per_layer(
+        window(old, {"scrub_verified_bytes_total": 5 * 2.0**30}))
+    # the families there and nothing read in the window: left out too
+    assert metric not in cell.read_per_layer(window(LANE_AFTER, LANE_AFTER))

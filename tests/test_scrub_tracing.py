@@ -42,8 +42,8 @@ def test_span_records_what_event_would():
     assert by_span["ts"] == sp.t0 // 1000
     assert by_span["dur"] == (sp.t1 - sp.t0) // 1000
     # a per-block section stays out of the ring
-    with tl.span("read file", "scrub-io", record=False):
-        assert innermost_span() == ("read file", tl)
+    with tl.span("put codeword", "scrub", record=False):
+        assert innermost_span() == ("put codeword", tl)
     assert len(tl.snapshot()) == 2
 
 
@@ -210,6 +210,58 @@ async def test_scrub_pass_span_tree_and_exact_sum_account(tmp_path):
     assert seg("heal") > 0 and w.m_passes.get() == 2
     # one event a batch or a pass: nothing here grows with the blocks
     assert len(second) <= 16
+    await shutdown(systems)
+
+
+@pytest.mark.asyncio
+async def test_the_smoke_and_the_report_read_the_lanes_account_off_the_ring(
+        tmp_path):
+    """`chip_smoke.lane_faults` and `scrub_trace_report.lane_account`
+    over the ring of a real pass: the first finds nothing wrong with it
+    and something with a tampered one; the second sums a pass's `read
+    files` events, whose slices' stages are their walls."""
+    import copy
+    import importlib.util
+
+    import chip_smoke
+    from garage_tpu.block import DataBlock
+    from garage_tpu.block.repair import ScrubWorker
+    from tests.test_block import make_block_cluster
+    from tests.test_table import shutdown
+
+    spec = importlib.util.spec_from_file_location(
+        "scrub_trace_report",
+        os.path.join(REPO, "scripts", "scrub_trace_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+
+    systems, (m,) = await make_block_cluster(tmp_path, n=1, mode="1")
+    for i in range(12):
+        d = (b"text that shrinks " * 700 if i % 3 == 0 else b"") + os.urandom(
+            8000 + i)
+        await m.write_block(blake2s_sum(d), DataBlock.from_buffer(d, 1))
+    w = ScrubWorker(m)
+    await _one_pass(w)
+    ring = m.codec.obs.timeline.snapshot()
+    lane = {name: [e for e in ring if e["name"] == name]
+            for name in ("read files", "read slice")}
+    assert len(lane["read files"]) == 1 and len(lane["read slice"]) == 4
+    assert chip_smoke.lane_faults(lane) == []
+    bent = copy.deepcopy(lane)
+    bent["read slice"][0]["args"]["pread_ms"] += 1.0
+    # its wall, and the batch's sum
+    assert len(chip_smoke.lane_faults(bent)) == 2
+    bare = copy.deepcopy(lane)
+    del bare["read files"][0]["args"]["open_ms"]     # a parent's event
+    assert "no account" in chip_smoke.lane_faults(bare)[0]
+    (root,) = [e for e in ring if e["name"] == "scrub pass"]
+    acct = report.lane_account(ring, root)
+    (ev,) = lane["read files"]
+    assert acct["batches"] == 1 and acct["wall_ms"] == ev["dur"] / 1e3
+    assert acct["direct"] + acct["buffered"] == 12
+    assert acct["inflate_ms"] == ev["args"]["inflate_ms"] > 0
+    assert abs(acct["unaccounted_us"]) <= 4.0
+    assert acct["wall_ms"] * 1e3 <= root["dur"]
     await shutdown(systems)
 
 
