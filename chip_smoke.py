@@ -134,11 +134,12 @@ def build_native(smoke: Smoke) -> None:
     from garage_tpu.db import native_adapter
     from garage_tpu.ops import native as nat
 
-    # the three libraries must load; inside them the kernels gate
+    # the four libraries must load; inside them the kernels gate
     # themselves on this host's ISA (GFNI, AVX2/AVX-512) at run time
     libs = {
         "libgf256": nat.get_native_gf_matmul_blocks() is not None,
         "libblake2smb": nat.get_native_blake2s_multi() is not None,
+        "libdirectio": nat.get_native_read_files() is not None,
     }
     try:
         native_adapter._load()
@@ -442,9 +443,7 @@ def lane_faults(lane: dict) -> list:
 
 def judge_pass(smoke: Smoke, label: str, p: dict, scrubbed: int) -> None:
     judge_on_device(smoke, label, p, scrubbed)
-    # the I/O lane accounts for itself (block/repair.py `_read_slice`);
-    # whether its reads were O_DIRECT depends on where the store lives:
-    # reported, not judged
+    # the I/O lane accounts for itself (block/repair.py `_read_slice`)
     reads = {m: sum(b["args"].get(m, 0) for b in p["lane"]["read files"])
              for m in ("direct", "buffered")}
     faults = lane_faults(p["lane"])
@@ -453,6 +452,16 @@ def judge_pass(smoke: Smoke, label: str, p: dict, scrubbed: int) -> None:
                 bool(p["lane"]["read files"]) and not faults,
                 f"reads {reads} in {len(p['lane']['read files'])} batches; "
                 f"{faults[:3]}")
+    # nothing wraps a node's disk here and the library was built above:
+    # every slice is read inside native/directio.cpp, and where the
+    # smoke's stores live every open takes O_DIRECT
+    roads = collections.Counter(
+        s["args"].get("road") for s in p["lane"]["read slice"])
+    smoke.check(f"{label}: every `read slice` event says road native, and "
+                "no read was buffered",
+                bool(roads) and set(roads) == {"native"}
+                and reads["buffered"] == 0,
+                f"slices by road {dict(roads)}; reads {reads}")
     # on one chip a batch under 128 lanes (the pass's tail and its hint)
     # is padded on the device to a row the Pallas kernels tile
     # (TpuCodec.scrub_device_lanes): nothing is left to the XLA scan

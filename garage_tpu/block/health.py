@@ -37,7 +37,7 @@ import logging
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 from ..net.resilience import CircuitBreaker, ResilienceTunables
 from ..utils.direct_io import write_file_direct
@@ -57,6 +57,16 @@ DISK_FAILED_FACTOR = 4
 # quarantine purge policy defaults (config quarantine_max_files/_bytes)
 QUARANTINE_MAX_FILES = 128
 QUARANTINE_MAX_BYTES = 256 << 20
+
+
+def read_or_error(read: Callable[[str], bytes],
+                  path: str) -> Union[bytes, OSError]:
+    """One read of a list's (`DiskIo.read_files_direct`): the bytes, or
+    the OSError it raised."""
+    try:
+        return read(path)
+    except OSError as e:
+        return e
 
 
 class DiskIo:
@@ -82,14 +92,17 @@ class DiskIo:
         # exactly under the load the gauge exists to diagnose
         self._busy_lock = threading.Lock()
 
-    def _note(self, path: str, t0: float) -> None:
-        dt = time.perf_counter() - t0
+    def _root(self, path: str) -> str:
         fn = self.root_of
         try:
             root = fn(path) if fn is not None else ""
         except Exception:  # noqa: BLE001 — accounting must never raise
             root = ""
-        root = root or ""
+        return root or ""
+
+    def _note(self, path: str, t0: float) -> None:
+        dt = time.perf_counter() - t0
+        root = self._root(path)
         with self._busy_lock:
             self.busy_seconds[root] = self.busy_seconds.get(root, 0.0) + dt
 
@@ -111,6 +124,30 @@ class DiskIo:
             return read_file_direct(path)
         finally:
             self._note(path, t0)
+
+    def read_files_direct(
+            self, paths: Sequence[str]) -> List[Union[bytes, OSError]]:
+        """`read_file_direct` of every path, in order: the bytes, or the
+        OSError the single read would have raised.  Read inside native
+        code that never takes the interpreter's lock between two files
+        (utils/direct_io.py `read_files_native`), with the roots' busy
+        seconds noted once for the list; file by file where the library
+        is not there.  A wrapper that injects per read (FaultyDisk)
+        answers this by looping its own single read."""
+        from ..utils.direct_io import read_files_native
+        got = read_files_native(paths)
+        if got is None:
+            return [read_or_error(self.read_file_direct, p) for p in paths]
+        results, spent = got
+        busy: Dict[str, int] = {}
+        for path, ns in zip(paths, spent):
+            root = self._root(path)
+            busy[root] = busy.get(root, 0) + ns
+        with self._busy_lock:
+            for root, ns in busy.items():
+                self.busy_seconds[root] = (
+                    self.busy_seconds.get(root, 0.0) + ns / 1e9)
+        return results
 
     def write_file(self, path: str, data: bytes, fsync: bool = False) -> None:
         t0 = time.perf_counter()

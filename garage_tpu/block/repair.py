@@ -73,11 +73,20 @@ _SCRUB_IO_LOCK = threading.Lock()
 # file done: `queue` until a lane thread takes it, then `open`, `pread`
 # and `copy` inside each read (utils/direct_io.py `ReadAccount`),
 # `inflate` inside zstd, and `other`, the residue: `close`, the health
-# notes, `DiskIo._note`'s lock, the loop over the files, what a
+# notes, the busy seconds' lock, the loop over the files, what a
 # `FaultyDisk` injects.  The stages of a slice sum to its wall exactly.
 # `list` is the listing's one submission, until its result.
 SCRUB_IO_STAGES = ("list", "queue", "open", "pread", "copy", "inflate",
                    "other")
+
+# The road a slice's reads took through the disk seam: `native`, all its
+# files inside native/directio.cpp with the interpreter's lock dropped
+# once for them all (`open`, `pread` and `copy` are then stamped there:
+# `copy` is the native memcpy, made without the lock), or `python`, file
+# by file through `read_file_direct` (a `FaultyDisk`, or a host where
+# the library cannot be built).  What the code can see decides, nothing
+# else: there is no setting.
+SCRUB_IO_ROADS = ("native", "python")
 
 # spans of a manager with no codec observer (unit fakes) go nowhere
 _NO_TIMELINE = Timeline(size=1)
@@ -128,18 +137,22 @@ class _LaneAccount:
     """The I/O lane's account of one slice, or of a batch (its slices
     added up): nanoseconds by `SCRUB_IO_STAGES` on the ring's clock,
     the slices' walls (submission → last file done), the CPU their
-    threads used (`time.thread_time_ns`), and the files read and their
-    bytes by the read's mode (`direct_io.READ_MODES`)."""
+    threads used (`time.thread_time_ns`), the files read and their
+    bytes by the read's mode (`direct_io.READ_MODES`), and the slices by
+    the road their reads took (`SCRUB_IO_ROADS`)."""
 
     def __init__(self):
         self.ns = dict.fromkeys(SCRUB_IO_STAGES, 0)
         self.wall_ns = self.cpu_ns = self.slices = 0
         self.files = dict.fromkeys(READ_MODES, 0)
         self.bytes = dict.fromkeys(READ_MODES, 0)
+        self.roads = dict.fromkeys(SCRUB_IO_ROADS, 0)   # slices by road
 
     def add(self, other: "_LaneAccount") -> None:
         for stage, ns in other.ns.items():
             self.ns[stage] += ns
+        for road, n in other.roads.items():
+            self.roads[road] += n
         self.wall_ns += other.wall_ns
         self.cpu_ns += other.cpu_ns
         self.slices += other.slices
@@ -321,7 +334,7 @@ class ScrubWorker(Worker):
         self.m_read = self.m_inflate_s = self.m_inflate_bytes = None
         self.m_hop_wait = self.m_hints = None
         self.m_io_s = self.m_io_cpu = self.m_io_wall = None
-        self.m_io_bytes = self.m_io_files = None
+        self.m_io_bytes = self.m_io_files = self.m_io_slices = None
         if metrics is not None:
             self.m_segments = metrics.counter(
                 "scrub_pass_seconds_total",
@@ -369,6 +382,12 @@ class ScrubWorker(Worker):
                 "scrub_io_files_total",
                 "Block files the I/O lane read, by the read's mode: the "
                 "files behind scrub_io_bytes_total")
+            self.m_io_slices = metrics.counter(
+                "scrub_io_slices_total",
+                "Slices of the I/O lane's batches by the road their reads "
+                "took: native (all the slice's files inside native code, "
+                "the interpreter's lock dropped once for them) | python "
+                "(file by file: a wrapped disk, or no library)")
             self.m_inflate_s = metrics.counter(
                 "scrub_decompress_seconds_total",
                 "Seconds inside the scrub's zstd decompressions, summed "
@@ -676,6 +695,9 @@ class ScrubWorker(Worker):
             if lane.files[mode]:
                 self.m_io_files.inc(lane.files[mode], mode=mode)
                 self.m_io_bytes.inc(lane.bytes[mode], mode=mode)
+        for road, n in lane.roads.items():
+            if n:
+                self.m_io_slices.inc(n, road=road)
 
     async def scrub_batch(self, batch: List[Tuple[Hash, str, bool]],
                           reads: Optional[list] = None) -> None:
@@ -1084,16 +1106,26 @@ _READ_ERROR = object()
 
 
 def _try_read(mgr, path: str):
-    """Scrub read through the manager's disk seam (DiskIo.
-    read_file_direct: O_DIRECT with buffered fallback — the buffered
-    path is kernel-CPU-bound on 1-core hosts and scrubbing through the
-    page cache evicts the GET path's working set, see
-    utils/direct_io.py).  Returns the bytes; None for a vanished file
-    (deleted concurrently) or a transient resource error (EMFILE-class
-    — skip this pass, the copy is fine); ``_READ_ERROR`` for a media
-    error, after feeding the root's health accounting so a scrub
-    churning through an EIO-ing disk shows up in disk_error_total and
-    the root's breaker instead of staying silently 'ok'.
+    """One scrub read through the manager's disk seam, judged
+    (`_judged`): the bytes, None or ``_READ_ERROR``.  The I/O lane reads
+    a slice's files in one call of the seam instead (`_read_slice`)."""
+    from .health import read_or_error
+
+    return _judged(mgr, path, read_or_error(mgr.disk.read_file_direct, path))
+
+
+def _judged(mgr, path: str, got):
+    """What the scrub makes of one read of the disk seam (DiskIo.
+    read_file_direct / read_files_direct: O_DIRECT with buffered
+    fallback — the buffered path is kernel-CPU-bound on 1-core hosts and
+    scrubbing through the page cache evicts the GET path's working set,
+    see utils/direct_io.py), `got` the bytes or the OSError.  Returns
+    the bytes; None for a vanished file (deleted concurrently) or a
+    transient resource error (EMFILE-class — skip this pass, the copy
+    is fine); ``_READ_ERROR`` for a media error, after feeding the
+    root's health accounting so a scrub churning through an EIO-ing
+    disk shows up in disk_error_total and the root's breaker instead of
+    staying silently 'ok'.
 
     A SUCCESSFUL read reports note_ok: the streak is *consecutive*
     errors, and on an archival node with no client GETs the scrub is
@@ -1102,21 +1134,19 @@ def _try_read(mgr, path: str):
     fundamentally healthy root read-only."""
     from .health import is_media_error
 
-    try:
-        raw = mgr.disk.read_file_direct(path)
-    except FileNotFoundError:
+    if isinstance(got, FileNotFoundError):
         return None
-    except OSError as e:
-        if not is_media_error(e):
+    if isinstance(got, OSError):
+        if not is_media_error(got):
             logger.warning("scrub: transient read error on %s "
-                           "(errno %s: %s)", path, e.errno, e)
+                           "(errno %s: %s)", path, got.errno, got)
             return None
         logger.error("scrub: read of %s failed (errno %s: %s)",
-                     path, e.errno, e)
-        mgr.health.note_error(mgr._root_of(path), "scrub", e)
+                     path, got.errno, got)
+        mgr.health.note_error(mgr._root_of(path), "scrub", got)
         return _READ_ERROR
     mgr.health.note_ok(mgr._root_of(path), "scrub")
-    return raw
+    return got
 
 
 class _Read(NamedTuple):
@@ -1162,12 +1192,14 @@ def _handed_over(raw: bytes, compressed: bool) -> _Read:
 
 
 def _read_slice(mgr, files, submitted_ns: int) -> Tuple[list, _LaneAccount]:
-    """One submission of the lane: the slice's files read in order, each
-    inflated by the thread that read it, and the slice's account
-    (`SCRUB_IO_STAGES`), which is also its `read slice` span on the
-    track `scrub-io`.  `_try_read` through the module's global name,
-    once a file: what wraps it there (the benchmark's
-    `bench:scrub:file_read`) wraps every read."""
+    """One submission of the lane: the slice's files read in ONE call of
+    the disk seam (`DiskIo.read_files_direct`: inside native code that
+    never takes the interpreter's lock between two files, where the
+    library is there and nothing wraps the disk), then each judged as
+    `_try_read` judges a single read and inflated on this thread, in
+    order; and the slice's account (`SCRUB_IO_STAGES`), which is also
+    its `read slice` span on the track `scrub-io` and says which road
+    the reads took (`SCRUB_IO_ROADS`)."""
     timeline = _timeline(mgr)
     acct = _LaneAccount()
     acct.slices = 1
@@ -1178,13 +1210,16 @@ def _read_slice(mgr, files, submitted_ns: int) -> Tuple[list, _LaneAccount]:
                        record=False) as sp:
         cpu0 = time.thread_time_ns()
         with accounting() as rd:
-            for _h, path, compressed in files:
-                raw = _try_read(mgr, path)
+            got = mgr.disk.read_files_direct([path for _h, path, _c in files])
+            for (_h, path, compressed), raw in zip(files, got):
+                raw = _judged(mgr, path, raw)
                 if isinstance(raw, bytes):
                     raw = _handed_over(raw, compressed)
                     acct.ns["inflate"] += raw.inflate_ns
                 out.append(raw)
         acct.cpu_ns = time.thread_time_ns() - cpu0
+    road = "native" if rd.native_calls else "python"
+    acct.roads[road] = 1
     acct.files, acct.bytes = rd.files, rd.bytes
     acct.wall_ns = sp.t1 - submitted_ns
     acct.ns.update(queue=sp.t0 - submitted_ns, open=rd.open_ns,
@@ -1192,7 +1227,8 @@ def _read_slice(mgr, files, submitted_ns: int) -> Tuple[list, _LaneAccount]:
     acct.ns["other"] = acct.wall_ns - sum(acct.ns.values())
     timeline.event("read slice", "scrub-io", sp.t0, sp.t1, cat="scrub",
                    files=len(files), bytes=sum(rd.bytes.values()),
-                   wall_ms=round(acct.wall_ns / 1e6, 3), **acct.args())
+                   wall_ms=round(acct.wall_ns / 1e6, 3), road=road,
+                   **acct.args())
     return out, acct
 
 

@@ -1,9 +1,11 @@
 """ctypes loaders for the native C++ kernels in garage_tpu/native/.
 
-Two kernels live here:
+Three libraries are loaded here:
   - gf256.cpp      → libgf256.so      (AVX2 split-nibble GF(2^8) matmul)
   - blake2s_mb.cpp → libblake2smb.so  (multi-buffer BLAKE2s-256;
     16-lane AVX-512 / 8-lane AVX2, runtime-dispatched inside the kernel)
+  - directio.cpp   → libdirectio.so   (whole-file O_DIRECT reads of many
+    files in one call, for utils/direct_io.py `read_files_native`)
 
 Resolved lazily on first use (not import — short CLI invocations must not
 pay for a compiler run); a failed build is cached on disk against the
@@ -20,7 +22,7 @@ import logging
 import os
 import subprocess
 import threading
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -49,13 +51,14 @@ def _variant(so_name: str):
     """(file to load, make target, files that target writes) for a
     plain library name.  GARAGE_NATIVE_SUFFIX=.asan/.tsan selects the
     sanitizer-instrumented variants (run the tests under the matching
-    LD_PRELOAD — see native/Makefile); those are built all three at once
+    LD_PRELOAD — see native/Makefile); those are built all four at once
     by the PHONY asan/tsan targets, the plain ones by per-.so rules."""
     suffix = os.environ.get("GARAGE_NATIVE_SUFFIX", "")
     if not suffix:
         return so_name, so_name, [so_name]
     return (so_name.replace(".so", f"{suffix}.so"), suffix.lstrip("."),
-            [f"lib{n}{suffix}.so" for n in ("gf256", "logdb", "blake2smb")])
+            [f"lib{n}{suffix}.so"
+             for n in ("gf256", "logdb", "blake2smb", "directio")])
 
 
 def make_so(so_name: str) -> str:
@@ -317,3 +320,94 @@ def get_native_blake2s_rows() -> Optional[Callable]:
 
     _b2_rows_fn = fn
     return _b2_rows_fn
+
+
+# --- whole-file O_DIRECT reads of many files in one call ---
+
+
+class _DioFile(ctypes.Structure):
+    """`struct DioFile` of native/directio.cpp."""
+    _fields_ = [(name, ctypes.c_int64) for name in (
+        "size", "got", "open_ns", "pread_ns", "copy_ns", "rest_ns")] + [
+        (name, ctypes.c_int32) for name in ("fd", "err", "mode", "pad_")]
+
+
+# files a pair of native calls: what bounds the fds a thread holds open
+# between its `dio_open` and its `dio_read`
+DIO_GROUP = 64
+
+_dio_resolved = False
+_dio_fn: Optional[Callable] = None
+# the lane's threads all ask at once, in a process's first batch: the
+# ones that wait must get what the one that builds gets
+_dio_lock = threading.Lock()
+
+
+def get_native_read_files() -> Optional[Callable]:
+    """fn(paths: Sequence[bytes], o_direct: int, chunk: int) → one tuple
+    a path, in order: (the file's bytes or None, errno, whether every
+    chunk came through O_DIRECT, ns of open + fstat, of the reads, of
+    the copy out of the aligned buffer, of the close); None where the
+    library cannot be built (the caller reads file by file in Python).
+
+    Two native calls a group of `DIO_GROUP` paths, each with the
+    interpreter's lock dropped (ctypes `CDLL`): open and fstat them all,
+    then, once Python has made one `bytes` a file of its size, read,
+    copy out and close them all.  The `bytes` are made uninitialised
+    (`PyBytes_FromStringAndSize(NULL, n)`, as a C extension would) and
+    filled by the native code before anything else can see them: no copy
+    of a file's bytes is made under the lock."""
+    global _dio_resolved, _dio_fn
+    if not _dio_resolved:
+        with _dio_lock:
+            if not _dio_resolved:
+                _dio_fn = _resolve_read_files()
+                _dio_resolved = True
+    return _dio_fn
+
+
+def _resolve_read_files() -> Optional[Callable]:
+    lib = _load_or_build("libdirectio.so", "directio.cpp")
+    if lib is None:
+        return None
+    try:
+        paths_t = ctypes.POINTER(ctypes.c_char_p)
+        files_t = ctypes.POINTER(_DioFile)
+        lib.dio_open.argtypes = [paths_t, ctypes.c_int64, ctypes.c_int32,
+                                 files_t]
+        lib.dio_read.argtypes = [paths_t, ctypes.c_int64, ctypes.c_int64,
+                                 paths_t, files_t]
+        lib.dio_close.argtypes = [ctypes.c_int64, files_t]
+        lib.dio_open.restype = lib.dio_read.restype = None
+        lib.dio_close.restype = None
+        new_bytes = ctypes.PYFUNCTYPE(
+            ctypes.py_object, ctypes.c_char_p, ctypes.c_ssize_t)(
+                ("PyBytes_FromStringAndSize", ctypes.pythonapi))
+    except Exception as e:
+        logger.debug("native directio symbol resolution failed: %s", e)
+        return None
+
+    def group(paths: Sequence[bytes], o_direct: int, chunk: int) -> List[Tuple]:
+        n = len(paths)
+        c_paths = (ctypes.c_char_p * n)(*paths)
+        files = (_DioFile * n)()
+        lib.dio_open(c_paths, n, o_direct, files)
+        try:
+            # a failed open has size 0; b"" is shared and never written
+            bufs = [new_bytes(None, f.size) if f.size else b""
+                    for f in files]
+            dest = (ctypes.c_char_p * n)(*bufs)
+        except BaseException:
+            lib.dio_close(n, files)
+            raise
+        lib.dio_read(c_paths, n, chunk, dest, files)
+        return [
+            (None if f.err else buf if f.got == f.size else buf[:f.got],
+             f.err, f.mode == 0, f.open_ns, f.pread_ns, f.copy_ns, f.rest_ns)
+            for f, buf in zip(files, bufs)]
+
+    def fn(paths: Sequence[bytes], o_direct: int, chunk: int) -> List[Tuple]:
+        return [r for lo in range(0, len(paths), DIO_GROUP)
+                for r in group(paths[lo:lo + DIO_GROUP], o_direct, chunk)]
+
+    return fn

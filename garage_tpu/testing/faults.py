@@ -47,7 +47,7 @@ import time
 from types import SimpleNamespace
 from typing import Dict, List, Optional, Tuple
 
-from ..block.health import DiskIo
+from ..block.health import DiskIo, read_or_error
 from ..net.latency_proxy import LatencyProxy
 from ..utils.data import Hash
 
@@ -213,6 +213,12 @@ class FaultyDisk(DiskIo):
         # the scrub worker's O_DIRECT flavor: same fault surface as
         # read_file — a dying disk errors scrubs and GETs alike
         return self._faulted_read(path, self.inner.read_file_direct)
+
+    def read_files_direct(self, paths):
+        # a list is so many single reads here: latency, errors and
+        # bitrot are injected per read, whatever road the inner disk
+        # would take for a list
+        return [read_or_error(self.read_file_direct, p) for p in paths]
 
     def _faulted_read(self, path: str, read) -> bytes:
         if self._applies(path):

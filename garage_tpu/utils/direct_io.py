@@ -31,6 +31,17 @@ returns: a thread that wants to know installs a `ReadAccount`
 stages and its mode there.  The scrub's I/O lane does, a slice at a
 time (block/repair.py `_read_slice`); nothing else pays more than the
 clock reads.
+
+Many files at once: `read_files_native` does the same for a list of
+paths inside native/directio.cpp, under the same contract and into the
+same account.  Every system call of the per-file code is a return to
+the interpreter and so a chance to lose its lock to another thread
+(measured in a scrub pass: 1.2 ms for an `os.open` + `os.fstat`, longer
+than the `preadv` of the file's MiB), and the copy out of the aligned
+buffer is made with the lock held; the native calls drop the lock once
+for all the files.  It is None where the library cannot be built: the
+caller (block/health.py `DiskIo.read_files_direct`) then loops
+`read_file_direct`.
 """
 
 from __future__ import annotations
@@ -40,7 +51,7 @@ import mmap
 import os
 import threading
 import time
-from typing import Iterator, List, Optional, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple, Union
 
 _PAGE = 4096
 _O_DIRECT = getattr(os, "O_DIRECT", 0)
@@ -67,12 +78,15 @@ class ReadAccount:
     `close` between the last two is in none of them.  `files` and
     `bytes` count the reads that came back, by `READ_MODES`: `direct`,
     or `buffered` where the read fell back at the open or mid-file (or
-    the platform has no O_DIRECT)."""
+    the platform has no O_DIRECT).  `native_calls` counts the
+    `read_files_native` calls that added to it: their stages are stamped
+    inside the native code, on the same clock."""
 
-    __slots__ = ("open_ns", "pread_ns", "copy_ns", "files", "bytes")
+    __slots__ = ("open_ns", "pread_ns", "copy_ns", "files", "bytes",
+                 "native_calls")
 
     def __init__(self):
-        self.open_ns = self.pread_ns = self.copy_ns = 0
+        self.open_ns = self.pread_ns = self.copy_ns = self.native_calls = 0
         self.files = dict.fromkeys(READ_MODES, 0)
         self.bytes = dict.fromkeys(READ_MODES, 0)
 
@@ -171,6 +185,44 @@ def read_file_direct(path: str) -> bytes:
         acct.files[mode] += 1
         acct.bytes[mode] += len(data)
     return data
+
+
+def read_files_native(
+        paths: Sequence[str],
+) -> Optional[Tuple[List[Union[bytes, OSError]], List[int]]]:
+    """Whole-file reads of `paths` inside native/directio.cpp, with the
+    interpreter's lock dropped for all of them at once → (one a path, in
+    order: the bytes, or the `OSError` `read_file_direct` would have
+    raised; the nanoseconds each spent in the call), or None where the
+    library is not there.  Stages and modes go to the calling thread's
+    account as `read_file_direct`'s do; a read that failed leaves the
+    time it took and counts no file."""
+    from ..ops.native import get_native_read_files
+
+    native = get_native_read_files()
+    if native is None:
+        return None
+    acct = getattr(_local, "acct", None)
+    if acct is not None:
+        acct.native_calls += 1
+    results: List[Union[bytes, OSError]] = []
+    spent: List[int] = []
+    for path, (data, err, direct, open_ns, pread_ns, copy_ns, rest_ns) in zip(
+            paths, native([os.fsencode(p) for p in paths], _O_DIRECT, _CHUNK)):
+        spent.append(open_ns + pread_ns + copy_ns + rest_ns)
+        if acct is not None:
+            acct.open_ns += open_ns
+            acct.pread_ns += pread_ns
+            acct.copy_ns += copy_ns
+        if err:
+            results.append(OSError(err, os.strerror(err), path))
+            continue
+        if acct is not None:
+            mode = "direct" if direct else "buffered"
+            acct.files[mode] += 1
+            acct.bytes[mode] += len(data)
+        results.append(data)
+    return results, spent
 
 
 def read_file_direct_blocks(path: str, block_size: int) -> List[bytes]:

@@ -6,12 +6,15 @@ queue; the pool is hinted only when the worker is not already waiting
 for the batch.  The lane accounts for itself (ISSUE 41): every slice's
 stages, stamped where the work happens, sum to its wall, a batch's
 counters grow by what its `read files` event says, and each read says
-whether it was O_DIRECT."""
+whether it was O_DIRECT.  A slice's files are read in one call of the
+disk seam (ISSUE 42): inside native code where the library is there and
+nothing wraps the disk, file by file otherwise, with one contract."""
 
 import asyncio
 import base64
 import errno
 import os
+import resource
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -26,6 +29,19 @@ from garage_tpu.testing.faults import FaultyDisk
 from garage_tpu.utils.data import Hash, blake2s_sum
 
 N = 24 * 1024
+
+
+def _without_library(monkeypatch):
+    """A host where native/libdirectio.so cannot be built: the disk seam
+    then reads a list file by file (`DiskIo.read_files_direct`)."""
+    from garage_tpu.ops import native
+
+    monkeypatch.setattr(native, "get_native_read_files", lambda: None)
+
+
+@pytest.fixture
+def no_library(monkeypatch):
+    _without_library(monkeypatch)
 
 
 def _content(i: int) -> bytes:
@@ -179,17 +195,16 @@ async def test_a_hop_starts_at_once_while_a_batch_is_read(tmp_path,
     from tests.test_table import shutdown
 
     systems, m, _contents = await _store(tmp_path, blocks=40)
-    fd = FaultyDisk(m.disk)
+    readers = []
+
+    class Counting(FaultyDisk):
+        def read_file_direct(self, path):
+            readers.append(threading.current_thread().name)
+            return super().read_file_direct(path)
+
+    fd = Counting(m.disk)
     fd.latency = 0.05                   # 40 reads: 0.5 s on the 4 threads
     m.disk = fd
-    readers = []
-    real = repair._try_read
-
-    def counted(mgr, path):
-        readers.append(threading.current_thread().name)
-        return real(mgr, path)
-
-    monkeypatch.setattr(repair, "_try_read", counted)
 
     submitted = []
 
@@ -357,29 +372,64 @@ async def test_a_read_ahead_that_ends_before_the_worker_waits_hints_every_lane(
     await shutdown(systems)
 
 
-# --- (f) what the benchmark's annotation relies on -----------------------------
+# --- (f) every file of a pass goes through the disk seam, once -------------------
 
 
-async def test_the_lane_calls_try_read_through_the_modules_global_name(
-        tmp_path, monkeypatch):
+@pytest.mark.parametrize("why", ["a faulty disk", "no library"])
+async def test_on_the_per_file_road_every_file_goes_through_the_single_read(
+        tmp_path, monkeypatch, why):
     from tests.test_table import shutdown
 
     systems, m, _contents = await _store(tmp_path)
+    if why == "a faulty disk":
+        m.disk = FaultyDisk(m.disk)     # the library is there: not asked
+    else:
+        _without_library(monkeypatch)
     calls = []
-    real = repair._try_read
+    real = m.disk.read_file_direct
     monkeypatch.setattr(
-        repair, "_try_read",
-        lambda mgr, path: (calls.append(path), real(mgr, path))[1])
+        m.disk, "read_file_direct",
+        lambda path: (calls.append(path), real(path))[1])
     w = ScrubWorker(m)
     await _one_pass(w)
     assert sorted(calls) == sorted(p for _h, p, _c in _listing(m))
     assert w.state.corruptions == 0
+    assert w.m_io_slices.get(road="python") == 4
+    assert w.m_io_slices.get(road="native") == 0
+    await shutdown(systems)
+
+
+async def test_on_the_native_road_every_path_reaches_the_batch_read_once(
+        tmp_path, monkeypatch):
+    from garage_tpu.utils import direct_io
+    from tests.test_table import shutdown
+
+    assert direct_io.read_files_native([]) == ([], []), "no libdirectio.so"
+    systems, m, _contents = await _store(tmp_path)
+    lists, singles = [], []
+    real = m.disk.read_files_direct
+    monkeypatch.setattr(
+        m.disk, "read_files_direct",
+        lambda paths: (lists.append(list(paths)), real(paths))[1])
+    monkeypatch.setattr(m.disk, "read_file_direct", singles.append)
+    w = ScrubWorker(m)
+    await _one_pass(w)
+    assert len(lists) == 4 and singles == []        # a call a slice
+    assert (sorted(p for paths in lists for p in paths)
+            == sorted(p for _h, p, _c in _listing(m)))
+    assert w.state.corruptions == 0
+    assert w.m_io_slices.get(road="native") == 4
+    assert w.m_io_slices.get(road="python") == 0
+    roads = [e["args"]["road"] for e in m.codec.obs.timeline.snapshot()
+             if e["name"] == "read slice"]
+    assert roads == ["native"] * 4
     await shutdown(systems)
 
 
 # --- (g) the lane's own account (ISSUE 41) --------------------------------------
 
 ON_THREAD = ("open", "pread", "copy", "inflate", "other")
+OTHER_ROAD = {"native": "python", "python": "native"}
 
 
 def _slice_now(m, files):
@@ -387,10 +437,13 @@ def _slice_now(m, files):
     return repair._read_slice(m, files, time.monotonic_ns())
 
 
+@pytest.mark.parametrize("road", ["native", "python"])
 async def test_a_slices_stages_sum_to_its_wall_and_a_batch_counts_what_its_event_says(
-        tmp_path):
+        tmp_path, monkeypatch, road):
     from tests.test_table import shutdown
 
+    if road == "python":
+        _without_library(monkeypatch)
     systems, m, contents = await _store(tmp_path, parity=True)
     batch = _listing(m)
     t_sub = time.monotonic_ns()
@@ -407,7 +460,8 @@ async def test_a_slices_stages_sum_to_its_wall_and_a_batch_counts_what_its_event
     assert sum(acct.bytes.values()) == sum(r.file_bytes for r in reads)
     (sl,) = [e for e in m.codec.obs.timeline.snapshot()
              if e["name"] == "read slice"]
-    assert sl["args"]["files"] == 5
+    assert sl["args"]["files"] == 5 and sl["args"]["road"] == road
+    assert acct.roads == {road: 1, OTHER_ROAD[road]: 0}
     assert sl["ts"] + sl["dur"] <= time.monotonic_ns() // 1000
     assert abs(sl["args"]["wall_ms"] * 1e6 - acct.wall_ns) < 1e3
 
@@ -451,6 +505,8 @@ async def test_a_slices_stages_sum_to_its_wall_and_a_batch_counts_what_its_event
     for mode in ("direct", "buffered"):
         assert w.m_io_files.get(mode=mode) == sum(
             b["args"][mode] for b in batches)
+    assert w.m_io_slices.get(road=road) == len(slices) == 8
+    assert w.m_io_slices.get(road=OTHER_ROAD[road]) == 0
     assert (w.m_io_bytes.get(mode="direct") + w.m_io_bytes.get(mode="buffered")
             == sum(b["args"]["bytes"] for b in batches)
             == w.m_read.get(form="zst") + w.m_read.get(form="plain"))
@@ -477,7 +533,7 @@ async def test_scrub_batch_on_its_own_counts_the_lane_too(tmp_path):
     await shutdown(systems)
 
 
-async def test_a_slow_preadv_lands_in_pread(tmp_path, monkeypatch):
+async def test_a_slow_preadv_lands_in_pread(tmp_path, monkeypatch, no_library):
     from garage_tpu.utils import direct_io
     from tests.test_table import shutdown
 
@@ -526,9 +582,9 @@ async def test_a_lane_whose_threads_are_held_lands_in_queue(tmp_path):
     ("none", "direct"),
 ])
 async def test_a_read_says_whether_it_was_o_direct(tmp_path, monkeypatch,
-                                                   fault, mode):
+                                                   no_library, fault, mode):
     """Whatever the test directory's filesystem makes of O_DIRECT: the
-    opens are steered here."""
+    opens are steered here, on the per-file road."""
     from garage_tpu.utils import direct_io
     from tests.test_table import shutdown
 
@@ -577,6 +633,7 @@ async def test_a_faulty_disk_still_wraps_the_read_unchanged(tmp_path):
     fd.latency = 0.04
     m.disk = fd
     reads, acct = _slice_now(m, batch)
+    assert acct.roads == {"native": 0, "python": 1}
     assert [r.data for r in reads] == [contents[bytes(h)]
                                        for h, _p, _c in batch]
     # what the wrapper injects is the slice's residue, not the read's
@@ -600,16 +657,210 @@ async def test_a_faulty_disk_still_wraps_the_read_unchanged(tmp_path):
     await shutdown(systems)
 
 
-# --- (h) the six metric files, through the benchmark's own reader ---------------
+# --- (i) the two roads through the disk seam (ISSUE 42) ---------------------------
+
+
+def _fds() -> int:
+    return len(os.listdir("/proc/self/fd"))
+
+
+def _same(a, b) -> bool:
+    """Two reads of one file, but for the time zstd took."""
+    if isinstance(a, _Read) and isinstance(b, _Read):
+        return a._replace(inflate_ns=0) == b._replace(inflate_ns=0)
+    return a is b
+
+
+def _both_roads(monkeypatch, m, files):
+    """→ (reads, account) of one slice on the native road, and of the
+    same slice with the library gone."""
+    got = _slice_now(m, files)
+    with monkeypatch.context() as mp:
+        _without_library(mp)
+        per_file = _slice_now(m, files)
+    assert got[1].roads == {"native": 1, "python": 0}
+    assert per_file[1].roads == {"native": 0, "python": 1}
+    return got, per_file
+
+
+async def test_the_native_road_reads_what_the_per_file_road_reads(
+        tmp_path, monkeypatch):
+    from garage_tpu.utils import direct_io
+    from tests.test_table import shutdown
+
+    systems, m, contents = await _store(tmp_path)
+    # every file of the store is over a chunk, the last chunk short
+    monkeypatch.setattr(direct_io, "_CHUNK", 8192)
+    files = _listing(m)
+    assert any(c for _h, _p, c in files) and not all(c for _h, _p, c in files)
+    root = os.path.dirname(files[0][1])
+    extra = {"empty": b"", "one": b"x", "unaligned": os.urandom(4097),
+             "page": os.urandom(4096)}
+    for name, data in extra.items():
+        with open(os.path.join(root, name), "wb") as f:
+            f.write(data)
+        files.append((Hash(blake2s_sum(data)), os.path.join(root, name),
+                      False))
+    os.remove(files[2][1])                          # one vanished
+    before = _fds()
+    (reads, acct), (reads_pf, acct_pf) = _both_roads(
+        monkeypatch, m, files)
+    assert _fds() == before
+    assert len(reads) == len(files)
+    assert all(_same(a, b) for a, b in zip(reads, reads_pf))    # same order
+    assert reads[2] is None
+    for (h, _p, compressed), r in zip(files[:16], reads):
+        if r is not None:
+            assert r.data == contents[bytes(h)]
+            assert r.inflated == compressed
+    assert [r.data for r in reads[16:]] == list(extra.values())
+    assert acct.files == acct_pf.files and acct.bytes == acct_pf.bytes
+    assert sum(acct.files.values()) == len(files) - 1
+    assert sum(acct.bytes.values()) == sum(r.file_bytes for r in reads if r)
+    for a in (acct, acct_pf):
+        assert sum(a.ns.values()) == a.wall_ns
+        assert all(a.ns[s] > 0 for s in ("open", "pread", "other"))
+    await shutdown(systems)
+
+
+@pytest.mark.parametrize("road", ["native", "python"])
+async def test_a_slice_with_errors_in_it_judges_each_and_leaves_no_fd_open(
+        tmp_path, monkeypatch, road):
+    from tests.test_table import shutdown
+
+    if road == "python":
+        _without_library(monkeypatch)
+    systems, m, contents = await _store(tmp_path)
+    files = _listing(m)[:8]
+    vanished, unreadable = files[1][1], files[5][1]
+    os.remove(vanished)
+    os.remove(unreadable)
+    os.mkdir(unreadable)        # a read of it fails with EISDIR: the media's
+    root = m._root_of(unreadable)
+    for _ in range(3):
+        m.health.note_error(root, "scrub", OSError(errno.EIO, "io"))
+    streak = m.health._streak[m.health._norm(root)]
+    before = _fds()
+    reads, acct = _slice_now(m, files)
+    assert _fds() == before
+    assert acct.roads[road] == 1
+    assert reads[1] is None and reads[5] is _READ_ERROR
+    assert m.health.error_counts[("scrub", "EISDIR")] == 1
+    # in order: the streak grew at the fifth file and the three sound
+    # reads after it walked it back
+    assert streak == 3 and m.health._streak[m.health._norm(root)] == 0
+    assert [r.data for r in reads if isinstance(r, _Read)] == [
+        contents[bytes(h)] for h, p, _c in files
+        if p not in (vanished, unreadable)]
+    assert sum(acct.files.values()) == 6
+    assert sum(acct.ns.values()) == acct.wall_ns
+
+    # no descriptor left for the process: every open is refused, which
+    # blames the process and not the disk, so every copy is skipped
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    highest = max(int(fd) for fd in os.listdir("/proc/self/fd"))
+    errors = dict(m.health.error_counts)
+    held = []
+    resource.setrlimit(resource.RLIMIT_NOFILE, (highest + 1, hard))
+    try:
+        with pytest.raises(OSError) as full:
+            while len(held) <= highest:     # the free numbers below the limit
+                held.append(os.open(os.devnull, os.O_RDONLY))
+        reads, acct = _slice_now(m, files[:4])
+    finally:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (soft, hard))
+        for fd in held:
+            os.close(fd)
+    assert full.value.errno == errno.EMFILE
+    assert reads == [None] * 4 and sum(acct.files.values()) == 0
+    assert m.health.error_counts == errors
+    assert m.health.state(root) == "ok"
+    assert _fds() == before
+    await shutdown(systems)
+
+
+def test_an_eio_is_a_read_error_with_the_roots_health_noted(tmp_path):
+    from garage_tpu.block.health import DiskHealthMonitor, DiskIo
+
+    class Mgr:
+        disk = DiskIo()
+        health = DiskHealthMonitor([str(tmp_path)], watermark=0)
+
+        def _root_of(self, path):
+            return str(tmp_path)
+
+    m = Mgr()
+    path = str(tmp_path / "block")
+    assert repair._judged(m, path, OSError(errno.EIO, "io", path)) is _READ_ERROR
+    assert m.health.error_counts == {("scrub", "EIO"): 1}
+    assert repair._judged(m, path, OSError(errno.EMFILE, "fds", path)) is None
+    assert repair._judged(m, path, FileNotFoundError(2, "gone", path)) is None
+    assert m.health.error_counts == {("scrub", "EIO"): 1}
+    assert m.health._streak[str(tmp_path)] == 1
+    assert repair._judged(m, path, b"sound") == b"sound"
+    assert m.health._streak[str(tmp_path)] == 0
+
+
+@pytest.mark.parametrize("refusal, copied", [
+    # O_DIRECTORY stands in for a filesystem's EINVAL: the open of a
+    # regular file with it fails, and is made again without the flag
+    ("at the open", False),
+    # a descriptor that cannot be read from: every chunk fails, and the
+    # remainder (all of it) goes through a plain descriptor
+    ("mid-file", True),
+])
+async def test_a_filesystem_that_refuses_o_direct_reads_buffered_on_both_roads(
+        tmp_path, monkeypatch, refusal, copied):
+    from garage_tpu.utils import direct_io
+    from tests.test_table import shutdown
+
+    systems, m, contents = await _store(tmp_path)
+    monkeypatch.setattr(
+        direct_io, "_O_DIRECT",
+        os.O_DIRECTORY if refusal == "at the open" else os.O_WRONLY)
+    files = _listing(m)[:6]
+    before = _fds()
+    for reads, acct in _both_roads(monkeypatch, m, files):
+        assert [r.data for r in reads] == [contents[bytes(h)]
+                                           for h, _p, _c in files]
+        assert acct.files == {"direct": 0, "buffered": 6}
+        assert acct.bytes == {"direct": 0, "buffered": sum(
+            r.file_bytes for r in reads)}
+        # a read that never reached the aligned buffer has no copy out of it
+        assert (acct.ns["copy"] > 0) == copied
+        assert sum(acct.ns.values()) == acct.wall_ns
+    assert _fds() == before
+    await shutdown(systems)
+
+
+async def test_a_pass_under_a_faulty_disk_counts_the_python_road(tmp_path):
+    from tests.test_table import shutdown
+
+    systems, m, _contents = await _store(tmp_path)
+    fd = FaultyDisk(m.disk)
+    fd.bitrot_prob = 1.0                # every read of the pass is injected
+    m.disk = fd
+    w = ScrubWorker(m)
+    w._begin_pass()
+    await w.scrub_batch(_listing(m))
+    assert fd.injected["bitrot"] == 16 == w.state.corruptions
+    assert w.m_io_slices.get(road="python") == 4
+    assert w.m_io_slices.get(road="native") == 0
+    await shutdown(systems)
+
+
+# --- (h) the lane's metric files, through the benchmark's own reader ---------------
 
 IO_S = 'scrub_io_seconds_total{stage="%s"}'
 IO_B = 'scrub_io_bytes_total{mode="%s"}'
 IO_F = 'scrub_io_files_total{mode="%s"}'
+IO_R = 'scrub_io_slices_total{road="%s"}'
 LANE_BEFORE = {
     IO_S % "list": 1.0, IO_S % "queue": 2.0, IO_S % "open": 1.0,
     IO_S % "pread": 10.0, IO_S % "copy": 1.0, IO_S % "other": 1.0,
     "scrub_io_cpu_seconds_total": 5.0, "scrub_io_wall_seconds_total": 4.0,
     IO_B % "buffered": 2.0**30, IO_F % "buffered": 1024.0,
+    IO_R % "python": 4.0,
     "scrub_verified_bytes_total": 2.0**30,
 }
 LANE_AFTER = {
@@ -619,6 +870,7 @@ LANE_AFTER = {
     "scrub_io_cpu_seconds_total": 9.0, "scrub_io_wall_seconds_total": 7.0,
     IO_B % "buffered": 2 * 2.0**30, IO_B % "direct": 3 * 2.0**30,
     IO_F % "buffered": 2048.0, IO_F % "direct": 3072.0,
+    IO_R % "python": 8.0, IO_R % "native": 12.0,
     "scrub_verified_bytes_total": 5 * 2.0**30,
 }
 
@@ -630,6 +882,7 @@ LANE_AFTER = {
     ("scrub_io_open_us.scrub", 0.5e6 / 4096),           # 0.5 s, 4,096 files
     ("scrub_io_cpu_share.scrub", 100 * 4.0 / 8.0),      # 4 s of the five's 8
     ("scrub_io_direct_share.scrub", 75.0),              # 3 GiB of 4
+    ("scrub_io_native_share.scrub", 75.0),              # 12 slices of 16
 ])
 def test_each_lane_metric_file_reads_its_value_and_nothing_without_the_families(
         metric, value):
