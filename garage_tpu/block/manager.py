@@ -600,15 +600,17 @@ class BlockManager:
         in its stored form: a block that was `<id>.zst` comes back as
         `<id>.zst`, since nothing compresses a plain file later."""
         block = await self.block_for_storage(content)
-        wrote = await self.write_block(h, block)
+        wrote = await self.write_block(h, block, healed=True)
         if wrote and self.m_heal_stored is not None:
             self.m_heal_stored.inc(
                 form="zst" if block.compressed else "plain")
 
     async def write_block(self, h: Hash, data: DataBlock,
-                          is_parity: bool = False) -> bool:
+                          is_parity: bool = False,
+                          healed: bool = False) -> bool:
         """→ whether a file was written (False: an equal-or-better copy
-        was already there)."""
+        was already there).  `healed`: content rebuilt on this node
+        (`store_rebuilt`), which its write-time codeword is counted to."""
         with self._span("write", h), maybe_time(self.m_write_dur):
             if is_parity and not self.is_parity_block(h):
                 self._parity_marks.insert(bytes(h), b"1")
@@ -623,7 +625,7 @@ class BlockManager:
                 # Parity blocks themselves are excluded — wrapping parity
                 # into further codewords would cascade encode rounds
                 # across the cluster for no durability the decode can use.
-                self.write_parity.add(h, data)
+                self.write_parity.add(h, data, healed=healed)
             return wrote
 
     def _write_block_sync(self, h: Hash, data: DataBlock) -> bool:

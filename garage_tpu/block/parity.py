@@ -39,6 +39,17 @@ logger = logging.getLogger("garage_tpu.block.parity")
 
 MANIFEST_VERSION = 1
 
+# Who wrote a sidecar (`parity_sidecar_written_bytes_total{origin}`): the
+# scrub pass (a codeword of its listing that had none), the write-time
+# accumulator over freshly written blocks, or the accumulator over a
+# codeword more than half of whose members a heal wrote back
+# (`store_rebuilt`; the flush event's `healed` has the count).
+SIDECAR_ORIGINS = ("scrub", "write", "heal")
+# What flushed a write-time codeword: k members, the timer, a second
+# member bound for a node the codeword already has (distributed
+# codewords only), or the shutdown's drain.
+FLUSH_CAUSES = ("full", "timeout", "node", "drain")
+
 
 class ParityStore:
     def __init__(self, manager, db, codec):
@@ -65,6 +76,27 @@ class ParityStore:
             "Bytes of the codewords put to the parity store, written or "
             "found and refreshed: part=parity is m x the longest member, "
             "part=covered the members' own lengths")
+        self.m_written_bytes = self.m_written = self.m_purged = None
+        if metrics is not None:
+            self.m_written_bytes = metrics.counter(
+                "parity_sidecar_written_bytes_total",
+                "Parity bytes (m x the longest member) of the sidecars "
+                "WRITTEN, one found and touched not counted, by who wrote "
+                "them: scrub | write (the write-time accumulator) | heal "
+                "(the accumulator, most members written back by heals)")
+            self.m_written = metrics.counter(
+                "parity_codewords_written_total",
+                "Sidecars written, by origin: the files behind "
+                "parity_sidecar_written_bytes_total")
+            self.m_purged = metrics.counter(
+                "parity_purged_sidecars_total",
+                "Sidecars the scrub's purge removed: refreshed by "
+                "neither the pass that ended nor the one before it")
+            for origin in SIDECAR_ORIGINS:      # every series from 0
+                self.m_written.inc(0, origin=origin)
+                self.m_written_bytes.inc(0, origin=origin)
+        # what the last purge did, for the pass's `purge stale` event
+        self.last_purge = {"removed": 0, "dead": 0}
 
     # --- write path (scrub) ------------------------------------------------
 
@@ -100,6 +132,7 @@ class ParityStore:
         hashes: Sequence[Hash],
         lengths: Sequence[int],
         parity: np.ndarray,
+        origin: str = "scrub",
     ) -> bool:
         """Persist one codeword's parity: `hashes`/`lengths` are the j ≤ k
         member blocks in codeword order, `parity` is (m, maxlen) uint8
@@ -111,18 +144,15 @@ class ParityStore:
         shards as always-available pieces.  Called by the scrub worker
         (full rows whose members all verified and whose sidecar
         `rows_lacking_sidecar` did not find) and the write-path
-        accumulator (possibly partial).  → whether a sidecar was
-        written (False: one with this content was there and got a fresh
-        mtime)."""
+        accumulator (possibly partial; `origin` write or heal).  →
+        whether a sidecar was written (False: one with this content was
+        there and got a fresh mtime)."""
         # one call a codeword: in the profiler's trace, not in the ring,
         # where the scrub batch's `parity write` event stands
         with self.codec.obs.timeline.span("put codeword", "scrub-io",
                                           cat="scrub", record=False):
-            return self._put_codeword(hashes, lengths, parity)
-
-    def _put_codeword(self, hashes, lengths, parity) -> bool:
-        return self._file(hashes, lengths, int(parity.shape[0]),
-                          lambda: parity)
+            return self._file(hashes, lengths, int(parity.shape[0]),
+                              lambda: parity, origin)
 
     def rows_lacking_sidecar(self, hashes: Sequence[Hash]) -> List[int]:
         """Which of a scrub batch's codewords have no sidecar: `hashes`
@@ -154,13 +184,15 @@ class ParityStore:
             for hashes, blocks in rows:
                 written += self._file(
                     hashes, [len(b) for b in blocks], m,
-                    lambda: self.codec.rs_encode_blocks(blocks)[0])
+                    lambda: self.codec.rs_encode_blocks(blocks)[0], "scrub")
         return len(rows) - written, written
 
-    def _file(self, hashes, lengths, m: int, parity_of) -> bool:
+    def _file(self, hashes, lengths, m: int, parity_of,
+              origin: str) -> bool:
         """One codeword filed: counted, its sidecar touched — or, where
-        it has none, written from `parity_of()`, (m, maxlen) — and its
-        members indexed.  → whether it was written."""
+        it has none, written from `parity_of()`, (m, maxlen), and
+        counted to `origin` — and its members indexed.  → whether it
+        was written."""
         k = self.codec.params.rs_data
         assert 0 < len(hashes) <= k, (len(hashes), k)
         if self.m_bytes is not None:
@@ -200,6 +232,9 @@ class ParityStore:
             with open(tmp, "wb") as f:
                 f.write(msgpack.packb(manifest, use_bin_type=True))
             os.replace(tmp, path)
+            if self.m_written is not None:
+                self.m_written.inc(origin=origin)
+                self.m_written_bytes.inc(int(parity.nbytes), origin=origin)
         for h in hashes:
             self.index.insert(bytes(h), gid)
         return existing is None
@@ -380,6 +415,9 @@ class ParityStore:
         ]
         for k in dead:
             self.index.remove(k)
+        self.last_purge = {"removed": removed, "dead": len(dead)}
+        if self.m_purged is not None and removed:
+            self.m_purged.inc(removed)
         if removed or dead:
             logger.info("parity purge: %d stale sidecars, %d index entries",
                         removed, len(dead))
@@ -613,10 +651,21 @@ class WriteParityAccumulator:
         self.distributor = distributor
         self.manager = manager if manager is not None else (
             store.manager if store is not None else None)
-        self._pending: List[tuple] = []  # (hash, DataBlock)
+        self._pending: List[tuple] = []  # (hash, DataBlock, healed?)
         self._pending_nodes: set = set()  # primary data node per member
         self._timer: Optional[object] = None  # asyncio.TimerHandle
         self._tasks: set = set()
+        self._flushed = asyncio.Event()   # set by every flush (settled)
+        metrics = getattr(getattr(self.manager, "system", None), "metrics",
+                          None)
+        self.m_flushes = None if metrics is None else metrics.counter(
+            "write_parity_flushes_total",
+            "Write-time codewords flushed to their encode, by cause: "
+            "full (k members) | timeout (the flush timer) | node (a "
+            "second member bound for one node) | drain (shutdown)")
+        if self.m_flushes is not None:
+            for cause in FLUSH_CAUSES:          # every series from 0
+                self.m_flushes.inc(0, cause=cause)
         # writer-side re-PUT dedup: an OrderedDict-as-LRU of hashes this
         # writer recently wrapped into codewords (bounded; cross-writer
         # repeats still duplicate, which the ref-driven GC cleans up)
@@ -629,10 +678,12 @@ class WriteParityAccumulator:
     def recently_added(self, h: Hash) -> bool:
         return bytes(h) in self._recent
 
-    def add(self, h: Hash, block: "DataBlock") -> None:
-        """Register a freshly-written block.  Event loop only; the block
-        is held as stored (possibly compressed) and decompressed on the
-        encode thread, so the write path pays nothing."""
+    def add(self, h: Hash, block: "DataBlock", healed: bool = False) -> None:
+        """Register a freshly-written block (`healed`: written back by a
+        heal, which its codeword's sidecar is counted to).  Event loop
+        only; the block is held as stored (possibly compressed) and
+        decompressed on the encode thread, so the write path pays
+        nothing."""
         k = self.codec.params.rs_data
         if k <= 0:
             return
@@ -645,16 +696,17 @@ class WriteParityAccumulator:
             nodes = self.manager.replication.write_nodes(h)
             node = bytes(nodes[0]) if nodes else b""
             if node in self._pending_nodes:
-                self._flush()
+                self._flush("node")
             self._pending_nodes.add(node)
-        self._pending.append((h, block))
+        self._pending.append((h, block, healed))
         if len(self._pending) >= k:
-            self._flush()
+            self._flush("full")
         elif self._timer is None:
             loop = asyncio.get_running_loop()
-            self._timer = loop.call_later(self.flush_after, self._flush)
+            self._timer = loop.call_later(self.flush_after, self._flush,
+                                          "timeout")
 
-    def _flush(self) -> None:
+    def _flush(self, cause: str) -> None:
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
@@ -662,19 +714,26 @@ class WriteParityAccumulator:
             return
         group, self._pending = self._pending, []
         self._pending_nodes = set()
+        if self.m_flushes is not None:
+            self.m_flushes.inc(cause=cause)
         task = asyncio.get_running_loop().create_task(
-            self._encode_and_store(group)
+            self._encode_and_store(group, cause)
         )
         # keep a strong ref (create_task results are weakly held)
         self._tasks.add(task)
         task.add_done_callback(self._tasks.discard)
+        self._flushed.set()
 
-    async def _encode_and_store(self, group: List[tuple]) -> None:
+    async def _encode_and_store(self, group: List[tuple],
+                                cause: str) -> None:
         try:
-            hashes = [h for h, _ in group]
+            hashes = [h for h, _b, _healed in group]
+            healed = sum(healed for _h, _b, healed in group)
+            origin = "heal" if 2 * healed > len(group) else "write"
+            k = self.codec.params.rs_data
 
             def encode_and_store():
-                blocks = [b.decompressed() for _, b in group]
+                blocks = [b.decompressed() for _h, b, _healed in group]
                 # rs_encode_blocks zero-pads the member count to a whole
                 # codeword — exactly the partial-codeword zero-shard
                 # semantics.  Via the codec feeder when the manager has
@@ -689,19 +748,41 @@ class WriteParityAccumulator:
                     parity = self.codec.rs_encode_blocks(blocks)
                 if self.store is not None:
                     self.store.put_codeword(
-                        hashes, [len(b) for b in blocks], parity[0])
+                        hashes, [len(b) for b in blocks], parity[0], origin)
                 return parity[0], [len(b) for b in blocks]
 
-            parity_row, lengths = await asyncio.to_thread(encode_and_store)
+            def flush():
+                # the flush as one section of the encode thread: in the
+                # ring (track `write-parity`) and, under `gt:`, in a
+                # profile, from the first inflate to the sidecar filed
+                with self.codec.obs.timeline.span(
+                        "write parity flush", "write-parity", cat="parity",
+                        members=len(group), partial=len(group) < k,
+                        cause=cause, healed=healed):
+                    return encode_and_store()
+
+            parity_row, lengths = await asyncio.to_thread(flush)
             self.codewords_encoded += 1
             if self.distributor is not None:
                 await self.distributor.distribute(hashes, lengths, parity_row)
         except Exception:  # noqa: BLE001 — write-path parity is best-effort
             logger.exception("write-time parity encode failed")
 
+    async def settled(self) -> None:
+        """Until no block is pending and no encode is in flight.  A
+        partial codeword is waited for, its timer's flush and the encode
+        after it, not forced (`drain` forces it)."""
+        while self._pending or self._tasks:
+            if self._tasks:
+                await asyncio.gather(*list(self._tasks),
+                                     return_exceptions=True)
+            else:
+                self._flushed.clear()
+                await self._flushed.wait()
+
     async def drain(self) -> None:
         """Flush the partial codeword and wait for in-flight encodes
         (shutdown path — a clean stop must not lose the tail)."""
-        self._flush()
+        self._flush("drain")
         while self._tasks:
             await asyncio.gather(*list(self._tasks), return_exceptions=True)

@@ -124,6 +124,9 @@ class _PassAccount:
         self.ns = dict.fromkeys(SCRUB_SEGMENTS, 0)
         self.flushed = dict.fromkeys(SCRUB_SEGMENTS, 0)
         self.blocks = self.bytes = self.batches = 0
+        # the pass's codewords: those asked of the parity store, those
+        # of them that had no sidecar, and the sidecars its purge removed
+        self.rows = self.rows_lacking = self.purged = 0
 
     def mark(self, segment: str) -> Tuple[int, int]:
         """→ (the last stamp, now): the interval now owned by `segment`."""
@@ -525,6 +528,8 @@ class ScrubWorker(Worker):
         _timeline(self.manager).event(
             "scrub pass", "scrub", acct.t0, acct.last, cat="scrub",
             blocks=acct.blocks, bytes=acct.bytes, batches=acct.batches,
+            rows=acct.rows, rows_lacking=acct.rows_lacking,
+            purged=acct.purged,
             corruptions=self.state.corruptions, resumed=acct.resumed,
             **{f"{seg}_ms": round(ns / 1e6, 3)
                for seg, ns in acct.ns.items() if ns})
@@ -599,10 +604,10 @@ class ScrubWorker(Worker):
                 # refreshed by NEITHER this pass nor the previous one,
                 # else orphans accumulate forever (one-pass grace keeps
                 # coverage for rows that failed verify this pass)
-                removed = await self._hop(
-                    "purge", self.manager.parity_store.purge_stale,
-                    self._prev_pass_start)
-                self._segment("purge", "purge stale", removed=removed)
+                store = self.manager.parity_store
+                self._acct.purged = await self._hop(
+                    "purge", store.purge_stale, self._prev_pass_start)
+                self._segment("purge", "purge stale", **store.last_purge)
             self._checkpoint(force=True)
             self._segment("checkpoint", "checkpoint")
             self._end_pass()
@@ -782,6 +787,9 @@ class ScrubWorker(Worker):
                     "parity_write", store.rows_lacking_sidecar, all_h)
                 self._segment("parity_write", "parity ask",
                               rows=len(all_h) // k, lacking=len(want_parity))
+                if self._acct is not None:
+                    self._acct.rows += len(all_h) // k
+                    self._acct.rows_lacking += len(want_parity)
             nbytes = sum(len(b) for b in plain_blocks)
             if self._acct is not None:
                 self._acct.batches += 1

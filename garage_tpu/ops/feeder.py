@@ -627,7 +627,8 @@ class CodecFeeder:
                             [it.payload for it in items])
                 if kind != "mhash":
                     kside = self._answered(kside)
-                self.obs.add_bytes(kside, sum(it.nbytes for it in items))
+                self.obs.add_bytes(kside, sum(it.nbytes for it in items),
+                                   kind)
             except BaseException as e:  # noqa: BLE001 — fan the error out
                 for it in items:
                     if not it.future.done():
@@ -694,7 +695,7 @@ class CodecFeeder:
                     [(it.payload[0], it.payload[1], it.want_parity)
                      for it in batch])
             self.obs.add_bytes(self._answered(side),
-                               sum(it.nbytes for it in batch))
+                               sum(it.nbytes for it in batch), "scrub")
         except BaseException as e:  # noqa: BLE001 — fan the error out
             for it in batch:
                 if not it.future.done():

@@ -467,7 +467,9 @@ class HybridCodec(BlockCodec):
             out = getattr(self.cpu, call)(*args)
         self._answered.side = side
         if nbytes:
-            self.obs.add_bytes(side, nbytes)
+            # the bytes-level calls that count here are a background
+            # caller's verify or fused scrub with no feeder in front
+            self.obs.add_bytes(side, nbytes, "scrub")
         return out
 
     def answered_side(self) -> str:

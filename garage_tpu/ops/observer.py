@@ -13,7 +13,8 @@ observer holding:
     attribution model of the degraded-read / erasure-coding literature
     (arXiv:2306.10528, arXiv:2108.02692);
   - bytes-by-side counters (`codec_bytes_total{side=}`) so tpu_frac is a
-    scrapeable ratio, not a bench-polled tuple;
+    scrapeable ratio, not a bench-polled tuple, and the same bytes by the
+    kind of work they were (`codec_work_bytes_total{kind=,side=}`);
   - a bounded, timestamped **gate-decision event ring**: every link
     probe, gate open/hold, fused-kernel demotion, transport error and
     sync failure lands here with a reason label, served by the admin
@@ -49,6 +50,13 @@ STAGES = (
 )
 
 EVENT_RING_SIZE = 256
+
+# What kind of work the codec's bytes were (`codec_work_bytes_total`):
+# scrub (the fused verify + re-encode of the scrub and resync producers,
+# and a bytes-level batch_verify), hash (block ids of a PUT, the read
+# path's verify), encode (write-time parity), decode (a rebuild from
+# parity), mhash (the table engine's Merkle hashing, CPU-only).
+BYTE_KINDS = ("scrub", "hash", "encode", "decode", "mhash")
 
 
 class _StageTimer:
@@ -109,6 +117,12 @@ class CodecObserver:
                 "Block bytes processed by the codec, by side "
                 "(tpu_frac = tpu / (cpu + tpu))",
             )
+            self._work_ctr = metrics.counter(
+                "codec_work_bytes_total",
+                "The bytes of codec_bytes_total by the kind of work they "
+                "were (scrub | hash | encode | decode | mhash) and by "
+                "side: the kinds of a side sum to codec_bytes_total's",
+            )
             self._event_ctr = metrics.counter(
                 "codec_gate_events_total",
                 "Gate-decision/demotion events by kind and reason",
@@ -147,6 +161,7 @@ class CodecObserver:
             )
         else:
             self._hist = self._bytes_ctr = self._event_ctr = None
+            self._work_ctr = None
             self._substage_s = self._substage_n = None
             self._pool_programs = None
             self._compile_n = self._compile_s = None
@@ -219,11 +234,13 @@ class CodecObserver:
 
     # --- bytes ---
 
-    def add_bytes(self, side: str, n: int) -> None:
+    def add_bytes(self, side: str, n: int, kind: str) -> None:
+        """`n` bytes of `kind` work (`BYTE_KINDS`) ran on `side`."""
         with self._lock:
             self.bytes_total[side] = self.bytes_total.get(side, 0) + n
         if self._bytes_ctr is not None:
             self._bytes_ctr.inc(n, side=side)
+            self._work_ctr.inc(n, kind=kind, side=side)
 
     def tpu_frac(self) -> float:
         with self._lock:

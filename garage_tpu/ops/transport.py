@@ -952,7 +952,7 @@ class DeviceTransport:
             except Exception:
                 logger.warning("note_sync_success hook failed",
                                exc_info=True)
-        self.obs.add_bytes("tpu", batch.nbytes)
+        self.obs.add_bytes("tpu", batch.nbytes, batch.kind)
         tl = self.obs.timeline
         track = batch.track
         if batch.t_submit1 and batch.t_ready > batch.t_submit1:
@@ -1453,7 +1453,7 @@ class DeviceTransport:
                     sub = shards[part.lo:part.hi]
                     res = cpu.rs_reconstruct(sub, present, rws)
                     nbytes = int(sub.nbytes)
-                self.obs.add_bytes("cpu", nbytes)
+                self.obs.add_bytes("cpu", nbytes, batch.kind)
                 part.sink.deliver(part.index, res)
             except BaseException as e:  # noqa: BLE001
                 part.sink.fail(e)
@@ -1483,7 +1483,8 @@ class DeviceTransport:
             digs = [Hash(d) for d in raw]
             for part, (o, n) in zip(batch.parts, spans):
                 self.obs.add_bytes(
-                    "cpu", int(sum(int(x) for x in lengths[o:o + n])))
+                    "cpu", int(sum(int(x) for x in lengths[o:o + n])),
+                    "hash")
                 part.sink.deliver(part.index, digs[o:o + n])
             return True
         except BaseException:  # noqa: BLE001 — fall back to payloads

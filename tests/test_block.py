@@ -595,11 +595,10 @@ async def test_write_time_parity(tmp_path):
     hs = [blake2s_sum(d) for d in datas]
     for h, d in zip(hs, datas):
         await m.write_block(h, DataBlock.from_buffer(d, 3))
-    # k-th write triggers the flush; encode runs async — wait for it
-    for _ in range(100):
-        if m.parity_store.coverage(hs[0]):
-            break
-        await asyncio.sleep(0.02)
+    # k-th write triggers the flush; encode runs async — wait for it,
+    # the accumulator's own flush and not the clock: six busy workers
+    # can hold an encode thread back past any poll
+    await m.write_parity.settled()
     assert all(m.parity_store.coverage(h) for h in hs), \
         "full codeword must be covered right after the k-th write, no scrub"
 
@@ -617,10 +616,7 @@ async def test_write_time_parity(tmp_path):
     sh = [blake2s_sum(d) for d in small]
     for h, d in zip(sh, small):
         await m.write_block(h, DataBlock.plain(d))
-    for _ in range(200):
-        if m.parity_store.coverage(sh[0]):
-            break
-        await asyncio.sleep(0.02)
+    await m.write_parity.settled()
     assert m.parity_store.coverage(sh[0]) and m.parity_store.coverage(sh[1])
     # delete one member: reconstruction uses the survivor + zero shards
     p2, _ = m.find_block(sh[1])
