@@ -8,18 +8,67 @@ computes RS(k, m) parity over each codeword of k blocks — this module
 persists that parity as a local sidecar so a corrupted or lost block can
 be **reconstructed on this node alone**, with zero network, as long as
 ≥ k of the codeword's k+m pieces survive.  Network resync remains the
-fallback; the sidecar is a best-effort cache refreshed on every scrub
-pass.
+fallback.
 
 Layout: one msgpack manifest per codeword under
 `<data_dir>/parity/xx/<group_id>.par` (group_id = blake2s over the
-member hashes), plus a small db tree mapping block hash → group file so
-repair can find a block's codeword in O(1).  Data shards are the member
-blocks themselves (zero-padded to the codeword width), read back from
-the block store and re-verified by content hash at reconstruction time;
-parity shards carry their own checksums.  Any mismatch disqualifies the
-piece — reconstruction either produces a block whose hash matches, or
-fails loudly and the caller falls back to the network.
+member hashes), plus a small db tree, `block_parity_index`, mapping
+block hash → group id so repair can find a block's codeword in O(1).
+Data shards are the member blocks themselves (zero-padded to the
+codeword width), read back from the block store and re-verified by
+content hash at reconstruction time; parity shards carry their own
+checksums.  Any mismatch disqualifies the piece — reconstruction either
+produces a block whose hash matches, or fails loudly and the caller
+falls back to the network.
+
+**The index is the record of membership.**  A codeword keeps its members
+from pass to pass: a block's place in a pass's listing decides nothing
+once the index names its codeword.  An entry is the group id, followed,
+for a codeword the scrub formed (k members, in id order), by the (k, m)
+it was formed at; an entry of the bare 32 bytes names a codeword that
+is the block's cover but not its place: a write-time codeword (members
+in arrival order, possibly fewer than k), one filed by code before the
+(k, m) suffix, or one the scrub dissolved.  What a pass does with each
+block it reads (`ScrubMembership`, asked once a batch, off the loop):
+
+1. *Settled*: the entry names a scrub codeword of this (k, m) whose
+   sidecar is on disk.  The block stays in it.  The file gets a fresh
+   mtime once a pass (what the purge keys on); nothing is hashed into a
+   group id, nothing is written to the index, no parity leaves the
+   device.
+2. *Its sidecar is gone, its members are not*: the same k members are
+   encoded again and the file comes back under the same name.  Members
+   read in an earlier batch are held, at most a batch of them, until
+   the last has been read.
+3. *Free*: no entry, or a bare one.  Free blocks are grouped k at a
+   time in listing order, with a carry across batches; a store without
+   sidecars forms the codewords a listing read k at a time gives.  A
+   batch goes to the device with its own lanes and no other, so that no
+   program is compiled for a lane count the listing does not have: a
+   row whose members were verified in two batches (rule 2's held ones,
+   the carry's) is encoded on the host, where the write-time codewords
+   are.  A
+   bare entry's sidecar is kept fresh while its block waits for a full
+   row, then left to the purge: the block is covered all the way.  k
+   blocks of one batch whose bare entries name the group id of exactly
+   those k in id order are an intact scrub codeword and are adopted as
+   settled (how an index from before the suffix converges).
+4. *A codeword that lost a member for good* (a whole pass read fewer
+   than k of them, and the store has none of those it did not read: a
+   read that failed this once is not a loss) is dissolved at the pass's
+   end: its survivors' entries become bare, the next pass regroups
+   them, and the old file ages out under the purge's grace.
+5. *A full codeword is not robbed by a later one*: filing a write-time
+   codeword (a PUT, a heal's `store_rebuilt`) leaves alone an entry
+   that names a scrub codeword of this (k, m); the newer sidecar is
+   extra parity the purge collects.
+
+The purge's grace of one pass (`purge_stale`) protects: a settled
+sidecar whose block failed this pass's verify (touched before the
+verdict, and again by the heal), the cover of a free block that has not
+met k − 1 others yet, and a dissolved codeword until its survivors are
+regrouped.  Its prune of index entries whose sidecar is gone spares a
+scrub codeword's while the block is in the store (rule 2 needs them).
 """
 
 from __future__ import annotations
@@ -27,6 +76,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 from typing import List, Optional, Sequence, Tuple
 
 import msgpack
@@ -49,6 +99,25 @@ SIDECAR_ORIGINS = ("scrub", "write", "heal")
 # member bound for a node the codeword already has (distributed
 # codewords only), or the shutdown's drain.
 FLUSH_CAUSES = ("full", "timeout", "node", "drain")
+# What a scrub pass did with a codeword (`scrub_codewords_total{state}`):
+# found it whole with its sidecar on disk, wrote its lost sidecar again
+# under the same name, formed it from free blocks, or dissolved it
+# because a whole pass read fewer than k of its members.
+CODEWORD_STATES = ("settled", "rewritten", "formed", "dissolved")
+GID_LEN = 32
+
+
+def encode_codeword(manager, codec, blocks: Sequence[bytes]) -> np.ndarray:
+    """Parity of one codeword's plain members, (m, maxlen), off the
+    scrub's fused road: rs_encode_blocks zero-pads the member count to a
+    whole codeword — exactly the partial-codeword zero-shard semantics.
+    Via the codec feeder when the manager has one: concurrent codewords
+    (every in-flight PUT under parity_on_write) coalesce into one ragged
+    pointer-gather/device pass instead of one GF call each."""
+    feeder = getattr(manager, "feeder", None)
+    if feeder is not None and feeder.codec is codec:
+        return feeder.encode_or_direct(blocks)[0]
+    return codec.rs_encode_blocks(blocks)[0]
 
 
 class ParityStore:
@@ -77,6 +146,7 @@ class ParityStore:
             "found and refreshed: part=parity is m x the longest member, "
             "part=covered the members' own lengths")
         self.m_written_bytes = self.m_written = self.m_purged = None
+        self.m_codewords = None
         if metrics is not None:
             self.m_written_bytes = metrics.counter(
                 "parity_sidecar_written_bytes_total",
@@ -92,9 +162,18 @@ class ParityStore:
                 "parity_purged_sidecars_total",
                 "Sidecars the scrub's purge removed: refreshed by "
                 "neither the pass that ended nor the one before it")
+            self.m_codewords = metrics.counter(
+                "scrub_codewords_total",
+                "Codewords a scrub pass accounted for, by what it did with "
+                "them: settled (members and sidecar as the index says) | "
+                "rewritten (the same members, the lost sidecar back under "
+                "its name) | formed (from free blocks) | dissolved (a "
+                "whole pass read fewer than k members)")
             for origin in SIDECAR_ORIGINS:      # every series from 0
                 self.m_written.inc(0, origin=origin)
                 self.m_written_bytes.inc(0, origin=origin)
+            for state in CODEWORD_STATES:
+                self.m_codewords.inc(0, state=state)
         # what the last purge did, for the pass's `purge stale` event
         self.last_purge = {"removed": 0, "dead": 0}
 
@@ -118,14 +197,15 @@ class ParityStore:
         hx = gid.hex()
         return os.path.join(self.dir, hx[:2], hx + ".par")
 
-    def _find_group_path(self, gid: bytes) -> Optional[str]:
-        """Read location: search every data dir's parity tree."""
+    def _group_paths(self, gid: bytes):
+        """Where a group's sidecar may be: every data dir's parity tree."""
         hx = gid.hex()
-        for base in self.all_dirs:
-            p = os.path.join(base, hx[:2], hx + ".par")
-            if os.path.exists(p):
-                return p
-        return None
+        return (os.path.join(base, hx[:2], hx + ".par")
+                for base in self.all_dirs)
+
+    def _find_group_path(self, gid: bytes) -> Optional[str]:
+        """Read location: the first of them that is there."""
+        return next(filter(os.path.exists, self._group_paths(gid)), None)
 
     def put_codeword(
         self,
@@ -142,79 +222,81 @@ class ParityStore:
         GF-linear, so the parity is identical to a k-member codeword
         whose tail members are zero, and reconstruction counts the zero
         shards as always-available pieces.  Called by the scrub worker
-        (full rows whose members all verified and whose sidecar
-        `rows_lacking_sidecar` did not find) and the write-path
-        accumulator (possibly partial; `origin` write or heal).  →
+        (the rows `ScrubMembership.plan` asked parity for, whose
+        members all verified) and the write-path accumulator (possibly
+        partial; `origin` write or heal).  →
         whether a sidecar was written (False: one with this content was
         there and got a fresh mtime)."""
         # one call a codeword: in the profiler's trace, not in the ring,
         # where the scrub batch's `parity write` event stands
         with self.codec.obs.timeline.span("put codeword", "scrub-io",
                                           cat="scrub", record=False):
-            return self._file(hashes, lengths, int(parity.shape[0]),
-                              lambda: parity, origin)
+            return self._file(hashes, lengths, parity, origin)
 
-    def rows_lacking_sidecar(self, hashes: Sequence[Hash]) -> List[int]:
-        """Which of a scrub batch's codewords have no sidecar: `hashes`
-        are the batch's members in order (carry first), row r the k of
-        them from r·k; a trailing partial row is no codeword yet and is
-        never named.  The rows returned are those whose parity has to
-        leave the device; every other row's sidecar is on disk, with the
-        content this (k, m) would give it again (`_gid`), in one of the
-        data dirs.  No I/O but os.path.exists; call it off the loop."""
-        k, m = self.codec.params.rs_data, self.codec.params.rs_parity
-        return [
-            r for r in range(len(hashes) // k)
-            if self._find_group_path(
-                bytes(self._gid(k, m, hashes[r * k:(r + 1) * k]))) is None]
+    def put_straddler(self, hashes: Sequence[Hash],
+                      blocks: Sequence[bytes]) -> bool:
+        """A scrub codeword whose members were verified in two batches:
+        encoded here, where the write-time codewords are (a shape the
+        PUT path has met), and put.  → whether it was written."""
+        return self.put_codeword(
+            hashes, [len(b) for b in blocks],
+            encode_codeword(self.manager, self.codec, blocks))
 
-    def refresh_codewords(self, rows: Sequence[tuple]) -> Tuple[int, int]:
-        """File, in one call, a batch's verified codewords whose sidecar
-        was on disk when `rows_lacking_sidecar` was asked: `rows` holds
-        (member hashes, member blocks) a codeword.  Each is touched,
-        counted and indexed as `put_codeword` does a codeword it finds —
-        without its parity, which stayed on the device.  A file that is
-        gone by now (a purge, an operator) is encoded here and written,
-        so that its codeword does not wait a pass for its sidecar.
-        → (touched, written)."""
-        m = self.codec.params.rs_parity
-        written = 0
-        with self.codec.obs.timeline.span("refresh codewords", "scrub-io",
-                                          cat="scrub", record=False):
-            for hashes, blocks in rows:
-                written += self._file(
-                    hashes, [len(b) for b in blocks], m,
-                    lambda: self.codec.rs_encode_blocks(blocks)[0], "scrub")
-        return len(rows) - written, written
+    def begin_pass(self, whole: bool) -> "ScrubMembership":
+        """What one scrub pass knows of the codewords it meets.  `whole`:
+        the pass reads the store from its first block in this process,
+        so a codeword it saw fewer than k members of has lost one."""
+        return ScrubMembership(self, whole)
 
-    def _file(self, hashes, lengths, m: int, parity_of,
+    # --- the index: block id → group id [+ (k, m) of a scrub codeword] ----
+
+    def _suffix(self) -> bytes:
+        p = self.codec.params
+        return bytes([p.rs_data, p.rs_parity])
+
+    def _scrub_entry(self, gid: bytes) -> bytes:
+        return gid + self._suffix()
+
+    def _scrub_gid(self, entry: Optional[bytes]) -> Optional[bytes]:
+        """The group id, where `entry` names a codeword the scrub formed
+        at this (k, m): the one kind of entry that is a block's place."""
+        if entry is not None and entry[GID_LEN:] == self._suffix():
+            return entry[:GID_LEN]
+        return None
+
+    def _entries(self, keys: Sequence[bytes]) -> dict:
+        """The index over the span of `keys`, in one range read."""
+        return dict(self.index.items(min(keys), max(keys) + b"\x00"))
+
+    def _touch(self, gid: bytes) -> bool:
+        """A fresh mtime for the group's sidecar, in whichever data dir
+        holds it; → whether it was there."""
+        # the time is given: left to the kernel it is the tick's, which
+        # can lie before the start of a pass that began within the tick
+        now = time.time_ns()
+        for path in self._group_paths(gid):
+            try:
+                os.utime(path, ns=(now, now))
+                return True
+            except OSError:
+                continue
+        return False
+
+    def _file(self, hashes, lengths, parity: np.ndarray,
               origin: str) -> bool:
         """One codeword filed: counted, its sidecar touched — or, where
-        it has none, written from `parity_of()`, (m, maxlen), and
-        counted to `origin` — and its members indexed.  → whether it
-        was written."""
+        it has none, written from `parity`, (m, maxlen), and counted to
+        `origin` — and its members indexed.  → whether it was written."""
         k = self.codec.params.rs_data
         assert 0 < len(hashes) <= k, (len(hashes), k)
-        if self.m_bytes is not None:
-            # a sidecar holds m rows as long as its longest member
-            self.m_bytes.inc(m * int(max(lengths)), part="parity")
-            self.m_bytes.inc(int(sum(lengths)), part="covered")
-        gid = bytes(self._gid(k, m, hashes))
-        existing = self._find_group_path(gid)
-        if existing is not None:
-            # gid hashes the member set AND the (version, k, m) geometry,
-            # so an existing file has identical content: a fresh mtime
-            # (what the purge keys on) is all a stable codeword needs —
-            # skip rewriting ~m/k of the dataset every scrub pass
-            try:
-                os.utime(existing)
-            except OSError:
-                existing = None
-        if existing is None:
-            # manifest built only on the miss path: in steady state most
-            # codewords take the touch shortcut, and serializing + hashing
-            # ~m rows of parity per codeword per pass would dominate it
-            parity = parity_of()
+        self._count_bytes(int(parity.shape[0]) * int(max(lengths)),
+                          int(sum(lengths)))
+        gid = bytes(self._gid(k, int(parity.shape[0]), hashes))
+        # gid hashes the member set AND the (version, k, m) geometry, so
+        # an existing file has identical content: a fresh mtime (what the
+        # purge keys on) is all it needs
+        found = self._touch(gid)
+        if not found:
             rows = [parity[i].tobytes() for i in range(parity.shape[0])]
             manifest = {
                 "v": MANIFEST_VERSION,
@@ -235,17 +317,32 @@ class ParityStore:
             if self.m_written is not None:
                 self.m_written.inc(origin=origin)
                 self.m_written_bytes.inc(int(parity.nbytes), origin=origin)
-        for h in hashes:
-            self.index.insert(bytes(h), gid)
-        return existing is None
+        if origin == "scrub" and len(hashes) == k:
+            entry = self._scrub_entry(gid)
+            for h in hashes:
+                self.index.insert(bytes(h), entry)
+        else:
+            # a full codeword is not robbed by a later one: a member the
+            # scrub has placed keeps its place, this sidecar is extra
+            for h in hashes:
+                if self._scrub_gid(self.index.get(bytes(h))) is None:
+                    self.index.insert(bytes(h), gid)
+        return not found
+
+    def _count_bytes(self, parity: int, covered: int) -> None:
+        """A codeword put or found settled: a sidecar holds m rows as
+        long as its longest member, over the members' own lengths."""
+        if self.m_bytes is not None:
+            self.m_bytes.inc(parity, part="parity")
+            self.m_bytes.inc(covered, part="covered")
 
     # --- repair path -------------------------------------------------------
 
     def _load_manifest(self, h: Hash) -> Optional[dict]:
-        gid = self.index.get(bytes(h))
-        if gid is None:
+        entry = self.index.get(bytes(h))
+        if entry is None:
             return None
-        path = self._find_group_path(bytes(gid))
+        path = self._find_group_path(bytes(entry[:GID_LEN]))
         if path is None:
             return None
         try:
@@ -386,10 +483,12 @@ class ParityStore:
 
     def purge_stale(self, older_than: float) -> int:
         """Delete sidecars not refreshed since `older_than` (unix time)
-        and prune index entries pointing at missing files.  Codeword
-        membership shifts with block churn, so every completed scrub
-        pass calls this with its own start time — without it, orphaned
-        .par files would accumulate on every pass."""
+        and prune index entries pointing at missing files (a scrub
+        codeword's only once the block is gone too).  Write-time
+        codewords are folded into the scrub's and dissolved ones
+        regrouped, so every completed scrub pass calls this with the
+        start of the pass before it — without it, orphaned .par files
+        would accumulate."""
         removed = 0
         for base in self.all_dirs:
             if not os.path.isdir(base):
@@ -408,10 +507,16 @@ class ParityStore:
                             removed += 1
                     except OSError:
                         pass
-        # prune index entries whose group file is gone
+        # prune index entries whose group file is gone, but for a block's
+        # place: a scrub codeword that lost its sidecar (an operator, a
+        # bad sector, one removed while this runs) keeps its members as
+        # long as they are in the store, and the next pass writes the
+        # file again under its name
         dead = [
-            k for k, gid in list(self.index.items(None, None))
-            if self._find_group_path(bytes(gid)) is None
+            k for k, entry in list(self.index.items(None, None))
+            if self._find_group_path(bytes(entry[:GID_LEN])) is None
+            and (self._scrub_gid(entry) is None
+                 or self.manager.find_block(Hash(k)) is None)
         ]
         for k in dead:
             self.index.remove(k)
@@ -425,6 +530,276 @@ class ParityStore:
 
     def stats(self) -> dict:
         return {"indexed_blocks": len(self.index)}
+
+
+# What a running pass knows of a scrub codeword it has met: its sidecar
+# is on disk / is gone and is written again once the k members are read /
+# has to wait for the next pass (a member failed its verify) / is not
+# what the index says and loses its members' entries at the pass's end
+_SETTLED, _PENDING, _BROKEN, _DISSOLVED = range(4)
+
+
+class _Codeword:
+    __slots__ = ("gid", "state", "members", "maxlen", "covered", "held",
+                 "here")
+
+    def __init__(self, gid: bytes, state: int):
+        self.gid, self.state = gid, state
+        self.members: List[bytes] = []  # the ids read so far
+        self.maxlen = self.covered = 0  # of their lengths
+        self.held: List[tuple] = []     # (hash, block) verified in earlier
+        self.here: List[int] = []       # batches; this batch's, by index
+
+
+class BatchPlan:
+    """One scrub batch as it goes to the codec: its own lanes and no
+    other (so its geometry is the listing's), the rows whose parity the
+    device has to hand back in front (k consecutive lanes each, as the
+    fused kernel encodes them), every other lane behind.  `blocks` /
+    `hashes` are the submission, `want` names the front rows, `where[j]`
+    is the lane of the batch's j-th block (a verdict is mapped back
+    through it).  A row that needs lanes an earlier batch verified
+    (`straddlers`) is not the device's: its new lanes stand behind and
+    `filed` hands it over to be encoded on the host."""
+
+    def __init__(self, hashes, blocks, order, rows, straddlers, pending,
+                 tail):
+        self.hashes = [hashes[j] for j in order]
+        self.blocks = [blocks[j] for j in order]
+        self.rows = rows            # front row r: its _Codeword, or None
+        self.want = list(range(len(rows)))      # (a row of free blocks)
+        self.straddlers = straddlers    # (its _Codeword | None, lanes kept
+        self.pending = pending      # over, this batch's by index); rewrites
+        self.tail = tail            # not whole yet; free, short of a row
+        self.where = [0] * len(order)
+        for lane, j in enumerate(order):
+            self.where[j] = lane
+
+
+class ScrubMembership:
+    """Which codeword each block of one scrub pass is in, and whether
+    that codeword needs its parity from the device: the five rules of
+    the module's docstring.  `plan` is asked once a batch before the
+    submit, `filed` names the rows to write once the verdicts are in
+    (`wrote` after each), `close` ends the pass.  `plan` and `close`
+    read and write the index and touch files: off the loop."""
+
+    def __init__(self, store: ParityStore, whole: bool):
+        self.store = store
+        self.whole = whole
+        self.k = store.codec.params.rs_data
+        self.m = store.codec.params.rs_parity
+        self.codewords: dict = {}       # gid → _Codeword, until settled
+        self.fresh: set = set()         # gids of bare entries, touched
+        self.carry: List[tuple] = []    # free (hash, block), fewer than k
+        self.held = 0                   # lanes held for rewrites, at most
+        self.most_held = max(self.k, store.codec.params.batch_blocks)
+        self.asked = 0                  # rows whose parity was asked for,
+        self.host = 0                   # those of them encoded on the host
+        self.counts = dict.fromkeys(CODEWORD_STATES, 0)
+
+    def _count(self, state: str) -> None:
+        self.counts[state] += 1
+        if self.store.m_codewords is not None:
+            self.store.m_codewords.inc(state=state)
+
+    def _adopt(self, keys: List[bytes], entries: dict) -> None:
+        """k blocks of the batch whose bare entries name the group id of
+        exactly those k in id order are an intact scrub codeword (its
+        name is a hash of its members): their entries get the suffix."""
+        named: dict = {}
+        for key in keys:
+            entry = entries.get(key)
+            if entry is not None and len(entry) == GID_LEN:
+                named.setdefault(entry, []).append(key)
+        for gid, members in named.items():
+            if len(members) == self.k and bytes(ParityStore._gid(
+                    self.k, self.m, members)) == gid:
+                entry = self.store._scrub_entry(gid)
+                for key in members:
+                    self.store.index.insert(key, entry)
+                    entries[key] = entry
+
+    def _let_go(self, cw: _Codeword, state: int) -> None:
+        self.held -= len(cw.held)
+        cw.held, cw.state = [], state
+
+    def _met(self, gid: bytes, key: bytes, length: int) -> _Codeword:
+        """A member of scrub codeword `gid` has been read: the first
+        one touches the sidecar, which settles the codeword for this
+        pass or finds it gone."""
+        cw = self.codewords.get(gid)
+        if cw is None:
+            cw = self.codewords[gid] = _Codeword(
+                gid, _SETTLED if self.store._touch(gid) else _PENDING)
+        cw.members.append(key)
+        cw.maxlen = max(cw.maxlen, length)
+        cw.covered += length
+        if cw.state == _SETTLED and len(cw.members) == self.k:
+            del self.codewords[gid]
+            self._count("settled")
+            self.store._count_bytes(self.m * cw.maxlen, cw.covered)
+        return cw
+
+    def plan(self, hashes: Sequence[Hash], blocks: Sequence[bytes],
+             unreadable: Sequence[Hash] = ()) -> BatchPlan:
+        """`unreadable`: blocks of the batch that are on the disk and
+        gave no bytes (a heal brings them back): members all the same,
+        but a codeword cannot be written again without them."""
+        store, k = self.store, self.k
+        keys = [bytes(h) for h in hashes]
+        lost = [bytes(h) for h in unreadable]
+        entries = store._entries(keys + lost)
+        self._adopt(keys, entries)
+        for key in lost:
+            gid = store._scrub_gid(entries.get(key))
+            if gid is not None and self._met(gid, key, 0).state == _PENDING:
+                self._let_go(self.codewords[gid], _BROKEN)
+        free, behind, rewrites = [], [], []
+        for j, key in enumerate(keys):
+            entry = entries.get(key)
+            gid = store._scrub_gid(entry)
+            if gid is None:
+                cover = entry and entry[:GID_LEN]
+                if cover and cover not in self.fresh:
+                    # the cover of a block that waits for a full row
+                    self.fresh.add(cover)
+                    store._touch(cover)
+                free.append(j)
+                continue
+            cw = self._met(gid, key, len(blocks[j]))
+            if cw.state != _PENDING:
+                behind.append(j)
+                continue
+            if not cw.here:
+                rewrites.append(cw)
+            cw.here.append(j)
+        rows, front, straddlers = [], [], []
+        pending = [cw for cw in rewrites if len(cw.members) < k]
+        for cw in rewrites:
+            if len(cw.members) < k:
+                continue
+            named = bytes(ParityStore._gid(k, self.m, sorted(
+                [bytes(h) for h, _b in cw.held]
+                + [keys[j] for j in cw.here]))) == cw.gid
+            if not named:       # not the members its name was made of
+                self._let_go(cw, _DISSOLVED)
+                behind += cw.here
+            elif cw.held:
+                straddlers.append((cw, cw.held, cw.here))
+                self._let_go(cw, _PENDING)
+                behind += cw.here
+            else:
+                rows.append(cw)
+                front += sorted(cw.here, key=keys.__getitem__)
+            cw.here = []
+        # free blocks k at a time in listing order, the carry first: the
+        # row the carry is in straddles, the others are this batch's own
+        if self.carry and len(self.carry) + len(free) >= k:
+            mine = free[:k - len(self.carry)]
+            straddlers.append((None, self.carry, mine))
+            self.carry, free = [], free[len(mine):]
+            behind += mine
+        if not self.carry:
+            full = len(free) // k * k
+            rows += [None] * (full // k)
+            front += free[:full]
+            free = free[full:]
+        for cw in pending:
+            behind += cw.here
+        self.asked += len(rows) + len(straddlers)
+        return BatchPlan(hashes, blocks, front + sorted(behind + free),
+                         rows, straddlers, pending, free)
+
+    def filed(self, plan: BatchPlan, ok) -> Tuple[list, list]:
+        """The verdicts are in.  → (the front rows whose members all
+        verified, to be written from the parity that came back: (row,
+        its _Codeword or None, hashes, blocks); the straddling rows
+        whose new members verified, to be encoded on the host:
+        (codeword or None, hashes, blocks), in codeword order).  What
+        waits is kept only as far as it verified: the free blocks short
+        of a row, the members of a rewrite that is not whole yet."""
+        k = self.k
+
+        def lanes(js):
+            return [plan.where[j] for j in js]
+
+        def took(ls):
+            return [(plan.hashes[lane], plan.blocks[lane]) for lane in ls]
+
+        sound, host = [], []
+        for r, cw in enumerate(plan.rows):
+            lo = r * k
+            if all(ok[lo:lo + k]):
+                sound.append((r, cw, plan.hashes[lo:lo + k],
+                              plan.blocks[lo:lo + k]))
+            elif cw is not None:
+                cw.state = _BROKEN
+        for cw, kept, mine in plan.straddlers:
+            mine = lanes(mine)
+            if all(ok[lane] for lane in mine):
+                row = sorted(kept + took(mine), key=lambda hb: bytes(hb[0]))
+                host.append((cw, [h for h, _b in row], [b for _h, b in row]))
+            elif cw is not None:
+                cw.state = _BROKEN
+        self.carry += took(lane for lane in lanes(plan.tail) if ok[lane])
+        for cw in plan.pending:
+            mine = lanes(cw.here)
+            cw.here = []
+            if all(ok[lane] for lane in mine):
+                cw.held += took(mine)
+                self.held += len(mine)
+            else:
+                self._let_go(cw, _BROKEN)
+        for cw in self.codewords.values():
+            # held for longer than a batch of lanes: the codeword's
+            # members are not where a listing keeps them together
+            if self.held <= self.most_held:
+                break
+            if cw.held:
+                self._let_go(cw, _DISSOLVED)
+        return sound, host
+
+    def wrote(self, cw: Optional[_Codeword], host: bool = False) -> None:
+        """A row `filed` named has been put to the store, its parity the
+        device's or (`host`) the write-time codewords' road's."""
+        self.host += host
+        if cw is None:
+            self._count("formed")
+        else:
+            del self.codewords[cw.gid]
+            self._count("rewritten")
+
+    @property
+    def unsettled(self) -> int:
+        """Codewords met and not settled: what `close` has to look at."""
+        return len(self.codewords)
+
+    def close(self) -> None:
+        """The pass has read its last block.  A codeword a whole pass
+        read fewer than k members of has lost one for good, unless a
+        member it did not read is still in the store (a read that failed
+        this once: the index names the members, the store says whether
+        they are there): its survivors' entries become bare, so the next
+        pass regroups them while the old sidecar covers them."""
+        store, index = self.store, self.store.index
+        short = {cw.gid: cw for cw in self.codewords.values()
+                 if cw.state != _DISSOLVED and self.whole
+                 and len(cw.members) < self.k}
+        if short:
+            for key, entry in index.items(None, None):
+                cw = short.get(store._scrub_gid(entry))
+                if (cw is not None and key not in cw.members
+                        and store.manager.find_block(Hash(key)) is not None):
+                    del short[cw.gid]       # not read, and not gone
+        for cw in self.codewords.values():
+            if cw.state != _DISSOLVED and cw.gid not in short:
+                continue
+            for key in cw.members:
+                if store._scrub_gid(index.get(key)) == cw.gid:
+                    index.insert(key, cw.gid)
+            self._count("dissolved")
+        self.codewords, self.carry, self.held = {}, [], 0
 
 
 # Distributed parity shards carry an 8-byte header {magic, salt}: the
@@ -734,22 +1109,11 @@ class WriteParityAccumulator:
 
             def encode_and_store():
                 blocks = [b.decompressed() for _h, b, _healed in group]
-                # rs_encode_blocks zero-pads the member count to a whole
-                # codeword — exactly the partial-codeword zero-shard
-                # semantics.  Via the codec feeder when the manager has
-                # one: concurrent write-time codewords (every in-flight
-                # PUT under parity_on_write) coalesce into one ragged
-                # pointer-gather/device pass instead of one GF call each.
-                feeder = getattr(self.manager, "feeder", None) \
-                    if self.manager is not None else None
-                if feeder is not None and feeder.codec is self.codec:
-                    parity = feeder.encode_or_direct(blocks)
-                else:
-                    parity = self.codec.rs_encode_blocks(blocks)
+                parity = encode_codeword(self.manager, self.codec, blocks)
                 if self.store is not None:
                     self.store.put_codeword(
-                        hashes, [len(b) for b in blocks], parity[0], origin)
-                return parity[0], [len(b) for b in blocks]
+                        hashes, [len(b) for b in blocks], parity, origin)
+                return parity, [len(b) for b in blocks]
 
             def flush():
                 # the flush as one section of the encode thread: in the

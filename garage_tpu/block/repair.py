@@ -40,6 +40,7 @@ from ..utils.migrate import Migrated
 from ..utils.persister import Persister
 from ..utils.timeline import Timeline
 from ..utils.tranquilizer import Tranquilizer
+from .parity import CODEWORD_STATES
 
 logger = logging.getLogger("garage_tpu.block.repair")
 
@@ -124,9 +125,11 @@ class _PassAccount:
         self.ns = dict.fromkeys(SCRUB_SEGMENTS, 0)
         self.flushed = dict.fromkeys(SCRUB_SEGMENTS, 0)
         self.blocks = self.bytes = self.batches = 0
-        # the pass's codewords: those asked of the parity store, those
-        # of them that had no sidecar, and the sidecars its purge removed
-        self.rows = self.rows_lacking = self.purged = 0
+        # the pass's codewords by what it did with them, the rows that
+        # needed parity, those of them encoded on the host (members of
+        # two batches), the sidecars its purge removed
+        self.codewords = dict.fromkeys(CODEWORD_STATES, 0)
+        self.rows_lacking = self.rows_host = self.purged = 0
 
     def mark(self, segment: str) -> Tuple[int, int]:
         """→ (the last stamp, now): the interval now owned by `segment`."""
@@ -324,9 +327,13 @@ class ScrubWorker(Worker):
         # would stage, send and hash it twice (`_read_ahead`)
         self._awaiting_read = False
         self._verified_pos = self.state.position
-        # verified plain blocks carried between batches until a full RS
-        # codeword (k blocks) accumulates for the parity sidecar store
-        self._parity_carry: Tuple[list, list] = ([], [])
+        # the running pass's view of the parity index: which codeword
+        # each block is in, and the verified blocks kept between batches
+        # until a row of k is whole (block/parity.py ScrubMembership)
+        self._members = None
+        # whether this process has run the pass from its first block: a
+        # resumed one cannot tell a codeword that lost a member
+        self._pass_whole = False
         self._prev_pass_start = 0.0  # resumed pass: purge nothing extra
         # the running pass's account (None between passes), and the
         # counters it is flushed into after every batch
@@ -439,7 +446,7 @@ class ScrubWorker(Worker):
                 self._verified_pos = 0
                 self._begin_pass()
                 self._drop_read_ahead()
-                self._drop_parity_carry()
+                self._members, self._pass_whole = None, True
                 # purge grace is ONE pass: remember the previous start
                 # before overwriting it (a sidecar skipped this pass —
                 # its row held the corruption being repaired — must
@@ -462,14 +469,17 @@ class ScrubWorker(Worker):
             st.running, st.paused, st.position = False, False, 0
             self._verified_pos = 0
             self._drop_read_ahead()
+            self._members = None
             self._flush_account()   # a cancelled pass has no span
             self._acct = None
         self._checkpoint(force=True)
         if self._acct is not None:
             self._acct.mark("checkpoint")
 
-    def _drop_parity_carry(self) -> None:
-        self._parity_carry = ([], [])
+    def _membership(self, store):
+        if self._members is None:
+            self._members = store.begin_pass(self._pass_whole)
+        return self._members
 
     def _drop_read_ahead(self) -> None:
         if self._ra_task is not None:
@@ -528,8 +538,10 @@ class ScrubWorker(Worker):
         _timeline(self.manager).event(
             "scrub pass", "scrub", acct.t0, acct.last, cat="scrub",
             blocks=acct.blocks, bytes=acct.bytes, batches=acct.batches,
-            rows=acct.rows, rows_lacking=acct.rows_lacking,
+            rows=sum(acct.codewords.values()),
+            rows_lacking=acct.rows_lacking, rows_host=acct.rows_host,
             purged=acct.purged,
+            **acct.codewords,
             corruptions=self.state.corruptions, resumed=acct.resumed,
             **{f"{seg}_ms": round(ns / 1e6, 3)
                for seg, ns in acct.ns.items() if ns})
@@ -598,13 +610,25 @@ class ScrubWorker(Worker):
                 self.m_passes.inc()
             self._flush_account()
             self.iterator = None
-            self._drop_parity_carry()  # <k leftover: next pass retries
-            if self.manager.parity_store is not None:
-                # codeword membership shifts with churn: drop sidecars
-                # refreshed by NEITHER this pass nor the previous one,
-                # else orphans accumulate forever (one-pass grace keeps
-                # coverage for rows that failed verify this pass)
-                store = self.manager.parity_store
+            store = self.manager.parity_store
+            members, self._members = self._members, None
+            if members is not None:
+                # fewer than k free blocks left over: the next pass
+                # retries.  A codeword this pass read fewer than k
+                # members of is dissolved (index writes: off the loop)
+                if members.unsettled:
+                    await self._hop("parity_write", members.close)
+                    self._segment("parity_write", "parity close",
+                                  **members.counts)
+                self._acct.codewords = members.counts
+                self._acct.rows_lacking = members.asked
+                self._acct.rows_host = members.host
+            if store is not None:
+                # write-time codewords are folded into the scrub's and
+                # dissolved ones regrouped: drop sidecars refreshed by
+                # NEITHER this pass nor the previous one, else orphans
+                # accumulate forever (one-pass grace keeps coverage for
+                # what waits: block/parity.py)
                 self._acct.purged = await self._hop(
                     "purge", store.purge_stale, self._prev_pass_start)
                 self._segment("purge", "purge stale", **store.last_purge)
@@ -763,33 +787,32 @@ class ScrubWorker(Worker):
                 self.m_inflate_bytes.inc(read_bytes["zst"], dir="in")
                 self.m_inflate_bytes.inc(inflate_out, dir="out")
         await self._heal(lost)
+        store = mgr.parity_store
+        k = mgr.codec.params.rs_data
+        if lost and not plain_blocks and store is not None and k > 0:
+            # nothing of this batch to verify: its blocks are their
+            # codewords' members all the same (block/parity.py, rule 4)
+            await self._hop("parity_write", self._membership(store).plan,
+                            [], [], [h for h, _path in lost])
+            self._segment("parity_write")
         if plain_blocks:
-            store = mgr.parity_store
-            k = mgr.codec.params.rs_data
-            files_parity = store is not None and k > 0
-            # prepend the carry (already-verified blocks from previous
-            # batches) so RS codewords align to k across batch boundaries
-            # — a per-prefix batch rarely holds k blocks by itself.  The
-            # ≤ k-1 carry blocks are re-hashed by the fused dispatch and
-            # the trailing partial row's parity is recomputed next batch:
-            # bounded waste (< k blocks per batch) accepted to keep the
-            # verify+encode a single codec call
-            carry_b, carry_h = self._parity_carry if files_parity else ([], [])
-            nc = len(carry_b)
-            all_b = carry_b + plain_blocks
-            all_h = carry_h + plain_hashes
-            # the rows whose parity has to come back: the codewords
-            # that have no sidecar yet.  A store in steady state names
-            # none, and nothing but the verdicts leaves the device
-            want_parity = False
-            if files_parity:
-                want_parity = await self._hop(
-                    "parity_write", store.rows_lacking_sidecar, all_h)
+            # which codeword each block is in is the parity index's to
+            # say (block/parity.py): the rows that need their parity (a
+            # lost sidecar's members, free blocks k at a time, with what
+            # earlier batches kept for them) go in front, and nothing
+            # but the verdicts leaves the device for the rest
+            members = plan = None
+            all_b, all_h, want_parity = plain_blocks, plain_hashes, False
+            if store is not None and k > 0:
+                members = self._membership(store)
+                plan = await self._hop(
+                    "parity_write", members.plan, plain_hashes, plain_blocks,
+                    [h for h, _path in lost])
+                all_b, all_h, want_parity = (plan.blocks, plan.hashes,
+                                             plan.want)
                 self._segment("parity_write", "parity ask",
-                              rows=len(all_h) // k, lacking=len(want_parity))
-                if self._acct is not None:
-                    self._acct.rows += len(all_h) // k
-                    self._acct.rows_lacking += len(want_parity)
+                              rows=len(all_h) // k, lacking=len(plan.want),
+                              straddling=len(plan.straddlers))
             nbytes = sum(len(b) for b in plain_blocks)
             if self._acct is not None:
                 self._acct.batches += 1
@@ -820,9 +843,12 @@ class ScrubWorker(Worker):
                         "codec_wait", mgr.codec.scrub_encode_batch,
                         all_b, all_h, want_parity)
             self._segment("codec_wait", "codec wait", blocks=len(all_b),
-                          bytes=nbytes, carry=nc)
+                          bytes=nbytes)
+            # the batch's own verdicts, in the batch's order
+            good = (list(ok) if plan is None
+                    else [ok[lane] for lane in plan.where])
             await self._heal([batch[plain_idx[j]][:2]
-                              for j, good in enumerate(ok[nc:]) if not good])
+                              for j, g in enumerate(good) if not g])
             # Coverage refresh: verified blocks with NO live distributed
             # codeword (distribution failed at write time, coverage was
             # wrongly tombstoned, or the data predates EC) re-enter the
@@ -841,17 +867,15 @@ class ScrubWorker(Worker):
             if acc is not None and acc.distributor is not None:
                 from .block import DataBlock
 
-                cand = []
-                for j, good in enumerate(ok[nc:]):
-                    h = all_h[nc + j]
-                    # NOT gated on acc.recently_added: that LRU remembers
-                    # the WRITE-time add, which is exactly the add whose
-                    # coverage may have been lost — locally_covered is
-                    # the authoritative duplicate guard, and a rare
-                    # double codeword (add raced an in-flight flush) is
-                    # benign extra parity, reclaimed by normal GC
-                    if good and not mgr.is_parity_block(h):
-                        cand.append((h, all_b[nc + j]))
+                # NOT gated on acc.recently_added: that LRU remembers
+                # the WRITE-time add, which is exactly the add whose
+                # coverage may have been lost — locally_covered is
+                # the authoritative duplicate guard, and a rare
+                # double codeword (add raced an in-flight flush) is
+                # benign extra parity, reclaimed by normal GC
+                cand = [(h, b) for h, b, g in zip(plain_hashes,
+                                                  plain_blocks, good)
+                        if g and not mgr.is_parity_block(h)]
 
                 def _uncovered():
                     # one off-loop hop for the whole batch: the per-hash
@@ -869,57 +893,43 @@ class ScrubWorker(Worker):
                     acc.add(h, DataBlock.plain(b))
                 self._segment("coverage_refresh", "coverage refresh",
                               candidates=len(cand), refreshed=refreshed)
-            if files_parity:
-                # persist RS sidecars for every COMPLETE codeword whose
-                # members all verified — this is what makes a later
-                # corruption locally repairable with zero network
-                # (the BlockCodec north star's decode-repair half).
-                # The rows that lacked one are written from the parity
-                # that came back, a thread hop a row; all the others
-                # are refreshed in one hop, without their parity
-                nrows = len(all_b) // k
-                sound = [row for row in range(nrows)
-                         if all(ok[row * k:(row + 1) * k])]
-                fresh = set(want_parity) if parity is not None else ()
+            if plan is not None:
+                # persist RS sidecars for the rows whose members all
+                # verified — this is what makes a later corruption
+                # locally repairable with zero network (the BlockCodec
+                # north star's decode-repair half): the front rows from
+                # the parity that came back, a row whose members were
+                # verified in two batches encoded where the write-time
+                # codewords are; a thread hop a row
                 written = touched = par_bytes = 0
-                for row in sound:
-                    if row not in fresh:
-                        continue
-                    lo = row * k
+                sound, straddling = members.filed(plan, ok)
+                for row, cw, hashes, blocks in sound:
                     # trim to the row's own width: pad columns beyond the
                     # longest member are zero parity (GF-linear) and would
                     # bloat the sidecar to the batch-global maxlen
-                    row_max = max(len(b) for b in all_b[lo:lo + k])
+                    row_max = max(len(b) for b in blocks)
                     row_parity = np.asarray(parity[row])[:, :row_max]
                     if await self._hop(
-                        "parity_write", store.put_codeword,
-                        all_h[lo:lo + k],
-                        [len(b) for b in all_b[lo:lo + k]],
-                        row_parity,
+                        "parity_write", store.put_codeword, hashes,
+                        [len(b) for b in blocks], row_parity,
                     ):
                         written += 1
                         par_bytes += row_parity.nbytes
                     else:
                         touched += 1
-                kept = [(all_h[row * k:(row + 1) * k],
-                         all_b[row * k:(row + 1) * k])
-                        for row in sound if row not in fresh]
-                if kept:
-                    # a file gone since it was asked for is encoded and
-                    # written there: `regained`, no parity of the batch's
-                    n, regained = await self._hop(
-                        "parity_write", store.refresh_codewords, kept)
-                    touched += n
-                    written += regained
-                self._segment("parity_write", "parity write", rows=nrows,
-                              written=written, touched=touched,
-                              bytes=par_bytes)
-                rest = nrows * k
-                self._parity_carry = (
-                    [b for b, good in zip(all_b[rest:], ok[rest:]) if good],
-                    [h for h, good in zip(all_h[rest:],
-                                          ok[rest:]) if good],
-                )
+                    members.wrote(cw)
+                for cw, hashes, blocks in straddling:
+                    if await self._hop("parity_write", store.put_straddler,
+                                       hashes, blocks):
+                        written += 1
+                    else:
+                        touched += 1
+                    members.wrote(cw, host=True)
+                if plan.rows or plan.straddlers:
+                    self._segment("parity_write", "parity write",
+                                  rows=len(plan.rows) + len(plan.straddlers),
+                                  written=written, touched=touched,
+                                  bytes=par_bytes, host=len(straddling))
 
     async def _heal(self, lost: List[Tuple[Hash, str]]) -> None:
         """Quarantine and heal a batch's lost copies as one stamped
@@ -1168,12 +1178,21 @@ class _Read(NamedTuple):
 
 
 def _list_batch(it: BlockStoreIterator, want: int):
-    """Prefix dirs until they hold `want` blocks, in one submission of
-    the lane; None when the walk is complete."""
+    """Prefix dirs until they hold `want` blocks and, but for one dir
+    that holds more by itself, never more: one lane past `want` is a
+    geometry twice as wide, with programs of its own (a store that
+    takes writes moves its batches' edges every pass, and one pass in a
+    hundred built them inside the chip's window: PERF.md, PR 44).  The
+    dir that would overfill is left for the next batch.  In one
+    submission of the lane; None when the walk is complete."""
     batch = None
     while batch is None or len(batch) < want:
+        before = it.position
         more = it.next_prefix()
         if more is None:
+            break
+        if batch and len(batch) + len(more) > want:
+            it.position = before
             break
         batch = (batch or []) + more
     return batch

@@ -539,28 +539,34 @@ async def test_parity_sidecar_local_reconstruction(tmp_path):
     rebuilt = m.parity_store.try_reconstruct(vh2)
     assert rebuilt == blocks[victim2]
 
-    # churn + GC: remove a block so its codeword can never re-form, then
-    # run TWO more passes on the SAME worker (the purge grace is one
-    # pass); the orphaned sidecar is deleted and its index entries pruned
+    # churn + GC: remove a block of the OTHER codeword, so neither can
+    # be whole again.  The pass that misses them dissolves both (their
+    # survivors keep the old sidecars as cover), the next regroups the
+    # first k survivors in id order, and the old sidecar under which no
+    # survivor waits is purged once the purge's grace is over, its index
+    # entries pruned; the other stays: it is six blocks' only cover
     import time as _time
 
-    removed_h = list(blocks)[10]
+    order = sorted(blocks)
+    removed_h = order[12] if order.index(victim2) < 8 else order[3]
+    lost = sorted((victim2, removed_h))     # [of order[:8], of order[8:]]
     rf = m.find_block(Hash(removed_h))
     os.remove(rf[0])
-    files_before = sum(
-        len(fs) for _d, _s, fs in os.walk(m.parity_store.dir))
     _time.sleep(0.05)
-    for _pass in range(2):
+    for _pass in range(4):
         w2.send_command("start")
         while (await w2.work()).name in ("BUSY", "THROTTLED"):
             pass
         _time.sleep(0.05)
     files_after = sum(
         len(fs) for _d, _s, fs in os.walk(m.parity_store.dir))
-    assert files_after < files_before, "orphaned sidecar never purged"
-    # 15 surviving blocks = 1 full codeword; the other 7 lose coverage
-    assert m.parity_store.stats()["indexed_blocks"] == 8
-    assert not m.parity_store.coverage(Hash(removed_h))
+    assert files_after == 2, "orphaned sidecar never purged"
+    # 14 surviving blocks = 1 full codeword and 6 under the old one,
+    # which names the block it lost too
+    assert m.parity_store.stats()["indexed_blocks"] == 15
+    assert not m.parity_store.coverage(Hash(lost[0]))
+    assert all(m.parity_store.coverage(Hash(h)) for h in order
+               if h != lost[0])
 
     # fewer than k surviving pieces → reconstruction refuses
     for i, hb in enumerate(list(blocks)):

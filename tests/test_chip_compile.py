@@ -256,3 +256,57 @@ def test_every_scrub_batch_on_one_chip_takes_the_pallas_road():
     assert not mesh._use_pallas_scrub(256)
     assert not demoted._use_pallas_scrub(256)
     assert on_cpu._use_pallas_scrub(256)    # the latch starts up
+
+
+def test_a_permuted_scrub_batch_takes_the_same_programs(tmp_path):
+    """ISSUE 44: the parity index's plan of a batch orders the lanes on
+    the host (the rows that need their parity in front) and leaves
+    their number alone: a pass's batches of 256, 256 and 64 blocks, two
+    sidecars lost and a block written since the last pass, go to the
+    codec with the lanes the listing gave them and no other (a row whose
+    members were verified in two batches is encoded on the host), so
+    they are staged at the geometry the batch in listing order takes and
+    take the same closed set of programs."""
+    import hashlib
+    import types
+
+    from garage_tpu.block.parity import ParityStore
+    from garage_tpu.db import open_db
+    from garage_tpu.ops.cpu_codec import CpuCodec
+    from garage_tpu.utils.data import Hash
+
+    params = CodecParams(rs_data=8, rs_parity=4)
+    manager = types.SimpleNamespace(
+        system=types.SimpleNamespace(metrics=None),
+        data_layout=types.SimpleNamespace(data_dirs=[types.SimpleNamespace(
+            path=str(tmp_path), read_only=False)]))
+    store = ParityStore(manager, open_db("memory"), CpuCodec(params))
+    rng = np.random.default_rng(44)
+    blocks = sorted(((Hash(hashlib.blake2s(b, digest_size=32).digest()), b)
+                     for b in (rng.bytes(64) for _ in range(576))),
+                    key=lambda hb: bytes(hb[0]))
+    for lo in range(0, 576, 8):
+        row = blocks[lo:lo + 8]
+        store.put_codeword([h for h, _b in row], [64] * 8,
+                           store.codec.rs_encode_blocks(
+                               [b for _h, b in row])[0])
+    for lo in (16, 296):
+        os.remove(store._find_group_path(bytes(store._gid(
+            8, 4, [h for h, _b in blocks[lo:lo + 8]]))))
+    new = rng.bytes(64)
+    listing = sorted(blocks + [(Hash(hashlib.blake2s(
+        new, digest_size=32).digest()), new)], key=lambda hb: bytes(hb[0]))
+    chip = _one_chip_codec()
+    pass_ = store.begin_pass(True)
+    rows = 0
+    for lo, hi in ((0, 256), (256, 512), (512, 577)):
+        batch = listing[lo:hi]
+        plan = pass_.plan([h for h, _b in batch], [b for _h, b in batch])
+        rows += len(plan.want)
+        assert sorted(map(bytes, plan.hashes)) == [
+            bytes(h) for h, _b in batch]
+        assert len(plan.hashes) == hi - lo      # its own lanes, no other
+        staged = chip.staging_geometry(len(plan.hashes), MIB, "scrub")
+        assert chip._use_pallas_scrub(chip.scrub_device_lanes(staged[0]))
+        pass_.filed(plan, [True] * len(plan.hashes))
+    assert rows == 2 and pass_.counts["settled"] == 70

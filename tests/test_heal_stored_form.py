@@ -208,8 +208,9 @@ async def test_a_pass_over_a_mixed_store_counts_what_the_files_say(tmp_path):
     # the frame that did not decode kept its lane: no codeword after it
     # changed its members, so none was encoded and written anew
     second = m.codec.obs.timeline.snapshot()[n_events:]
-    writes = [e["args"] for e in second if e["name"] == "parity write"]
-    assert writes and sum(a["written"] for a in writes) == 0
+    asks = [e["args"] for e in second if e["name"] == "parity ask"]
+    assert asks and sum(a["lacking"] for a in asks) == 0
+    assert not [e for e in second if e["name"] == "parity write"]
     assert m.m_heal_stored.get(form="zst") == 1
     assert m.m_heal_stored.get(form="plain") == 1
     for hb, d in contents.items():

@@ -1,22 +1,29 @@
-"""The scrub under ingest (ISSUE 43): blocks written between two passes.
+"""The scrub under ingest (ISSUE 43), and a codeword that keeps its
+members through it (ISSUE 44).
 
 Upstream starts a full pass every 25 to 35 days, so every pass of a node
 in use meets blocks the last one never saw.  Here: the second pass
 verifies them; every block it verified is then in a stored codeword
-whose parity is the reference's, but for fewer than k; the sidecars of
-codewords that are no more are purged and counted; a flipped old block
-heals from a sidecar after its codeword moved; the counters tell a
-sidecar of the scrub from one of the write-time accumulator and from one
-after a heal; the accumulator's flush says what caused it.
+whose parity is the reference's, but for fewer than k; and membership is
+what the parity index says it is, one test a rule of `block/parity.py`:
+a block written between two passes moves no old codeword; a removed
+sidecar comes back under its own path, a stray block earlier in the
+listing or its members over two batches; a partial write-time codeword
+is folded into a full one and purged later, with no block uncovered in
+between; a deleted member dissolves its codeword and the survivors are
+regrouped; a healed block keeps its codeword and heals from it again;
+an index from before the rule converges without a sidecar written; the
+counters tell a sidecar of the scrub from one of the write-time
+accumulator and from one after a heal, and say what a pass did with
+every codeword it met.
 
 The reference (`benchmarks/reference.py`) imports nothing of the
-program, and nothing here says how the program groups blocks into
-codewords: a sidecar is judged as the codeword it states (its members,
-in its order, at its `maxlen`), and "moved" is read off the disk (a
-block that two sidecars name).
+program: a sidecar is judged as the codeword it states (its members, in
+its order, at its `maxlen`).
 """
 
 import asyncio
+import errno
 import hashlib
 import os
 
@@ -140,21 +147,57 @@ async def test_every_verified_block_is_in_a_codeword_of_the_references_parity(
     await stop()
 
 
-async def test_the_second_pass_writes_what_lacked_a_sidecar_and_says_so(
-        tmp_path):
-    mgr, _w, stop, _b, first, _v, written = await _two_passes(tmp_path)
+def _members(sidecars: dict) -> dict:
+    return {path: [bytes(h) for h in man["hashes"]]
+            for path, man in sidecars.items()}
+
+
+def _named_by(sidecars: dict) -> dict:
+    """{block id: the paths of the sidecars that name it}."""
+    out = {}
+    for path, members in _members(sidecars).items():
+        for h in members:
+            out.setdefault(h, set()).add(path)
+    return out
+
+
+def _last_pass(mgr) -> dict:
+    return _events(mgr, "scrub pass")[-1]
+
+
+def _states(args: dict) -> dict:
+    return {s: args[s] for s in ("settled", "rewritten", "formed",
+                                 "dissolved")}
+
+
+async def _next_pass(worker):
+    await asyncio.sleep(0.05)   # mtimes against the next pass's start
+    await _pass(worker)
+
+
+@pytest.mark.parametrize("new", [1, NEW])
+async def test_a_block_written_between_two_passes_moves_no_old_codeword(
+        tmp_path, new):
+    """The second pass writes at most ⌈new / k⌉ sidecars, asks the
+    device for those rows' parity and no other, and says so."""
+    mgr, _w, stop, _b, first, _v, written = await _two_passes(tmp_path, new)
+    k, m = mgr.codec.params.rs_data, mgr.codec.params.rs_parity
     second = _sidecars(mgr)
+    assert len(first) == OLD // k
+    assert _members(first).items() <= _members(second).items()
     fresh = set(second) - set(first)
-    assert fresh, "no block written between the passes changed a codeword"
-    last = _events(mgr, "scrub pass")[-1]
-    assert last["rows_lacking"] == len(fresh) <= last["rows"]
+    assert len(fresh) == (OLD % k + new) // k <= -(-new // k)
+    assert all(len(paths) == 1 for paths in _named_by(second).values())
+    last = _last_pass(mgr)
+    assert _states(last) == {"settled": len(first), "rewritten": 0,
+                             "formed": len(fresh), "dissolved": 0}
+    assert last["rows_lacking"] == len(fresh)
+    assert last["rows"] == len(second)
     assert (_counter(mgr, "parity_codewords_written_total", origin="scrub")
             - written) == len(fresh)
-    k, m = mgr.codec.params.rs_data, mgr.codec.params.rs_parity
     assert _counter(mgr, "parity_sidecar_written_bytes_total",
                     origin="scrub") == sum(
         m * man["maxlen"] for man in second.values())
-    assert k * len(second) >= OLD  # and the first pass's are still there
     await stop()
 
 
@@ -163,61 +206,327 @@ async def test_a_store_that_stood_still_finds_every_sidecar_and_writes_none(
     mgr, worker, stop, _b, _first, _v, _wr = await _two_passes(tmp_path)
     written = _counter(mgr, "parity_codewords_written_total", origin="scrub")
     await _pass(worker)
-    last = _events(mgr, "scrub pass")[-1]
-    assert last["rows_lacking"] == 0 < last["rows"]
+    last = _last_pass(mgr)
+    assert last["rows_lacking"] == 0 < last["rows"] == last["settled"]
     assert _counter(mgr, "parity_codewords_written_total",
                     origin="scrub") == written
     await stop()
 
 
-async def test_what_moved_is_purged_a_pass_later_and_counted(tmp_path):
-    """The purge's grace is one pass: the sidecars the second pass did
-    not refresh go at the end of the third, and the counter, the `purge
-    stale` event and the `scrub pass` event all say how many."""
-    mgr, worker, stop, blocks, first, _v, _wr = await _two_passes(tmp_path)
-    second = _sidecars(mgr)
-    assert _counter(mgr, "parity_purged_sidecars_total") == 0
-    assert set(first) <= set(second)
-    await asyncio.sleep(0.05)       # mtimes against the third pass's start
-    await _pass(worker)
-    third = _sidecars(mgr)
-    gone = set(second) - set(third)
-    assert gone and gone <= set(first)
-    assert _counter(mgr, "parity_purged_sidecars_total") == len(gone)
-    assert _events(mgr, "purge stale")[-1]["removed"] == len(gone)
-    assert _events(mgr, "scrub pass")[-1]["purged"] == len(gone)
-    assert sum(e["removed"] for e in _events(mgr, "purge stale")) == len(gone)
-    wrong, named = _judged(mgr, blocks)
-    assert wrong == 0
-    assert len(set(blocks) - named) < mgr.codec.params.rs_data
+async def test_a_partial_write_time_codeword_is_folded_and_purged_later(
+        tmp_path):
+    """Blocks whose only cover is a write-time codeword of fewer than k
+    are free: the scrub keeps that sidecar fresh while they wait for a
+    full row, folds them into a codeword of its own, and the purge then
+    takes the old files, its grace over: no block is without a sidecar
+    that names it at any pass's end.  The counter, the `purge stale`
+    event and the `scrub pass` event all say how many went."""
+    # no timer: a short one fires between two writes on a loaded host
+    mgr, worker, stop = await _node(tmp_path, flush_after=60.0)
+    k = mgr.codec.params.rs_data
+    rng = np.random.default_rng(SEED)
+    blocks = await _write(mgr, 3, rng)
+    await mgr.write_parity.drain()          # a partial flush: 3 of k
+    partial = set(_sidecars(mgr))
+    assert len(partial) == 1
+
+    def covered():
+        return all(mgr.parity_store.coverage(Hash(h)) for h in blocks)
+
+    await _next_pass(worker)                # nothing to form: 3 wait
+    assert _states(_last_pass(mgr)) == dict.fromkeys(
+        ("settled", "rewritten", "formed", "dissolved"), 0)
+    assert set(_sidecars(mgr)) == partial and covered()
+    blocks.update(await _write(mgr, k - 3, rng))
+    await mgr.write_parity.drain()
+    partial = set(_sidecars(mgr))
+    assert len(partial) == 2
+    await _next_pass(worker)                # k free blocks: one codeword
+    assert _last_pass(mgr)["formed"] == 1
+    full = set(_sidecars(mgr)) - partial
+    assert len(full) == 1 and covered()
+    (members,) = _members({p: _sidecars(mgr)[p] for p in full}).values()
+    assert members == sorted(blocks)
+    for expect in (partial | full, full):   # the grace, then the purge
+        await _next_pass(worker)
+        assert set(_sidecars(mgr)) == expect and covered()
+        assert _last_pass(mgr)["settled"] == 1
+    assert _counter(mgr, "parity_purged_sidecars_total") == 2
+    assert _events(mgr, "purge stale")[-1]["removed"] == 2
+    assert _last_pass(mgr)["purged"] == 2
+    assert sum(e["removed"] for e in _events(mgr, "purge stale")) == 2
+    assert _judged(mgr, blocks) == (0, set(blocks))
     await stop()
 
 
-async def test_a_flipped_old_block_heals_from_a_sidecar_after_its_codeword_moved(
+async def test_a_healed_block_keeps_its_codeword_and_heals_from_it_again(
         tmp_path):
-    mgr, worker, stop, blocks, first, _v, _wr = await _two_passes(tmp_path)
-    names = {}
-    for path, man in _sidecars(mgr).items():
-        for h in man["hashes"]:
-            names.setdefault(bytes(h), set()).add(path)
-    # moved, as the disk shows it: a block of the first pass that a
-    # second sidecar names now
-    moved = sorted(h for h, paths in names.items()
-                   if len(paths) > 1 and paths & set(first))
-    assert moved, "no codeword moved"
-    victim = moved[len(moved) // 2]
-    path, _ = mgr.find_block(Hash(victim))
-    bad = bytearray(blocks[victim])
-    bad[len(bad) // 2] ^= 0x40
-    with open(path, "wb") as f:
-        f.write(bytes(bad))
+    """A heal writes the block back through the write-time accumulator,
+    whose codeword names it too; the index keeps naming the scrub's (a
+    full codeword is not robbed), so no codeword is dissolved and the
+    next flip of the same block heals from the same sidecar."""
+    mgr, worker, stop = await _node(tmp_path, flush_after=60.0)
+    k = mgr.codec.params.rs_data
+    store = mgr.parity_store
+    blocks = await _write(mgr, 2 * k, np.random.default_rng(SEED))
+    await mgr.write_parity.settled()        # two write-time codewords
     await _pass(worker)
-    assert worker.state.corruptions == 1
-    # one node, no replica: what came back came from a sidecar
-    assert mgr.blocks_reconstructed == 1
-    assert _counter(mgr, "block_heal_total", source="local_sidecar") == 1
-    with open(mgr.find_block(Hash(victim))[0], "rb") as f:
-        assert f.read() == blocks[victim]
+    assert _last_pass(mgr)["formed"] == 2
+    victim = sorted(blocks)[3]
+    place = store.index.get(victim)
+    assert store._scrub_gid(place) is not None
+    scrubs = {p for p, ms in _members(_sidecars(mgr)).items()
+              if ms == sorted(ms)}
+    assert len(scrubs) == 2
+    for heals in (1, 2):
+        path, _ = mgr.find_block(Hash(victim))
+        bad = bytearray(blocks[victim])
+        bad[len(bad) // 2] ^= 0x40
+        with open(path, "wb") as f:
+            f.write(bytes(bad))
+        await _next_pass(worker)
+        # one node, no replica: what came back came from a sidecar
+        assert mgr.blocks_reconstructed == heals
+        assert _counter(mgr, "block_heal_total",
+                        source="local_sidecar") == heals
+        # the heal's own codeword, of this one member: written once,
+        # found the second time
+        await mgr.write_parity.drain()
+        assert _counter(mgr, "parity_codewords_written_total",
+                        origin="heal") == 1
+        assert store.index.get(victim) == place
+        assert _states(_last_pass(mgr)) == {
+            "settled": 2, "rewritten": 0, "formed": 0, "dissolved": 0}
+        with open(mgr.find_block(Hash(victim))[0], "rb") as f:
+            assert f.read() == blocks[victim]
+    assert scrubs <= set(_sidecars(mgr))
+    await stop()
+
+
+async def _three_codewords(tmp_path, batch_blocks=None):
+    """A pass over 3 k blocks: three codewords of the scrub's."""
+    mgr, worker, stop = await _node(tmp_path)
+    if batch_blocks is not None:
+        mgr.codec.params.batch_blocks = batch_blocks
+    k = mgr.codec.params.rs_data
+    rng = np.random.default_rng(SEED)
+    blocks = await _write(mgr, 3 * k, rng)
+    await _pass(worker)
+    first = _sidecars(mgr)
+    assert len(first) == 3
+    return mgr, worker, stop, blocks, first, rng
+
+
+async def test_a_removed_sidecar_comes_back_under_its_own_path(tmp_path):
+    """With a stray free block earlier in the listing: the codeword's k
+    members are encoded again, not k blocks counted off from the stray
+    one on."""
+    mgr, worker, stop, blocks, first, rng = await _three_codewords(tmp_path)
+    last = sorted(blocks)[-1]
+    (lost,) = [p for p, ms in _members(first).items() if last in ms]
+    stray = {}
+    while not stray or min(stray) > sorted(blocks)[0]:
+        stray = await _write(mgr, 1, rng)
+        blocks.update(stray)
+    os.remove(lost)
+    written = _counter(mgr, "parity_codewords_written_total", origin="scrub")
+    await _next_pass(worker)
+    assert _members(_sidecars(mgr)) == _members(first)
+    assert _states(_last_pass(mgr)) == {
+        "settled": 2, "rewritten": 1, "formed": 0, "dissolved": 0}
+    assert _last_pass(mgr)["rows_lacking"] == 1
+    assert _last_pass(mgr)["rows_host"] == 0
+    assert (_counter(mgr, "parity_codewords_written_total", origin="scrub")
+            - written) == 1
+    wrong, named = _judged(mgr, blocks)
+    assert wrong == 0 and set(blocks) - named == set(blocks) - set(
+        h for ms in _members(first).values() for h in ms)
+    await stop()
+
+
+async def test_a_sidecar_lost_while_the_purge_runs_keeps_its_members(
+        tmp_path):
+    """The purge prunes index entries whose sidecar is gone; a scrub
+    codeword's survive it while their blocks are in the store (whoever
+    polls the worker's state sees a pass ended before its purge has
+    run, and may remove a sidecar under it), and go with their block."""
+    mgr, worker, stop, blocks, first, _rng = await _three_codewords(tmp_path)
+    store = mgr.parity_store
+    lost = sorted(first)[1]
+    members = _members(first)[lost]
+    os.remove(lost)
+    os.remove(mgr.find_block(Hash(members[0]))[0])
+    store.purge_stale(0.0)
+    assert store.last_purge == {"removed": 0, "dead": 1}
+    assert store.index.get(members[0]) is None
+    assert all(store._scrub_gid(store.index.get(h)) for h in members[1:])
+    await mgr.write_block(Hash(members[0]),
+                          DataBlock.plain(blocks[members[0]]))
+    os.remove(sorted(first)[2])
+    store.purge_stale(0.0)
+    assert store.last_purge == {"removed": 0, "dead": 0}
+    await _next_pass(worker)
+    # the one whose members are all indexed is back under its name; the
+    # other has a member the index lost: dissolved, regrouped next pass
+    assert _states(_last_pass(mgr)) == {
+        "settled": 1, "rewritten": 1, "formed": 0, "dissolved": 1}
+    assert set(_sidecars(mgr)) == set(first) - {lost}
+    await _next_pass(worker)
+    assert _last_pass(mgr)["formed"] == 1
+    assert _members(_sidecars(mgr)) == _members(first)
+    assert _judged(mgr, blocks) == (0, set(blocks))
+    await stop()
+
+
+async def test_a_codeword_over_two_batches_is_rewritten_whole(tmp_path):
+    """Every sidecar removed, batches shorter than a codeword: the
+    members read first are kept until the last is, and each file comes
+    back under its name with the reference's parity."""
+    mgr, worker, stop, blocks, first, _rng = await _three_codewords(
+        tmp_path, batch_blocks=5)
+    for path in first:
+        os.remove(path)
+    await _next_pass(worker)
+    last = _last_pass(mgr)
+    assert last["batches"] >= 4
+    assert _states(last) == {"settled": 0, "rewritten": 3, "formed": 0,
+                             "dissolved": 0}
+    # members of two batches: none of the three was the device's to encode
+    assert last["rows_host"] == last["rows_lacking"] == 3
+    assert sum(e["host"] for e in _events(mgr, "parity write")[-3:]) == 3
+    assert _members(_sidecars(mgr)) == _members(first)
+    assert _judged(mgr, blocks) == (0, set(blocks))
+    await stop()
+
+
+async def test_a_deleted_member_dissolves_its_codeword_at_the_passs_end(
+        tmp_path):
+    """The survivors keep the old sidecar as their cover, are regrouped
+    by the next pass that has k free blocks, and the old file goes when
+    the purge's grace is over."""
+    mgr, worker, stop, blocks, first, rng = await _three_codewords(tmp_path)
+    store = mgr.parity_store
+    gone = sorted(blocks)[10]
+    (old,) = [p for p, ms in _members(first).items() if gone in ms]
+    os.remove(mgr.find_block(Hash(gone))[0])
+    del blocks[gone]
+    await _next_pass(worker)
+    assert _states(_last_pass(mgr)) == {
+        "settled": 2, "rewritten": 0, "formed": 0, "dissolved": 1}
+    assert set(_sidecars(mgr)) == set(first)
+    survivors = [h for h in _members(first)[old] if h != gone]
+    assert all(store.coverage(Hash(h)) for h in blocks)
+    assert all(store._scrub_gid(store.index.get(h)) is None
+               for h in survivors)
+    blocks.update(await _write(mgr, 1, rng))    # k free blocks now
+    await _next_pass(worker)
+    assert _states(_last_pass(mgr)) == {
+        "settled": 2, "rewritten": 0, "formed": 1, "dissolved": 0}
+    (new,) = set(_sidecars(mgr)) - set(first)
+    assert set(_members(_sidecars(mgr))[new]) == set(survivors) | (
+        set(blocks) - set(h for ms in _members(first).values() for h in ms))
+    assert old in _sidecars(mgr)
+    for _ in range(2):
+        await _next_pass(worker)
+        assert _last_pass(mgr)["settled"] == 3
+    assert old not in _sidecars(mgr)
+    assert _counter(mgr, "parity_purged_sidecars_total") == 1
+    assert _judged(mgr, blocks) == (0, set(blocks))
+    await stop()
+
+
+@pytest.mark.parametrize("eno, batch_blocks, settled", [
+    (errno.EMFILE, None, 2),    # a transient error: the read gives None
+    (errno.EIO, 1, 3),          # a media error, in a batch of its own
+])
+async def test_a_member_not_read_this_once_dissolves_nothing(
+        tmp_path, eno, batch_blocks, settled):
+    """Not read is not gone: a codeword a whole pass read fewer than k
+    members of keeps them while the store still has the one it missed,
+    and a block the disk could not read (healed from the sidecar) is a
+    member read, in a batch that holds nothing else too."""
+    from garage_tpu.testing.faults import FaultyDisk
+
+    mgr, worker, stop, blocks, first, _rng = await _three_codewords(
+        tmp_path, batch_blocks=batch_blocks)
+    store = mgr.parity_store
+    index = dict(store.index.items(None, None))
+    missed = sorted(blocks)[10]
+    mgr.disk = FaultyDisk(mgr.disk,
+                          path_prefix=mgr.find_block(Hash(missed))[0])
+    mgr.disk.read_errno = eno
+    written = _counter(mgr, "parity_codewords_written_total", origin="scrub")
+    await _next_pass(worker)
+    assert mgr.disk.injected["read"] == 1
+    assert _states(_last_pass(mgr)) == {
+        "settled": settled, "rewritten": 0, "formed": 0, "dissolved": 0}
+    assert dict(store.index.items(None, None)) == index
+    mgr.disk.clear()
+    await _next_pass(worker)
+    assert _states(_last_pass(mgr)) == {
+        "settled": 3, "rewritten": 0, "formed": 0, "dissolved": 0}
+    assert _counter(mgr, "parity_codewords_written_total",
+                    origin="scrub") == written
+    assert _members(_sidecars(mgr)) == _members(first)
+    assert _judged(mgr, blocks) == (0, set(blocks))
+    await stop()
+
+
+async def test_an_index_from_before_the_rule_converges_in_one_pass(tmp_path):
+    """Entries of the bare 32 bytes, as the code before wrote them, and a
+    block written since: the pass writes no sidecar, finds every
+    codeword settled and gives its members' entries the suffix."""
+    mgr, worker, stop, blocks, first, rng = await _three_codewords(tmp_path)
+    store = mgr.parity_store
+    for key, entry in list(store.index.items(None, None)):
+        assert len(entry) == 34
+        store.index.insert(key, entry[:32])
+    blocks.update(await _write(mgr, 1, rng))
+    written = _counter(mgr, "parity_codewords_written_total", origin="scrub")
+    await _next_pass(worker)
+    assert _counter(mgr, "parity_codewords_written_total",
+                    origin="scrub") == written
+    assert _states(_last_pass(mgr)) == {
+        "settled": 3, "rewritten": 0, "formed": 0, "dissolved": 0}
+    assert _last_pass(mgr)["rows_lacking"] == 0
+    assert _members(_sidecars(mgr)) == _members(first)
+    assert sorted(len(e) for _k, e in store.index.items(None, None)) == [
+        34] * (3 * mgr.codec.params.rs_data)
+    await stop()
+
+
+async def test_scrub_codewords_total_sums_to_the_passs_rows(tmp_path):
+    """Every series from 0; after each pass the four states' growth is
+    the `scrub pass` event's four counts, and their sum its `rows`."""
+    mgr, worker, stop = await _node(tmp_path)
+    states = ("settled", "rewritten", "formed", "dissolved")
+
+    def counted():
+        return {s: _counter(mgr, "scrub_codewords_total", state=s)
+                for s in states}
+
+    assert counted() == dict.fromkeys(states, 0)
+    rng = np.random.default_rng(SEED)
+    blocks = await _write(mgr, OLD, rng)
+    seen = []
+    for step in range(4):
+        before = counted()
+        await _next_pass(worker)
+        last = _last_pass(mgr)
+        grown = {s: n - before[s] for s, n in counted().items()}
+        assert grown == _states(last)
+        assert sum(grown.values()) == last["rows"] > 0
+        seen.append(grown)
+        if step == 0:       # a sidecar lost, blocks written
+            os.remove(sorted(_sidecars(mgr))[0])
+            blocks.update(await _write(mgr, NEW, rng))
+        elif step == 1:     # a member deleted for good
+            os.remove(mgr.find_block(Hash(sorted(blocks)[0]))[0])
+    assert [g["formed"] for g in seen] == [OLD // 8, 1, 0, 0]
+    assert [g["rewritten"] for g in seen] == [0, 1, 0, 0]
+    assert [g["dissolved"] for g in seen] == [0, 0, 1, 0]
+    assert [g["settled"] for g in seen] == [0, OLD // 8 - 1, OLD // 8,
+                                            OLD // 8]
     await stop()
 
 
@@ -269,7 +578,7 @@ async def test_the_written_counters_tell_scrub_write_time_and_heal_apart(
 async def test_the_accumulators_flush_event_carries_its_cause(
         tmp_path, cause, members):
     mgr, _worker, stop = await _node(
-        tmp_path, flush_after=0.05 if cause == "timeout" else 60.0)
+        tmp_path, flush_after=0.5 if cause == "timeout" else 60.0)
     k = mgr.codec.params.rs_data
     assert k == 8
     blocks = await _write(mgr, members, np.random.default_rng(SEED))

@@ -187,6 +187,30 @@ async def test_file_bytes_a_caller_read_itself_are_inflated_on_the_workers_path(
     await shutdown(systems)
 
 
+def test_a_listed_batch_never_holds_more_than_asked_for(tmp_path):
+    """A prefix dir that would take a batch past `want` is the next
+    batch's first (a dir that holds more by itself is a batch); every
+    block is listed once, in order, and the position after a batch
+    does not pass the dir it left."""
+    held = {0x0a00: 1, 0x0a01: 1, 0x0a02: 2, 0x0b00: 1, 0x0c00: 4, 0x0c01: 1}
+    names = []
+    for prefix, n in held.items():
+        d = tmp_path / f"{prefix >> 8:02x}" / f"{prefix & 0xff:02x}"
+        d.mkdir(parents=True)
+        for i in range(n):
+            name = f"{prefix:04x}" + f"{i:02x}" * 30
+            (d / name).write_bytes(b"x")
+            names.append(name)
+    it = BlockStoreIterator([str(tmp_path)])
+    batches, after = [], []
+    while (batch := repair._list_batch(it, 3)) is not None:
+        batches.append([bytes(h).hex() for h, _p, _c in batch])
+        after.append(it.position)
+    assert [len(b) for b in batches] == [2, 3, 4, 1]
+    assert sum(batches, []) == names
+    assert after == [0x0a02, 0x0b01, 0x0c01, 65536]
+
+
 # --- (b) the worker's hops do not queue behind the reads ----------------------
 
 

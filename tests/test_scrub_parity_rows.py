@@ -1,7 +1,9 @@
 """Parity leaves the device only for the codewords that lack a sidecar
-(ISSUE 33): the store says which rows those are, the submission names
-them, the transport brings back those rows and no others, and the
-worker files the rest of the batch without their parity.
+(ISSUE 33): the store says which rows those are and puts them in front
+(ISSUE 44: by what the parity index says of each block, not by the
+block's place in the batch), the submission names them, the transport
+brings back those rows and no others, and the rest of the batch is
+settled without its parity.
 
 Every parity compared is held to RS(k, m) written from the field's
 definition (`benchmarks/reference.py`, which imports nothing of the
@@ -50,6 +52,13 @@ def _store(tmp_path, dirs=("d0",), k=K, m=M, metrics=None):
                        CpuCodec(_params(rs_data=k, rs_parity=m)))
 
 
+def _sorted_blocks(n, seed):
+    """Blocks in id order, as a pass's listing gives them."""
+    pairs = sorted(zip(*_blocks(n, seed=seed)[::-1]),
+                   key=lambda hb: bytes(hb[0]))
+    return [b for _h, b in pairs], [h for h, _b in pairs]
+
+
 def _put_rows(store, blocks, hashes, rows):
     k = store.codec.params.rs_data
     for r in rows:
@@ -59,92 +68,169 @@ def _put_rows(store, blocks, hashes, rows):
             store.codec.rs_encode_blocks(members)[0])
 
 
-def _lacking_with_a_carry(tmp_path):
-    """Rows are rows of carry + batch: a sidecar filed under members
-    that straddle the two is found."""
-    store = _store(tmp_path)
-    carry, batch = _blocks(3, seed=1), _blocks(4 * K - 3, seed=2)
-    all_b, all_h = carry[0] + batch[0], carry[1] + batch[1]
-    _put_rows(store, all_b, all_h, [0, 2])
-    assert store.rows_lacking_sidecar(all_h) == [1, 3]
-    # without the carry the same blocks group otherwise: no row is found
-    assert store.rows_lacking_sidecar(batch[1]) == [0, 1, 2]
+def _front(plan, k=K):
+    """The front rows of a plan, as their members' ids."""
+    return [[bytes(h) for h in plan.hashes[r * k:(r + 1) * k]]
+            for r in plan.want]
 
 
-def _lacking_with_a_partial_last_row(tmp_path):
-    """The trailing members are no codeword yet: never named, whatever
-    is on disk."""
+def _plan_with_a_carry(tmp_path):
+    """Free blocks short of a row wait, verified, and are never
+    submitted again: a batch goes to the codec with its own lanes and
+    no other.  The free block that makes their row whole stands behind
+    like a settled lane, and the row is handed over to be encoded on
+    the host once that block has verified."""
     store = _store(tmp_path)
-    blocks, hashes = _blocks(3 * K + 3, seed=3)
-    assert store.rows_lacking_sidecar(hashes) == [0, 1, 2]
+    blocks, hashes = _sorted_blocks(4 * K, seed=1)
+    _put_rows(store, blocks, hashes, [1, 2])
+    pass_ = store.begin_pass(True)
+    first = pass_.plan(hashes[:K - 1], blocks[:K - 1])
+    assert first.want == [] and first.hashes == hashes[:K - 1]
+    assert pass_.filed(first, [True] * (K - 1)) == ([], [])
+    # K settled blocks, then the free block that makes the carry a row
+    batch_h = hashes[K:2 * K] + hashes[K - 1:K]
+    batch_b = blocks[K:2 * K] + blocks[K - 1:K]
+    plan = pass_.plan(batch_h, batch_b)
+    assert plan.want == [] and plan.hashes == batch_h
+    assert plan.where == list(range(K + 1))
+    sound, ((cw, row_h, row_b),) = pass_.filed(plan, [True] * (K + 1))
+    assert sound == [] and cw is None
+    assert (row_h, row_b) == (hashes[:K], blocks[:K])
+    assert store.put_straddler(row_h, row_b)
+    pass_.wrote(cw)
+    assert pass_.counts == {"settled": 1, "rewritten": 0, "formed": 1,
+                            "dissolved": 0} and pass_.carry == []
+    assert store.begin_pass(True).plan(hashes[:K], blocks[:K]).want == []
+    # a free block that failed its verify is not carried
+    plan = pass_.plan(hashes[3 * K:3 * K + 2], blocks[3 * K:3 * K + 2])
+    pass_.filed(plan, [True, False])
+    assert [h for h, _b in pass_.carry] == hashes[3 * K:3 * K + 1]
+
+
+def _plan_with_a_partial_last_row(tmp_path):
+    """The trailing free blocks are no codeword yet: never named,
+    whatever is on disk; a free row is k free blocks in listing order,
+    the settled ones between them skipped."""
+    store = _store(tmp_path)
+    blocks, hashes = _sorted_blocks(3 * K + 3, seed=3)
+    pass_ = store.begin_pass(True)
+    plan = pass_.plan(hashes, blocks)
+    assert plan.want == [0, 1, 2] and plan.hashes == hashes
+    assert plan.tail == [3 * K, 3 * K + 1, 3 * K + 2]
     _put_rows(store, blocks, hashes, [1])
-    assert store.rows_lacking_sidecar(hashes) == [0, 2]
-    assert store.rows_lacking_sidecar(hashes[:K - 1]) == []
+    plan = store.begin_pass(True).plan(hashes, blocks)
+    free = hashes[:K] + hashes[2 * K:]
+    assert _front(plan) == [[bytes(h) for h in free[:K]],
+                            [bytes(h) for h in free[K:2 * K]]]
+    assert store.begin_pass(True).plan(
+        hashes[:K - 1], blocks[:K - 1]).want == []
 
 
-def _lacking_with_a_second_data_dir(tmp_path):
+def _plan_with_a_second_data_dir(tmp_path):
     """A sidecar written before the layout changed, in a dir that is no
-    longer the one written to, is present."""
+    longer the one written to, is present: touched there, its codeword
+    settled, no row asked for."""
     old = _store(tmp_path, dirs=("d1",))
-    blocks, hashes = _blocks(2 * K, seed=4)
+    blocks, hashes = _sorted_blocks(2 * K, seed=4)
     _put_rows(old, blocks, hashes, [1])
     store = _store(tmp_path, dirs=("d0", "d1"))
+    store.index = old.index
     assert store.dir.startswith(str(tmp_path / "d0"))
-    assert store.rows_lacking_sidecar(hashes) == [0]
+    (path,) = [os.path.join(d, n) for d, _s, ns in os.walk(old.dir)
+               for n in ns]
+    os.utime(path, (1, 1))
+    pass_ = store.begin_pass(True)
+    plan = pass_.plan(hashes, blocks)
+    assert _front(plan) == [[bytes(h) for h in hashes[:K]]]
+    assert pass_.counts["settled"] == 1 and os.stat(path).st_mtime > 1
 
 
-def _lacking_after_a_geometry_change(tmp_path):
-    """A file of another (k, m) over the same members has another
-    content and another name: it does not count as present."""
-    blocks, hashes = _blocks(2 * K, seed=5)
-    _put_rows(_store(tmp_path), blocks, hashes, [0, 1])
-    assert _store(tmp_path).rows_lacking_sidecar(hashes) == []
-    assert _store(tmp_path, m=M + 1).rows_lacking_sidecar(hashes) == [0, 1]
-    assert _store(tmp_path, k=K // 2).rows_lacking_sidecar(
-        hashes) == [0, 1, 2, 3]
+def _plan_after_a_geometry_change(tmp_path):
+    """An entry that names a codeword of another (k, m) is no place:
+    its block is free, and regrouped at the geometry of now."""
+    blocks, hashes = _sorted_blocks(2 * K, seed=5)
+    was = _store(tmp_path)
+    _put_rows(was, blocks, hashes, [0, 1])
+    assert was.begin_pass(True).plan(hashes, blocks).want == []
+    for kw, rows in (({"m": M + 1}, 2), ({"k": K // 2}, 4)):
+        store = _store(tmp_path, **kw)
+        store.index = was.index
+        plan = store.begin_pass(True).plan(hashes, blocks)
+        assert plan.want == list(range(rows)) and plan.hashes == hashes
+
+
+def _plan_with_an_unreadable_member(tmp_path):
+    """A block that is on the disk and gave no bytes (the heal brings it
+    back) is a member read all the same: its codeword is settled, not
+    dissolved at the pass's end; one whose sidecar is gone too waits for
+    the next pass."""
+    store = _store(tmp_path)
+    blocks, hashes = _sorted_blocks(2 * K, seed=8)
+    _put_rows(store, blocks, hashes, [0, 1])
+    os.remove(store._find_group_path(bytes(store._gid(K, M, hashes[K:]))))
+    index = dict(store.index.items(None, None))
+    pass_ = store.begin_pass(True)
+    plan = pass_.plan(hashes[1:K + 2] + hashes[K + 3:],
+                      blocks[1:K + 2] + blocks[K + 3:],
+                      [hashes[0], hashes[K + 2]])
+    assert plan.want == [] and len(plan.hashes) == 2 * K - 2
+    assert pass_.filed(plan, [True] * len(plan.hashes)) == ([], [])
+    pass_.close()
+    assert pass_.counts == {"settled": 1, "rewritten": 0, "formed": 0,
+                            "dissolved": 0}
+    assert dict(store.index.items(None, None)) == index
 
 
 @pytest.mark.parametrize("case", [
-    _lacking_with_a_carry, _lacking_with_a_partial_last_row,
-    _lacking_with_a_second_data_dir, _lacking_after_a_geometry_change],
-    ids=lambda f: f.__name__[9:])
-def test_rows_lacking_sidecar(tmp_path, case):
+    _plan_with_a_carry, _plan_with_a_partial_last_row,
+    _plan_with_a_second_data_dir, _plan_after_a_geometry_change,
+    _plan_with_an_unreadable_member],
+    ids=lambda f: f.__name__[6:])
+def test_the_plan_of_a_batch(tmp_path, case):
     case(tmp_path)
 
 
-def test_refresh_counts_touches_and_indexes_like_a_put(tmp_path):
-    """`refresh_codewords` against `put_codeword` over the same rows on
-    two stores: the same bytes counted (the parity's from the lengths),
-    the same index, a fresh mtime on every file; and a file gone since
-    the ask is written there, with the reference's bytes."""
-    blocks, hashes = _blocks(3 * K, seed=6)
+def test_settled_counts_and_touches_like_a_put(tmp_path):
+    """A codeword found settled against `put_codeword` over the same
+    rows on two stores: the same bytes counted (the parity's from the
+    lengths), a fresh mtime on every file, the index as it was; and a
+    file that is gone is asked for, in front, and written there with
+    the reference's bytes."""
+    blocks, hashes = _sorted_blocks(3 * K, seed=6)
     regs = MetricsRegistry(), MetricsRegistry()
     by_put = _store(tmp_path / "a", metrics=regs[0])
-    by_refresh = _store(tmp_path / "b", metrics=regs[1])
-    for store in (by_put, by_refresh):
+    by_plan = _store(tmp_path / "b", metrics=regs[1])
+    for store in (by_put, by_plan):
         _put_rows(store, blocks, hashes, [0, 1, 2])
     counted = [{p: r.counter("parity_sidecar_bytes_total").get(part=p)
                 for p in ("parity", "covered")} for r in regs]
     assert counted[0] == counted[1]
     files = sorted(os.path.join(d, n) for d, _s, ns in os.walk(
-        by_refresh.dir) for n in ns)
+        by_plan.dir) for n in ns)
     assert len(files) == 3
     for f in files:
         os.utime(f, (1, 1))
-    gone = by_refresh._find_group_path(bytes(by_refresh._gid(
+    gone = by_plan._find_group_path(bytes(by_plan._gid(
         K, M, hashes[K:2 * K])))
     os.remove(gone)
-    for h in hashes:
-        by_refresh.index.remove(bytes(h))
+    index = dict(by_plan.index.items(None, None))
     # the second filing of the same rows, either way
     for r in range(3):
         members = blocks[r * K:(r + 1) * K]
         assert not by_put.put_codeword(
             hashes[r * K:(r + 1) * K], [len(b) for b in members],
             by_put.codec.rs_encode_blocks(members)[0])
-    assert by_refresh.refresh_codewords(
-        [(hashes[r * K:(r + 1) * K], blocks[r * K:(r + 1) * K])
-         for r in range(3)]) == (2, 1)
+    pass_ = by_plan.begin_pass(True)
+    plan = pass_.plan(hashes, blocks)
+    assert _front(plan) == [[bytes(h) for h in hashes[K:2 * K]]]
+    ((row, cw, row_h, row_b),), _none = pass_.filed(
+        plan, [True] * len(hashes))
+    assert by_plan.put_codeword(
+        row_h, [len(b) for b in row_b],
+        by_plan.codec.rs_encode_blocks(row_b)[0])
+    pass_.wrote(cw)
+    assert pass_.counts == {"settled": 2, "rewritten": 1, "formed": 0,
+                            "dissolved": 0} and not pass_.unsettled
     for reg, first in zip(regs, counted):
         c = reg.counter("parity_sidecar_bytes_total")
         assert {p: c.get(part=p) for p in first} == {
@@ -152,8 +238,9 @@ def test_refresh_counts_touches_and_indexes_like_a_put(tmp_path):
     assert counted[0]["parity"] == sum(
         M * max(map(len, blocks[r * K:(r + 1) * K])) for r in range(3))
     assert all(os.stat(f).st_mtime > 1 for f in files)
-    assert by_refresh.purge_stale(time.time() - 60) == 0
-    assert all(by_refresh.coverage(h) for h in hashes)
+    assert by_plan.purge_stale(time.time() - 60) == 0
+    assert dict(by_plan.index.items(None, None)) == index
+    assert all(by_plan.coverage(h) for h in hashes)
     with open(gone, "rb") as f:
         man = msgpack.unpackb(f.read(), raw=False)
     assert man["lengths"] == [len(b) for b in blocks[K:2 * K]]
@@ -370,11 +457,12 @@ def _held_to_the_reference(path, blocks):
 async def test_a_pass_files_by_the_batch_on_the_floor(tmp_path):
     """Three passes over one store through the feeder on the CPU floor.
     The first names every row and writes every sidecar.  Before the
-    second two are removed: it names those two, writes them again with
-    the reference's bytes and touches the rest, counting the bytes the
-    first counted (so the purge spares what it spared).  In the third
-    a file is removed between the ask and the touch: it is written in
-    that pass, from the floor, and no row was named."""
+    second two are removed: it names those two, writes them again under
+    their names with the reference's bytes and touches the rest,
+    counting the bytes the first counted (so the purge spares what it
+    spared).  Before the third a block is flipped and its codeword's
+    sidecar stays: no row is named, the block heals from that sidecar
+    and every byte is counted again."""
     from tests.test_block import make_block_cluster
     from tests.test_table import shutdown
 
@@ -388,64 +476,60 @@ async def test_a_pass_files_by_the_batch_on_the_floor(tmp_path):
         h = hashlib.blake2s(d, digest_size=32).digest()
         blocks[h] = d
         await m.write_block(Hash(h), DataBlock.plain(d))
-    asked = []
-    ask = store.rows_lacking_sidecar
-    store.rows_lacking_sidecar = lambda hs: asked.append(ask(hs)) or asked[-1]
     counter = systems[0].metrics.counter("parity_sidecar_bytes_total")
 
     def counted():
         return {p: counter.get(part=p) for p in ("parity", "covered")}
 
-    def parity_writes():
+    def events(name):
         return [e["args"] for e in m.codec.obs.timeline.snapshot()
-                if e["name"] == "parity write"]
+                if e["name"] == name]
 
     worker = ScrubWorker(m)
     await _pass(worker)
     files = _sidecar_files(store)
     first = counted()
-    assert len(files) == 5 and sum(map(len, asked)) == 5
+    assert len(files) == 5
+    assert sum(a["lacking"] for a in events("parity ask")) == 5
     assert first["covered"] == sum(
         len(blocks[h]) for h in sorted(blocks)[:5 * k])
     for f in files:
         _held_to_the_reference(f, blocks)
 
-    # (b), (d): two sidecars gone before the pass
-    del asked[:]
+    # two sidecars gone before the pass
     for f in files:
         os.utime(f, (1, 1))
     os.remove(files[1])
     os.remove(files[3])
-    t0, before = time.time(), len(parity_writes())
+    t0 = time.time()
+    asks, writes = len(events("parity ask")), len(events("parity write"))
     await _pass(worker)
-    assert sum(map(len, asked)) == 2
+    assert sum(a["lacking"] for a in events("parity ask")[asks:]) == 2
     assert _sidecar_files(store) == files
     assert all(os.stat(f).st_mtime >= t0 - 1 for f in files)
     assert counted() == {p: 2 * n for p, n in first.items()}
     for f in (files[1], files[3]):
         _held_to_the_reference(f, blocks)
-    writes = parity_writes()[before:]
-    assert (sum(w["written"] for w in writes),
-            sum(w["touched"] for w in writes)) == (2, 3)
+    assert (sum(w["written"] for w in events("parity write")[writes:]),
+            sum(w["touched"] for w in events("parity write")[writes:])) == (
+        2, 0)
+    assert {s: events("scrub pass")[-1][s] for s in (
+        "settled", "rewritten", "formed")} == {
+        "settled": 3, "rewritten": 2, "formed": 0}
 
-    # (c): a file there when asked, gone when touched
-    del asked[:]
-
-    def ask_then_lose(hs):
-        rows = ask(hs)
-        if os.path.exists(files[2]):
-            os.remove(files[2])
-        return rows
-
-    store.rows_lacking_sidecar = lambda hs: asked.append(
-        ask_then_lose(hs)) or asked[-1]
+    # a flipped block: nothing is named, its codeword stays and heals it
+    victim = sorted(blocks)[2 * k + 1]
+    with open(m.find_block(Hash(victim))[0], "r+b") as f:
+        f.write(bytes([blocks[victim][0] ^ 1]))
+    asks = len(events("parity ask"))
     await _pass(worker)
-    assert sum(map(len, asked)) == 0
+    assert sum(a["lacking"] for a in events("parity ask")[asks:]) == 0
     assert _sidecar_files(store) == files
-    _held_to_the_reference(files[2], blocks)
     assert counted() == {p: 3 * n for p, n in first.items()}
     assert all(store.coverage(Hash(h)) for h in sorted(blocks)[:5 * k])
-    assert worker.state.corruptions == 0
+    assert worker.state.corruptions == 1 == m.blocks_reconstructed
+    with open(m.find_block(Hash(victim))[0], "rb") as f:
+        assert f.read() == blocks[victim]
     if m.feeder is not None:
         m.feeder.shutdown()
     await shutdown(systems)

@@ -201,10 +201,14 @@ async def test_scrub_pass_span_tree_and_exact_sum_account(tmp_path):
     (heal,) = [e for e in second if e["name"] == "quarantine+heal"]
     assert heal["args"] == {"blocks": 1, "how": "local_sidecar",
                             "local_sidecar": 1}
-    (pw2,) = [e for e in second if e["name"] == "parity write"]
-    assert pw2["args"]["touched"] >= 1
+    # both codewords keep their members and their sidecars: no row is
+    # asked for, so no `parity write` stands in the ring
+    (ask2,) = [e for e in second if e["name"] == "parity ask"]
+    assert ask2["args"]["lacking"] == 0
+    assert not [e for e in second if e["name"] == "parity write"]
     (root2,) = [e for e in second if e["name"] == "scrub pass"]
     assert root2["args"]["corruptions"] == 1
+    assert (root2["args"]["settled"], root2["args"]["rows"]) == (2, 2)
     grown = sum(seg(s) for s in SCRUB_SEGMENTS) - before
     assert abs(grown * 1e6 - root2["dur"]) < 1.5
     assert seg("heal") > 0 and w.m_passes.get() == 2
